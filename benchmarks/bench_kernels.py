@@ -1,9 +1,9 @@
 """Pallas kernel validation sweep + timing.
 
-Sweeps shapes/dtypes for each TPU kernel against the pure-jnp oracle
-(interpret mode — this container has no TPU, so wall numbers time the
-oracle path; correctness is the deliverable here, perf comes from the
-roofline analysis)."""
+Sweeps shapes/dtypes for each TPU kernel against the pure-jnp oracle.
+On CPU the kernels run in the Pallas interpreter, so wall numbers time
+the interpreter and the oracle path; correctness is the deliverable
+there."""
 
 from __future__ import annotations
 
@@ -24,7 +24,7 @@ def run(fast: bool = False) -> list[dict]:
     rows = []
     for (h, w) in shapes:
         img = jnp.asarray(rng.integers(0, 255, (h, w)).astype(np.float32))
-        ii_k = ops.integral_image(img, interpret=True, use_kernel=True)
+        ii_k = ops.integral_image(img, use_kernel=True)
         ii_r = ops.integral_image(img, use_kernel=False)
         err = float(jnp.max(jnp.abs(ii_k - ii_r)))
         with Timer() as t:

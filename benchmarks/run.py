@@ -17,6 +17,8 @@ import os
 import time
 import traceback
 
+from repro.compile_cache import use_compile_cache
+
 BENCHES = [
     ("bench_kernels", "Pallas kernels vs oracle (shape sweep)"),
     ("bench_profile", "Fig 13  — per-phase cost profile"),
@@ -41,6 +43,7 @@ def main() -> None:
                     help="write BENCH_<name>.json per benchmark into DIR")
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
+    print(f"compile cache: {use_compile_cache()}")
 
     failures = []
     for name, desc in BENCHES:

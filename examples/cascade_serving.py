@@ -9,6 +9,7 @@ import numpy as np
 from repro.core import Detector, EngineConfig, paper_shaped_cascade
 from repro.core.training.data import render_scene
 from repro.serve import DetectorService, PodSpec, ServiceConfig
+from repro.compile_cache import use_compile_cache
 
 
 def main() -> None:
@@ -44,4 +45,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

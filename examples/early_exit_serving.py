@@ -12,6 +12,7 @@ from repro.configs import get_smoke_config
 from repro.models import build_model
 from repro.models.early_exit import ExitConfig, CascadeBatcher
 from repro.serve import make_cascade_decode_step
+from repro.compile_cache import use_compile_cache
 
 
 def main() -> None:
@@ -50,4 +51,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

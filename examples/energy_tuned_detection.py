@@ -12,6 +12,7 @@ from repro.core.training.data import render_scene
 from repro.configs.viola_jones import pretrained
 from repro.scheduling.autotune import accuracy_sweep, error_table
 from repro.scheduling.dvfs import dvfs_sweep, optimal_operating_point
+from repro.compile_cache import use_compile_cache
 
 
 def main() -> None:
@@ -45,4 +46,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -11,6 +11,7 @@ from repro.core.training.data import render_scene
 from repro.configs.viola_jones import pretrained
 from repro.scheduling import (build_detection_dag, simulate, odroid_xu4,
                               rpi3b, SequentialScheduler, BotlevScheduler)
+from repro.compile_cache import use_compile_cache
 
 
 def main() -> None:
@@ -43,4 +44,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -11,6 +11,7 @@ from repro.core import Detector, EngineConfig
 from repro.configs.viola_jones import pretrained
 from repro.serve import DetectorService, PodSpec, ServiceConfig
 from repro.stream import StreamConfig, VideoDetector, make_video
+from repro.compile_cache import use_compile_cache
 
 
 def main() -> None:
@@ -55,4 +56,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -19,6 +19,10 @@ Two execution strategies over the same cascade semantics:
   capacities are profile-guided (see ``calibrate_capacities``), mirroring
   the paper's measured per-stage rejection profile.
 
+Left unset, ``EngineConfig.mode`` follows the platform
+(:func:`repro.kernels.platform.mode_by_default`): ``dense`` on TPU, where
+the compacted tail's XLA gathers are serialized, ``wave`` elsewhere.
+
 The first (densest) waves can run through the Pallas tile kernel
 (``repro.kernels.ops.dense_stage_sums``) — on the single-image path *and*
 on the packed batched head, which routes per-level dense waves through the
@@ -29,8 +33,9 @@ list through the shared packed-tail evaluator
 gather, or the blocked packed-window Pallas kernel — is chosen per
 capacity rung by the measured crossover ladder
 (``EngineConfig.tail_rungs``, see ``Detector.calibrated``).  All backends
-and the dense kernels are verified bit-identical on the test corpus
-(interpret mode).  This dense/packed/gather spectrum is the SIMD
+and the dense kernels are verified bit-identical on the test corpus (in
+the Pallas interpreter on CPU; on TPU the kernels compile through
+Mosaic).  This dense/packed/gather spectrum is the SIMD
 re-expression of the paper's "balance between parallelism and optimal
 computational workload".
 
@@ -66,29 +71,38 @@ import jax
 import jax.numpy as jnp
 
 from .cascade import Cascade, WINDOW
-from .integral import integral_images, window_inv_sigma
-from .features import stage_sum_windows
+from .integral import integral_images, window_inv_sigma_grid
+from .features import run_sums_grid, stage_sum_windows
 from .pyramid import downscale_nearest, downscale_indices
 from . import nms
 from repro.kernels import packed_tail
+from repro.kernels.platform import kernels_by_default, mode_by_default
 import repro.plan as planlib
 
 __all__ = ["EngineConfig", "LevelResult", "BatchResult", "Detector",
-           "calibrate_capacities"]
+           "CapacityOverflow", "calibrate_capacities"]
+
+
+class CapacityOverflow(RuntimeError):
+    """More windows survived a compaction than its static capacity holds,
+    so the result would drop some: the one engine error that depends on
+    the image content rather than on the program."""
 
 
 class EngineConfig(NamedTuple):
     step: int = 1                  # window stride (paper §7.3 'step')
     scale_factor: float = 1.2      # pyramid ratio (paper §7.3 'scaleFactor')
-    mode: str = "wave"             # 'dense' | 'wave'
+    mode: str | None = None        # 'dense' | 'wave'; None = the
+    #                                platform's choice (dense on TPU only)
     dense_segments: tuple = (1, 2)  # stage counts of dense (full-grid) waves
     compact_every: int = 3         # stages per segment in the compacted tail
     capacity_fracs: tuple = ()     # per-compaction survivor capacity as a
     #                                fraction of the level's window count;
     #                                () = auto (2 * 0.5^(k+1), floor 0.02)
-    use_pallas: bool = False       # dense waves via Pallas kernel
+    use_pallas: bool | None = None  # dense waves via Pallas kernels; None =
+    #                                the platform's choice (on for TPU only),
+    #                                False = the jnp oracle path
     min_neighbors: int = 3
-    interpret: bool = True         # Pallas interpret mode (CPU container)
     pad_multiple: int = 0          # shape-bucket rounding: images are padded
     #                                up to the next multiple per side so mixed
     #                                resolutions share a few compiled bucket
@@ -169,6 +183,10 @@ class Detector:
     """
 
     def __init__(self, cascade: Cascade, config: EngineConfig = EngineConfig()):
+        if config.use_pallas is None:
+            config = config._replace(use_pallas=kernels_by_default())
+        if config.mode is None:
+            config = config._replace(mode=mode_by_default())
         self.cascade = cascade
         self.config = config
         self.stage_bounds = tuple(int(o) for o in np.asarray(cascade.stage_offsets))
@@ -216,6 +234,7 @@ class Detector:
 
         n_dense_lp = sum(seg.s1 - seg.s0 for seg in segs if seg.dense)
         fused = lp.head_mode == "fused" and n_dense_lp > 0
+        split_kernels = cfg.use_pallas and step == 1 and not fused
         head_tile = lp.head_tile
         if cfg.use_pallas or fused:
             from repro.kernels import ops as kops
@@ -225,12 +244,13 @@ class Detector:
         def level_fn(cascade: Cascade, img: jax.Array,
                      limits: jax.Array) -> LevelResult:
             if fused:
-                # whole dense head — SAT + 1/sigma + every dense stage's
-                # sums — in one megakernel dispatch (bit-identical to the
-                # split path below; the plan chose per measured crossover)
+                # whole dense head — SAT and 1/sigma (XLA), then every
+                # dense stage's sums in one megakernel dispatch
+                # (bit-identical to the split path below; the plan chose
+                # per measured crossover)
                 ii, inv_sigma_grid, dsums = kops.fused_head(
                     cascade, cascade_static, 0, n_dense_lp, img,
-                    tile=head_tile, interpret=cfg.interpret)
+                    tile=head_tile)
             else:
                 ii, ii_pair = integral_images(img)
             gy = jnp.arange(ny, dtype=jnp.int32) * step
@@ -238,8 +258,11 @@ class Detector:
             ys = jnp.repeat(gy, nx)
             xs = jnp.tile(gx, ny)
             if not fused:
-                inv_sigma_grid = window_inv_sigma(
-                    ii_pair, gy[:, None], gx[None, :], WINDOW)  # (ny, nx)
+                inv_sigma_grid = window_inv_sigma_grid(
+                    ii_pair, ny, nx, step, WINDOW)           # (ny, nx)
+                if not split_kernels:
+                    dsums = run_sums_grid(cascade, ii, inv_sigma_grid,
+                                          step, 0, n_dense_lp)
             inv_sigma = inv_sigma_grid.reshape(-1)
 
             # dense-grid liveness; ``limits`` masks windows whose receptive
@@ -256,17 +279,12 @@ class Detector:
                 s0, s1, dense = seg.s0, seg.s1, seg.dense
                 if dense:
                     for s in range(s0, s1):
-                        k0, k1 = bounds[s], bounds[s + 1]
-                        if fused:
-                            ss = dsums[s].reshape(-1)
-                        elif cfg.use_pallas and step == 1:
+                        if split_kernels:
                             ss = kops.dense_stage_sums(
                                 cascade, cascade_static, s, ii, inv_sigma_grid,
-                                tile=head_tile,
-                                interpret=cfg.interpret).reshape(-1)
+                                tile=head_tile).reshape(-1)
                         else:
-                            ss = stage_sum_windows(cascade, ii, ys, xs,
-                                                   inv_sigma, k0, k1)
+                            ss = dsums[s].reshape(-1)
                         alive = alive & (ss >= cascade.stage_threshold[s])
                         counts.append(alive.sum())
                 else:
@@ -379,7 +397,7 @@ class Detector:
         rects = []
         for res, scale in self.detect_raw(image):
             if bool(np.asarray(res.overflow)):
-                raise RuntimeError(
+                raise CapacityOverflow(
                     "wave-engine capacity overflow; raise capacity_fracs "
                     "(see calibrate_capacities)")
             val = np.asarray(res.valid)
@@ -446,27 +464,29 @@ class Detector:
                 ys_idx = downscale_indices(hp, lp.height)
                 xs_idx = downscale_indices(wp, lp.width)
                 img_l = stack[:, ys_idx[:, None], xs_idx[None, :]]
-                gy = np.arange(lp.ny, dtype=np.int32) * step
-                gx = np.arange(lp.nx, dtype=np.int32) * step
                 fused_l = plan.head_modes[li] == "fused" and n_dense > 0
 
-                def head(img, gy=gy, gx=gx):
+                def head(img, ny=lp.ny, nx=lp.nx):
                     ii, ii_pair = integral_images(img)
-                    inv = window_inv_sigma(
-                        ii_pair, jnp.asarray(gy)[:, None],
-                        jnp.asarray(gx)[None, :], WINDOW)
+                    inv = window_inv_sigma_grid(ii_pair, ny, nx, step,
+                                                WINDOW)
                     return ii, inv                            # (ny, nx) grid
 
                 if fused_l:
-                    # SAT + 1/sigma + every dense stage's sums for the whole
-                    # stack in one batched megakernel dispatch (bit-identical
-                    # to the split path; the plan chose per level from the
-                    # measured fused-vs-split crossover)
+                    # SAT and 1/sigma, then every dense stage's sums for
+                    # the whole stack in one batched megakernel dispatch
+                    # (bit-identical to the split path; the plan chose per
+                    # level from the measured fused-vs-split crossover)
                     ii_l, inv_grid_l, sums_l = kops.fused_head_batch(
                         cascade, cascade_static, 0, n_dense, img_l,
-                        tile=head_tile, interpret=cfg.interpret)
+                        tile=head_tile)
                 else:
                     ii_l, inv_grid_l = jax.vmap(head)(img_l)  # (B,h+1,w+1),(B,ny,nx)
+                    if not use_pallas:
+                        sums_l = jax.vmap(
+                            lambda ii_b, inv_b: run_sums_grid(
+                                cascade, ii_b, inv_b, step, 0, n_dense)
+                        )(ii_l, inv_grid_l)            # (B, n_dense, ny, nx)
                 inv_l = inv_grid_l.reshape(batch, -1)
                 if tail_segs:
                     sat_parts.append(ii_l.reshape(batch, -1))
@@ -479,22 +499,15 @@ class Detector:
                 alive_l = ((ys_w[None, :] <= y_lim[:, None])
                            & (xs_w[None, :] <= x_lim[:, None]))  # (B, n)
                 for s in range(n_dense):
-                    k0, k1 = bounds[s], bounds[s + 1]
-                    if fused_l:
-                        ss = sums_l[:, s].reshape(batch, -1)
-                    elif use_pallas:
+                    if use_pallas and not fused_l:
                         # dense waves through the Pallas tile kernel, one
                         # dispatch per (stage, level) over the whole stack —
                         # same kernel the single-image level_fn runs
                         ss = kops.dense_stage_sums_batch(
                             cascade, cascade_static, s, ii_l, inv_grid_l,
-                            tile=head_tile,
-                            interpret=cfg.interpret).reshape(batch, -1)
+                            tile=head_tile).reshape(batch, -1)
                     else:
-                        ss = jax.vmap(
-                            lambda ii_b, inv_b: stage_sum_windows(
-                                cascade, ii_b, ys_w, xs_w, inv_b, k0, k1)
-                        )(ii_l, inv_l)                        # (B, n)
+                        ss = sums_l[:, s].reshape(batch, -1)  # (B, n)
                     alive_l = alive_l & (ss >= cascade.stage_threshold[s])
                     counts = counts.at[s].add(
                         alive_l.sum(axis=1).astype(jnp.int32))
@@ -543,8 +556,7 @@ class Detector:
                 ss_run = packed_tail.stage_sums(
                     cascade, cascade_static, s0, s1, ii_flat, b_sel,
                     base_sel, stride_sel, y_sel, x_sel, inv_sel,
-                    backend=seg.backend, tile=lane_block,
-                    interpret=cfg.interpret)
+                    backend=seg.backend, tile=lane_block)
                 for j, s in enumerate(range(s0, s1)):
                     valid = valid & (ss_run[j] >= cascade.stage_threshold[s])
                     per_img = jnp.zeros((batch,), jnp.int32).at[b_sel].add(
@@ -655,16 +667,28 @@ class Detector:
                           if group else rects)
         return out
 
+    def batch_result(self, images) -> BatchResult:
+        """Pre-NMS survivors of a same-bucket stack: the :class:`BatchResult`
+        of the packed batch program that ``detect_batch`` runs (per-stage
+        alive counts, surviving windows, overflow flag)."""
+        imgs = [np.asarray(im, np.float32) for im in images]
+        hws = {self._bucket_hw(*im.shape) for im in imgs}
+        if len(hws) != 1:
+            raise ValueError(
+                f"batch_result needs a single shape bucket, got {hws}")
+        (hp, wp), = hws
+        stack, valid_hw = self._pack_stack(imgs, hp, wp)
+        return self._batch_fn(hp, wp, len(imgs))(
+            self.cascade, stack, jnp.asarray(valid_hw))
+
     def _detect_bucket_packed(self, imgs: list, hp: int, wp: int) -> list:
         n = len(imgs)
         plan = self.batch_plan(hp, wp, n)
         if not plan.levels:  # bucket smaller than the detection window
             return [np.zeros((0, 4), np.int32) for _ in range(n)]
-        stack, valid_hw = self._pack_stack(imgs, hp, wp)
-        res = self._batch_fn(hp, wp, n)(
-            self.cascade, stack, jnp.asarray(valid_hw))
+        res = self.batch_result(imgs)
         if bool(np.asarray(res.overflow)):
-            raise RuntimeError(
+            raise CapacityOverflow(
                 "batched-engine shared capacity overflow; raise "
                 "batch_capacity_fracs / capacity_fracs (see "
                 "Detector.calibrated)")
@@ -687,7 +711,7 @@ class Detector:
             over |= np.asarray(res.overflow)
         if over.any():
             bad = [idxs[i] for i in np.nonzero(over)[0]]
-            raise RuntimeError(
+            raise CapacityOverflow(
                 f"wave-engine capacity overflow on image(s) {bad}; raise "
                 "capacity_fracs (see Detector.calibrated)")
         out = []
@@ -787,9 +811,8 @@ class Detector:
                 for lp, d in zip(bplan.levels, level_density)]
         if tune_tail:
             kw = {} if tail_sizes is None else {"sizes": tuple(tail_sizes)}
-            tail = packed_tail.measure_rungs(
-                self.cascade, interpret=self.config.interpret,
-                workload=workload, **kw)
+            tail = packed_tail.measure_rungs(self.cascade, workload=workload,
+                                             **kw)
             cfg = cfg._replace(tail_backend="auto", tail_rungs=tail["rungs"])
             profile["tail"] = tail
         if tune_head:
@@ -797,22 +820,21 @@ class Detector:
             n_dense = bplan.dense_prefix
             if n_dense > 0:
                 head = kernels_autotune.measure_head(
-                    self.cascade, workload, n_dense=n_dense,
-                    interpret=self.config.interpret)
+                    self.cascade, workload, n_dense=n_dense)
                 cfg = cfg._replace(head_mode="auto",
                                    head_rungs=head["rungs"],
                                    head_tile=head["head_tiles"])
                 profile["head"] = head
                 profile["head_tiles"] = head["head_tiles"]
-            lane_size = (profile["tail"]["crossover"]
-                         if tune_tail and profile["tail"]["crossover"] > 0
-                         else 2048)
-            lane = kernels_autotune.measure_lane_block(
-                self.cascade, workload, size=lane_size,
-                interpret=self.config.interpret)
-            cfg = cfg._replace(lane_block=lane["lane_block"])
-            profile["lane"] = lane
-            profile["lane_block"] = lane["lane_block"]
+            if "pallas" in packed_tail.tail_backends():
+                lane_size = (profile["tail"]["crossover"]
+                             if tune_tail and profile["tail"]["crossover"] > 0
+                             else 2048)
+                lane = kernels_autotune.measure_lane_block(
+                    self.cascade, workload, size=lane_size)
+                cfg = cfg._replace(lane_block=lane["lane_block"])
+                profile["lane"] = lane
+                profile["lane_block"] = lane["lane_block"]
         det = Detector(self.cascade, cfg)
         det.cal_profile = profile
         return det

@@ -18,11 +18,13 @@ import jax
 import jax.numpy as jnp
 
 from .cascade import Cascade, WINDOW
-from .integral import rect_sum
+from .integral import grid_rect_sum, rect_sum
 
 __all__ = [
     "eval_weak_classifier",
     "stage_sum_windows",
+    "stage_sum_grid",
+    "run_sums_grid",
     "eval_stage",
     "run_cascade_windows",
 ]
@@ -64,6 +66,47 @@ def stage_sum_windows(cascade: Cascade, ii: jax.Array, ys: jax.Array,
 
     init = jnp.zeros_like(ys, jnp.float32)
     return jax.lax.fori_loop(k0, k1, body, init)
+
+
+def stage_sum_grid(cascade: Cascade, ii: jax.Array, inv_sigma: jax.Array,
+                   step: int, k0: jax.Array, k1: jax.Array) -> jax.Array:
+    """:func:`stage_sum_windows` over a whole (ny, nx) grid of window
+    origins ``(step * i, step * j)`` (``inv_sigma`` is that grid's
+    1/sigma): every corner is a SAT slice (:func:`grid_rect_sum`), with
+    the same float ordering, so the sums are bit-identical to the gather
+    form.  Returns (ny, nx)."""
+    ny, nx = inv_sigma.shape
+
+    def body(k, acc):
+        rects = jax.lax.dynamic_index_in_dim(cascade.rect_xywh, k, 0, False)
+        w = jax.lax.dynamic_index_in_dim(cascade.rect_w, k, 0, False)
+        feat = jnp.zeros((ny, nx), jnp.float32)
+        for r in range(rects.shape[0]):
+            rx, ry, rw, rh = rects[r, 0], rects[r, 1], rects[r, 2], rects[r, 3]
+            feat = feat + w[r] * grid_rect_sum(ii, ny, nx, step, ry, rx, rh,
+                                               rw)
+        f_norm = feat * inv_sigma / _AREA
+        return acc + jnp.where(f_norm < cascade.wc_threshold[k],
+                               cascade.left_val[k], cascade.right_val[k])
+
+    return jax.lax.fori_loop(k0, k1, body, jnp.zeros((ny, nx), jnp.float32))
+
+
+def run_sums_grid(cascade: Cascade, ii: jax.Array, inv_sigma: jax.Array,
+                  step: int, s0: int, s1: int) -> jax.Array:
+    """(s1 - s0, ny, nx) :func:`stage_sum_grid` of every stage in
+    ``[s0, s1)``, in one loop rolled over the stages, so the program holds
+    one loop body however many stages the run has (the fused kernel's
+    shape, and its output)."""
+    ny, nx = inv_sigma.shape
+    off = cascade.stage_offsets
+
+    def stage(j, out):
+        return out.at[j].set(stage_sum_grid(cascade, ii, inv_sigma, step,
+                                            off[s0 + j], off[s0 + j + 1]))
+
+    return jax.lax.fori_loop(0, s1 - s0, stage,
+                             jnp.zeros((s1 - s0, ny, nx), jnp.float32))
 
 
 def eval_stage(cascade: Cascade, s: int, ii: jax.Array, ys: jax.Array,

@@ -40,7 +40,9 @@ __all__ = [
     "integral_image",
     "integral_images",
     "rect_sum",
+    "grid_rect_sum",
     "window_inv_sigma",
+    "window_inv_sigma_grid",
     "integral_value",
     "CENTRE",
 ]
@@ -84,6 +86,31 @@ def rect_sum(ii: jax.Array, ys: jax.Array, xs: jax.Array,
     return ii[y1, x1] - ii[ys, x1] - ii[y1, xs] + ii[ys, xs]
 
 
+def grid_rect_sum(ii: jax.Array, ny: int, nx: int, step: int,
+                  y: jax.Array, x: jax.Array, h: jax.Array,
+                  w: jax.Array) -> jax.Array:
+    """:func:`rect_sum` over the whole (ny, nx) grid of window origins
+    ``(step * i, step * j)``, offset by ``(y, x)``: each corner is one
+    (strided) slice of the SAT instead of a gather — the same elements in
+    the same float ordering, so the result is bit-identical, but a dense
+    grid costs slices, which TPUs stream, instead of gathers, which they
+    serialize.  ``y``/``x``/``h``/``w`` may be traced scalars."""
+    span = ((ny - 1) * step + 1, (nx - 1) * step + 1)
+
+    def corner(cy, cx):
+        return jax.lax.dynamic_slice(ii, (cy, cx), span)[::step, ::step]
+
+    return (corner(y + h, x + w) - corner(y, x + w) - corner(y + h, x)
+            + corner(y, x))
+
+
+def _inv_sigma(s2: jax.Array, s1: jax.Array, window: int) -> jax.Array:
+    n = float(window * window)
+    var = s2 / n - (s1 / n) ** 2
+    sigma = jnp.sqrt(jnp.maximum(var, 1.0))
+    return 1.0 / sigma
+
+
 def window_inv_sigma(ii_pair: jax.Array, ys: jax.Array, xs: jax.Array,
                      window: int) -> jax.Array:
     """1 / sigma for each detection window (paper Eq. 5, float-safe form).
@@ -94,13 +121,21 @@ def window_inv_sigma(ii_pair: jax.Array, ys: jax.Array, xs: jax.Array,
     normalized feature values (same guard as the reference C code's
     ``int_sqrt`` path).
     """
-    n = float(window * window)
     ii2, iic = ii_pair[0], ii_pair[1]
     s2 = rect_sum(ii2, ys, xs, window, window)      # sum (x-mu)^2
     s1 = rect_sum(iic, ys, xs, window, window)      # sum (x-mu)
-    var = s2 / n - (s1 / n) ** 2
-    sigma = jnp.sqrt(jnp.maximum(var, 1.0))
-    return 1.0 / sigma
+    return _inv_sigma(s2, s1, window)
+
+
+def window_inv_sigma_grid(ii_pair: jax.Array, ny: int, nx: int, step: int,
+                          window: int) -> jax.Array:
+    """(ny, nx) :func:`window_inv_sigma` over the grid of window origins
+    ``(step * i, step * j)``, from slices (:func:`grid_rect_sum`);
+    bit-identical to the gather form."""
+    ii2, iic = ii_pair[0], ii_pair[1]
+    s2 = grid_rect_sum(ii2, ny, nx, step, 0, 0, window, window)
+    s1 = grid_rect_sum(iic, ny, nx, step, 0, 0, window, window)
+    return _inv_sigma(s2, s1, window)
 
 
 def integral_value(img: jax.Array) -> jax.Array:

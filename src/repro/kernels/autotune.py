@@ -71,7 +71,7 @@ def _tile_label(tile) -> str:
     return f"{tile[0]}x{tile[1]}"
 
 
-def measure_head(cascade, workload, *, n_dense: int, interpret: bool = True,
+def measure_head(cascade, workload, *, n_dense: int,
                  candidates=HEAD_TILE_CANDIDATES, repeats: int = 2,
                  inner: int = 3) -> dict:
     """Race the fused head megakernel against the split three-dispatch path.
@@ -116,8 +116,7 @@ def measure_head(cascade, workload, *, n_dense: int, interpret: bool = True,
             ii, pair = integral_images(im)
             inv = window_inv_sigma(pair, jnp.arange(ny)[:, None],
                                    jnp.arange(nx)[None, :], WINDOW)
-            sums = [ops.dense_stage_sums(c, cascade, s, ii, inv,
-                                         interpret=interpret)
+            sums = [ops.dense_stage_sums(c, cascade, s, ii, inv)
                     for s in range(n_dense)]
             return ii, inv, sums
 
@@ -126,8 +125,7 @@ def measure_head(cascade, workload, *, n_dense: int, interpret: bool = True,
                                  repeats, inner))
         for cand in candidates:
             def fused_head(c, im, _t=cand):
-                return ops.fused_head(c, cascade, 0, n_dense, im,
-                                      tile=_t, interpret=interpret)
+                return ops.fused_head(c, cascade, 0, n_dense, im, tile=_t)
 
             # repro: ignore[JIT_CACHE] tuner harness: one fresh jitted fn per measured (level, tile) point is the measurement unit; compile cost is excluded by the warm-up call in _best_ms
             fn = jax.jit(fused_head)
@@ -151,7 +149,6 @@ def measure_head(cascade, workload, *, n_dense: int, interpret: bool = True,
 
 
 def measure_lane_block(cascade, workload=None, *, size: int = 2048,
-                       interpret: bool = True,
                        candidates=LANE_BLOCK_CANDIDATES, repeats: int = 3,
                        inner: int = 5, seed: int = 0) -> dict:
     """Race packed-tail lane-block shapes at one packed-list size.
@@ -161,7 +158,9 @@ def measure_lane_block(cascade, workload=None, *, size: int = 2048,
     the full cascade at each candidate ``tile``.  ``size`` should be the
     calibrated tail crossover (the smallest packed-list size routed to
     the kernel), so the winner is tuned where the kernel actually runs.
-    Returns ``{"size", "n_windows", "candidates", "ms", "lane_block"}``.
+    The kernel runs only where ``packed_tail.tail_backends()`` lists it
+    (not on TPU).  Returns ``{"size", "n_windows", "candidates", "ms",
+    "lane_block"}``.
     """
     from . import packed_tail
 
@@ -178,7 +177,7 @@ def measure_lane_block(cascade, workload=None, *, size: int = 2048,
         # repro: ignore[JIT_CACHE] tuner harness: one fresh jitted fn per candidate lane block is the measurement unit; compile cost is excluded by the warm-up call in _best_ms
         fn = jax.jit(lambda c, iif, iv, _t=cand: packed_tail.stage_sums(
             c, cascade, 0, n_stages, iif, imgi, base, stride, ys, xs, iv,
-            backend="pallas", tile=_t, interpret=interpret))
+            backend="pallas", tile=_t))
         ms.append(_best_ms(fn, (cascade, ii_flat, inv), repeats, inner))
     winner = candidates[int(np.argmin(ms))]
     return {"size": int(size), "n_windows": int(n_windows),

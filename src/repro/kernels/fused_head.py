@@ -1,33 +1,27 @@
-"""Fused Haar-head megakernel: SAT + 1/sigma + dense stage sums, one dispatch.
+"""Fused Haar-head megakernel: every dense stage's vote sums, one dispatch.
 
-The split head runs three Pallas/jnp dispatches with HBM round-trips
-between them: integral images -> window-variance grid -> one haar_stage
-dispatch *per dense stage*.  BENCH_detector shows that split head is the
-dominant cost of a batched detect.  This kernel fuses the whole dense
-head into one ``pallas_call`` per image: the first grid step builds all
-three summed-area tables into VMEM scratch (cumsum of the full image —
-grid iteration is sequential on TPU, so later steps see them resident),
-then every (ty, tx) tile of window origins computes its inverse-sigma and
-*every* dense stage's vote sums while the SAT slab stays in VMEM — the
-xformers fused-softmax idiom (keep the row resident, do all the passes)
-applied to SAT+cascade.
+The split head runs one ``haar_stage`` Pallas dispatch *per dense stage*
+after the jnp SAT and 1/sigma, with HBM round-trips between them.
+BENCH_detector shows that split head is the dominant cost of a batched
+detect.  This kernel fuses the dense head into one ``pallas_call`` per
+image: the summed-area table stays resident in VMEM (constant index map,
+so it is copied in once), and every (ty, tx) tile of window origins
+computes *every* dense stage's vote sums from it — the xformers
+fused-softmax idiom (keep the row resident, do all the passes) applied
+to SAT+cascade.
 
-Bit-exactness contract (the whole point — the engine asserts fused ==
-split to the last ulp): the engine's split path computes the SAT and
-1/sigma with *jnp* (:mod:`repro.core.integral`) and the stage sums with
-the haar_stage Pallas kernel, so this kernel replicates those exact
-float orderings:
+Bit-exactness contract (the engine asserts fused == split to the last
+ulp, and both equal the jnp path):
 
-- SAT: ``jnp.cumsum(jnp.cumsum(img, 0), 1)`` then zero top/left pad —
-  the same XLA op sequence as :func:`repro.core.integral.integral_image`;
-- 1/sigma: corner order ``d - b - c + a`` and
-  ``var = s2/n - (s1/n)**2``, ``1/sqrt(max(var, 1))`` exactly as
-  :func:`repro.core.integral.window_inv_sigma` (NOT the
-  ``(d-b)-(c-a)`` + ``rsqrt`` form of kernels/window_variance.py — that
-  kernel is not what the engine's split head runs);
-- stage sums: corner order ``(d - b) - (c - a)`` and
-  ``feat * inv_sigma * _INV_AREA``, ascending-k vote accumulation,
-  exactly as kernels/haar_stage.py.
+- SAT and 1/sigma: computed outside the kernel, in the same jitted
+  program, by :func:`repro.core.integral.integral_images` and
+  :func:`repro.core.integral.window_inv_sigma_grid` — the very XLA ops
+  the split and jnp paths run, so they are identical on every backend.
+  (A prefix sum has no Mosaic lowering, an in-kernel scan would round
+  sums past 2^24 in another order than XLA's cumsum, and Mosaic's
+  sqrt/divide need not round as XLA's do.)
+- stage sums: :func:`repro.kernels.haar_stage.weak_vote` — the split
+  kernel's own per-classifier body — accumulated in ascending k.
 
 Valid window origins only ever read SAT rows/cols up to ``(h, w)`` — the
 true (h+1, w+1) table — so the edge padding added for non-tile-aligned
@@ -44,98 +38,48 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.cascade import WINDOW
-from repro.core.integral import CENTRE
+from repro.core.integral import integral_images, window_inv_sigma_grid
 
 from .autotune import DEFAULT_TILE
-from .haar_stage import _INV_AREA
+from .haar_stage import sat_pad_shape, weak_tables, weak_vote
 
 
 def _fused_kernel(rx_ref, rw_ref, th_ref, lv_ref, rv_ref,  # SMEM (prefetch)
-                  img_ref, ii_ref, inv_ref, o_ref,
-                  s2_ref, sc_ref, *, rel_bounds, tile):
+                  bd_ref, ii_ref, inv_ref, o_ref, *, n_run, tile):
     ty, tx = tile
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when((i == 0) & (j == 0))
-    def _build_sats():
-        # all three SATs of the full image, once per image; zero top/left
-        # pad (the integral_image convention) then edge-pad bottom/right
-        # out to the tile-aligned buffer (never read by valid windows)
-        img = img_ref[...]
-        h, w = img.shape
-        hp, wp = ii_ref.shape
-
-        def sat(x):
-            s = jnp.pad(jnp.cumsum(jnp.cumsum(x, axis=0), axis=1),
-                        ((1, 0), (1, 0)))
-            return jnp.pad(s, ((0, hp - h - 1), (0, wp - w - 1)),
-                           mode="edge")
-
-        ii_ref[...] = sat(img)
-        centred = img - CENTRE
-        s2_ref[...] = sat(centred * centred)
-        sc_ref[...] = sat(centred)
-
-    y0 = i * ty
-    x0 = j * tx
-
-    # ---- window inverse-sigma (repro.core.integral.window_inv_sigma) ----
-    def win_sum(ref):
-        a = pl.load(ref, (pl.ds(y0, ty), pl.ds(x0, tx)))
-        b = pl.load(ref, (pl.ds(y0, ty), pl.ds(x0 + WINDOW, tx)))
-        c = pl.load(ref, (pl.ds(y0 + WINDOW, ty), pl.ds(x0, tx)))
-        d = pl.load(ref, (pl.ds(y0 + WINDOW, ty), pl.ds(x0 + WINDOW, tx)))
-        return d - b - c + a             # rect_sum's exact float ordering
-
-    n = float(WINDOW * WINDOW)
-    s2 = win_sum(s2_ref)
-    s1 = win_sum(sc_ref)
-    var = s2 / n - (s1 / n) ** 2
-    inv_sigma = 1.0 / jnp.sqrt(jnp.maximum(var, 1.0))
-    inv_ref[...] = inv_sigma
-
-    # ---- dense stage sums (kernels.haar_stage._stage_kernel) ----
-    def rect_sum(k, r):
-        x = rx_ref[k, r, 0]
-        y = rx_ref[k, r, 1]
-        w = rx_ref[k, r, 2]
-        h = rx_ref[k, r, 3]
-        a = pl.load(ii_ref, (pl.ds(y0 + y, ty), pl.ds(x0 + x, tx)))
-        b = pl.load(ii_ref, (pl.ds(y0 + y, ty), pl.ds(x0 + x + w, tx)))
-        c = pl.load(ii_ref, (pl.ds(y0 + y + h, ty), pl.ds(x0 + x, tx)))
-        d = pl.load(ii_ref, (pl.ds(y0 + y + h, ty), pl.ds(x0 + x + w, tx)))
-        return (d - b) - (c - a)         # haar_stage's exact float ordering
+    y0 = pl.program_id(0) * ty
+    x0 = pl.program_id(1) * tx
+    inv_sigma = inv_ref[...]
 
     def body(k, acc):
-        feat = jnp.zeros(tile, jnp.float32)
-        for r in range(3):               # static unroll: ≤3 rects
-            feat = feat + rw_ref[k, r] * rect_sum(k, r)
-        f_norm = feat * inv_sigma * _INV_AREA
-        vote = jnp.where(f_norm < th_ref[k], lv_ref[k], rv_ref[k])
-        return acc + vote
+        return acc + weak_vote(k, rx_ref, rw_ref, th_ref, lv_ref, rv_ref,
+                               ii_ref, inv_sigma, y0, x0, tile)
 
-    for si in range(len(rel_bounds) - 1):   # static unroll over the run
-        o_ref[si] = jax.lax.fori_loop(
-            rel_bounds[si], rel_bounds[si + 1], body,
-            jnp.zeros(tile, jnp.float32))
+    # rolled over the run's stages (bounds from SMEM): one loop body to
+    # lower and compile however many stages the run holds
+    def stage(si, carry):
+        o_ref[si] = jax.lax.fori_loop(bd_ref[si], bd_ref[si + 1], body,
+                                      jnp.zeros(tile, jnp.float32))
+        return carry
+
+    jax.lax.fori_loop(0, n_run, stage, 0)
 
 
 def fused_head_kernel(rect_xywh: jax.Array, rect_w: jax.Array,
                       wc_threshold: jax.Array, left_val: jax.Array,
                       right_val: jax.Array, rel_bounds: tuple,
                       img: jax.Array, *, tile=DEFAULT_TILE,
-                      interpret: bool = True):
+                      interpret: bool):
     """One-dispatch dense head over a full image.
 
     The weak-classifier arrays cover stages ``[s0, s1)`` of the cascade
     (already sliced by the ops wrapper); ``rel_bounds`` are that run's
     stage boundaries relative to its first weak classifier.  Returns
-    ``(ii, inv_sigma, sums)``: the (H+1, W+1) padded SAT (bit-identical
-    to ``integral_images(img)[0]`` — it feeds the tail's gathers), the
+    ``(ii, inv_sigma, sums)``: the (H+1, W+1) padded SAT (identical to
+    ``integral_images(img)[0]`` — it feeds the tail's gathers), the
     (ny, nx) 1/sigma grid, and (n_run, ny, nx) per-stage vote sums, each
-    bit-identical to the split three-dispatch path.  Handles
-    non-tile-aligned grids by padding and slicing here.
+    bit-identical to the split path.  Handles non-tile-aligned grids by
+    padding and slicing here.
     """
     h, w = img.shape
     ny = h - WINDOW + 1
@@ -144,40 +88,33 @@ def fused_head_kernel(rect_xywh: jax.Array, rect_w: jax.Array,
     ty, tx = tile
     ny_pad = ny + ((-ny) % ty)
     nx_pad = nx + ((-nx) % tx)
-    hp = ny_pad + WINDOW                 # >= h + 1, holds every corner load
-    wp = nx_pad + WINDOW
+    hp, wp = sat_pad_shape(ny_pad, nx_pad)
     rel_bounds = tuple(int(b) for b in rel_bounds)
     n_run = len(rel_bounds) - 1
     assert n_run >= 1, rel_bounds
 
-    kernel = functools.partial(_fused_kernel, rel_bounds=rel_bounds,
-                               tile=tile)
+    ii, pair = integral_images(img)
+    inv = window_inv_sigma_grid(pair, ny, nx, 1, WINDOW)
+    # edge pad: SAT rows/cols and 1/sigma entries no valid window reads
+    ii_p = jnp.pad(ii, ((0, hp - h - 1), (0, wp - w - 1)), mode="edge")
+    inv_p = jnp.pad(inv, ((0, ny_pad - ny), (0, nx_pad - nx)), mode="edge")
+
+    kernel = functools.partial(_fused_kernel, n_run=n_run, tile=tile)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
+        num_scalar_prefetch=6,
         grid=(ny_pad // ty, nx_pad // tx),
         in_specs=[
-            # full image resident in VMEM (constant index map)
-            pl.BlockSpec((h, w), lambda i, j, *_: (0, 0)),
-        ],
-        out_specs=[
-            # the SAT output doubles as the kernel's own working buffer:
-            # written on the first grid step, read by every tile after
+            # the SAT stays resident for the whole grid
             pl.BlockSpec((hp, wp), lambda i, j, *_: (0, 0)),
             pl.BlockSpec((ty, tx), lambda i, j, *_: (i, j)),
-            pl.BlockSpec((n_run, ty, tx), lambda i, j, *_: (0, i, j)),
         ],
-        scratch_shapes=[pltpu.VMEM((hp, wp), jnp.float32),   # centred^2 SAT
-                        pltpu.VMEM((hp, wp), jnp.float32)],  # centred SAT
+        out_specs=pl.BlockSpec((n_run, ty, tx), lambda i, j, *_: (0, i, j)),
     )
-    ii, inv, sums = pl.pallas_call(
+    sums = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((hp, wp), jnp.float32),
-                   jax.ShapeDtypeStruct((ny_pad, nx_pad), jnp.float32),
-                   jax.ShapeDtypeStruct((n_run, ny_pad, nx_pad),
-                                        jnp.float32)],
+        out_shape=jax.ShapeDtypeStruct((n_run, ny_pad, nx_pad), jnp.float32),
         interpret=interpret,
-    )(rect_xywh.astype(jnp.int32), rect_w.astype(jnp.float32),
-      wc_threshold.astype(jnp.float32), left_val.astype(jnp.float32),
-      right_val.astype(jnp.float32), img.astype(jnp.float32))
-    return ii[:h + 1, :w + 1], inv[:ny, :nx], sums[:, :ny, :nx]
+    )(*weak_tables(rect_xywh, rect_w, wc_threshold, left_val, right_val),
+      jnp.asarray(rel_bounds, jnp.int32), ii_p, inv_p)
+    return ii, inv, sums[:, :ny, :nx]

@@ -20,8 +20,6 @@ is 2 reads + 2 writes of the image (the roofline floor for a 2-pass SAT).
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -57,7 +55,7 @@ def _col_scan_kernel(x_ref, o_ref, carry_ref):
 
 
 def integral_image_kernel(img: jax.Array, *, tile=DEFAULT_TILE,
-                          interpret: bool = True) -> jax.Array:
+                          interpret: bool) -> jax.Array:
     """Inclusive 2-D cumsum of ``img`` (H, W) → float32 (H, W).
 
     H and W must be multiples of the tile (the ops.py wrapper pads).
@@ -87,7 +85,3 @@ def integral_image_kernel(img: jax.Array, *, tile=DEFAULT_TILE,
         interpret=interpret,
     )(row)
     return col
-
-
-integral_image_kernel_jit = functools.partial(
-    jax.jit, static_argnames=("tile", "interpret"))(integral_image_kernel)

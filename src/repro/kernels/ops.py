@@ -1,9 +1,9 @@
 """Public jit'd wrappers over the Pallas kernels (with jnp-ref fallback).
 
 All wrappers handle tile padding/unpadding so callers see natural shapes.
-``interpret=True`` (default) executes the kernel bodies in Python on CPU —
-this container has no TPU; the kernels are *written* for TPU (BlockSpec
-VMEM tiling, SMEM scalar prefetch) and validated against ``ref.py``.
+Interpret mode follows the backend (:mod:`repro.kernels.platform`): the
+kernels compile through Mosaic on TPU and run in the Pallas interpreter
+on CPU, where they are validated against ``ref.py``.
 """
 
 from __future__ import annotations
@@ -14,14 +14,15 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.core.cascade import Cascade, WINDOW
+from repro.core.cascade import Cascade
 from . import ref
 from .autotune import DEFAULT_TILE
 from .integral_image import integral_image_kernel
-from .haar_stage import haar_stage_sums_kernel
+from .haar_stage import haar_stage_sums_kernel, sat_pad_shape
 from .window_variance import window_inv_sigma_kernel
 from .packed_window import packed_stage_sums_kernel
 from .fused_head import fused_head_kernel
+from .platform import interpret_mode
 from .tile_change import (tile_change_mask_kernel,
                           changed_window_map_kernel)
 
@@ -45,10 +46,9 @@ def _pad_to(x: jax.Array, mh: int, mw: int, mode: str = "edge") -> jax.Array:
     return jnp.pad(x, cfg, mode=mode)
 
 
-@partial(jax.jit, static_argnames=("tile", "interpret", "use_kernel"))
+@partial(jax.jit, static_argnames=("tile", "use_kernel"))
 def integral_image(img: jax.Array, *, tile=DEFAULT_TILE,
-                   interpret: bool = True, use_kernel: bool = True
-                   ) -> jax.Array:
+                   use_kernel: bool = True) -> jax.Array:
     """Padded SAT (H+1, W+1) of ``img`` — kernel-accelerated version of
     :func:`repro.core.integral.integral_image`."""
     h, w = img.shape
@@ -58,14 +58,13 @@ def integral_image(img: jax.Array, *, tile=DEFAULT_TILE,
         padded = _pad_to(img.astype(jnp.float32), tile[0], tile[1],
                          mode="constant")
         ii = integral_image_kernel(padded, tile=tile,
-                                   interpret=interpret)[:h, :w]
+                                   interpret=interpret_mode())[:h, :w]
     return jnp.pad(ii, ((1, 0), (1, 0)))
 
 
-@partial(jax.jit, static_argnames=("ny", "nx", "tile", "interpret",
-                                   "use_kernel"))
+@partial(jax.jit, static_argnames=("ny", "nx", "tile", "use_kernel"))
 def window_inv_sigma_grid(ii_pair: jax.Array, ny: int, nx: int, *,
-                          tile=DEFAULT_TILE, interpret: bool = True,
+                          tile=DEFAULT_TILE,
                           use_kernel: bool = True) -> jax.Array:
     """(ny, nx) 1/sigma grid from the stacked (ii2, iic) padded SAT pair."""
     ii2, iic = ii_pair[0], ii_pair[1]
@@ -74,20 +73,19 @@ def window_inv_sigma_grid(ii_pair: jax.Array, ny: int, nx: int, *,
     ty, tx = tile
     ny_pad = ny + ((-ny) % ty)
     nx_pad = nx + ((-nx) % tx)
-    need_h = ny_pad + WINDOW + 1
-    need_w = nx_pad + WINDOW + 1
+    need_h, need_w = sat_pad_shape(ny_pad, nx_pad)
     pad_h = max(0, need_h - ii2.shape[0])
     pad_w = max(0, need_w - ii2.shape[1])
     ii2p = jnp.pad(ii2, ((0, pad_h), (0, pad_w)), mode="edge")
     iicp = jnp.pad(iic, ((0, pad_h), (0, pad_w)), mode="edge")
     out = window_inv_sigma_kernel(ii2p, iicp, ny_pad, nx_pad, tile=tile,
-                                  interpret=interpret)
+                                  interpret=interpret_mode())
     return out[:ny, :nx]
 
 
 def dense_stage_sums(cascade: Cascade, cascade_static: Cascade, s: int,
                      ii: jax.Array, inv_sigma_grid: jax.Array, *,
-                     tile=DEFAULT_TILE, interpret: bool = True) -> jax.Array:
+                     tile=DEFAULT_TILE) -> jax.Array:
     """Stage-``s`` vote sums over the dense stride-1 window grid.
 
     ``cascade`` carries (possibly traced) parameter arrays; the *static*
@@ -99,8 +97,9 @@ def dense_stage_sums(cascade: Cascade, cascade_static: Cascade, s: int,
     ty, tx = tile
     ny_pad = ny + ((-ny) % ty)
     nx_pad = nx + ((-nx) % tx)
-    pad_h = max(0, ny_pad + WINDOW + 1 - ii.shape[0])
-    pad_w = max(0, nx_pad + WINDOW + 1 - ii.shape[1])
+    need_h, need_w = sat_pad_shape(ny_pad, nx_pad)
+    pad_h = max(0, need_h - ii.shape[0])
+    pad_w = max(0, need_w - ii.shape[1])
     iip = jnp.pad(ii, ((0, pad_h), (0, pad_w)), mode="edge")
     invp = jnp.pad(inv_sigma_grid,
                    ((0, ny_pad - ny), (0, nx_pad - nx)), mode="edge")
@@ -108,7 +107,7 @@ def dense_stage_sums(cascade: Cascade, cascade_static: Cascade, s: int,
         cascade.rect_xywh[k0:k1], cascade.rect_w[k0:k1],
         cascade.wc_threshold[k0:k1], cascade.left_val[k0:k1],
         cascade.right_val[k0:k1], iip, invp, tile=tile,
-        interpret=interpret)
+        interpret=interpret_mode())
     return out[:ny, :nx]
 
 
@@ -132,10 +131,9 @@ def dense_stage_sums_ref(cascade: Cascade, cascade_static: Cascade, s: int,
 # padding hoisted out so it is computed once per call, not once per image.
 # Oracle twins live in kernels/ref.py (``*_batch_ref``).
 
-@partial(jax.jit, static_argnames=("tile", "interpret", "use_kernel"))
+@partial(jax.jit, static_argnames=("tile", "use_kernel"))
 def integral_image_batch(imgs: jax.Array, *, tile=DEFAULT_TILE,
-                         interpret: bool = True, use_kernel: bool = True
-                         ) -> jax.Array:
+                         use_kernel: bool = True) -> jax.Array:
     """(B, H, W) -> (B, H+1, W+1) padded SATs (batched
     :func:`integral_image`, same per-image contract)."""
     _, h, w = imgs.shape
@@ -145,14 +143,13 @@ def integral_image_batch(imgs: jax.Array, *, tile=DEFAULT_TILE,
         padded = _pad_to(imgs.astype(jnp.float32), tile[0], tile[1],
                          mode="constant")
         ii = jax.vmap(lambda im: integral_image_kernel(
-            im, tile=tile, interpret=interpret))(padded)[:, :h, :w]
+            im, tile=tile, interpret=interpret_mode()))(padded)[:, :h, :w]
     return jnp.pad(ii, ((0, 0), (1, 0), (1, 0)))
 
 
-@partial(jax.jit, static_argnames=("ny", "nx", "tile", "interpret",
-                                   "use_kernel"))
+@partial(jax.jit, static_argnames=("ny", "nx", "tile", "use_kernel"))
 def window_inv_sigma_grid_batch(ii_pairs: jax.Array, ny: int, nx: int, *,
-                                tile=DEFAULT_TILE, interpret: bool = True,
+                                tile=DEFAULT_TILE,
                                 use_kernel: bool = True) -> jax.Array:
     """(B, ny, nx) 1/sigma grids from stacked (B, 2, H+1, W+1) SAT pairs
     (batched :func:`window_inv_sigma_grid`, same per-image contract)."""
@@ -162,20 +159,21 @@ def window_inv_sigma_grid_batch(ii_pairs: jax.Array, ny: int, nx: int, *,
     ty, tx = tile
     ny_pad = ny + ((-ny) % ty)
     nx_pad = nx + ((-nx) % tx)
-    pad_h = max(0, ny_pad + WINDOW + 1 - ii2.shape[1])
-    pad_w = max(0, nx_pad + WINDOW + 1 - ii2.shape[2])
+    need_h, need_w = sat_pad_shape(ny_pad, nx_pad)
+    pad_h = max(0, need_h - ii2.shape[1])
+    pad_w = max(0, need_w - ii2.shape[2])
     cfg = ((0, 0), (0, pad_h), (0, pad_w))
     ii2p = jnp.pad(ii2, cfg, mode="edge")
     iicp = jnp.pad(iic, cfg, mode="edge")
     out = jax.vmap(lambda a, b: window_inv_sigma_kernel(
-        a, b, ny_pad, nx_pad, tile=tile, interpret=interpret))(ii2p, iicp)
+        a, b, ny_pad, nx_pad, tile=tile, interpret=interpret_mode())
+                   )(ii2p, iicp)
     return out[:, :ny, :nx]
 
 
 def dense_stage_sums_batch(cascade: Cascade, cascade_static: Cascade, s: int,
                            ii: jax.Array, inv_sigma_grid: jax.Array, *,
-                           tile=DEFAULT_TILE, interpret: bool = True
-                           ) -> jax.Array:
+                           tile=DEFAULT_TILE) -> jax.Array:
     """(B, ny, nx) stage-``s`` vote sums over a stack of dense stride-1
     window grids — batched :func:`dense_stage_sums`: ``ii`` is (B, H+1, W+1)
     padded SATs, ``inv_sigma_grid`` is (B, ny, nx)."""
@@ -185,8 +183,9 @@ def dense_stage_sums_batch(cascade: Cascade, cascade_static: Cascade, s: int,
     ty, tx = tile
     ny_pad = ny + ((-ny) % ty)
     nx_pad = nx + ((-nx) % tx)
-    pad_h = max(0, ny_pad + WINDOW + 1 - ii.shape[1])
-    pad_w = max(0, nx_pad + WINDOW + 1 - ii.shape[2])
+    need_h, need_w = sat_pad_shape(ny_pad, nx_pad)
+    pad_h = max(0, need_h - ii.shape[1])
+    pad_w = max(0, need_w - ii.shape[2])
     iip = jnp.pad(ii, ((0, 0), (0, pad_h), (0, pad_w)), mode="edge")
     invp = jnp.pad(inv_sigma_grid,
                    ((0, 0), (0, ny_pad - ny), (0, nx_pad - nx)), mode="edge")
@@ -194,20 +193,20 @@ def dense_stage_sums_batch(cascade: Cascade, cascade_static: Cascade, s: int,
         cascade.rect_xywh[k0:k1], cascade.rect_w[k0:k1],
         cascade.wc_threshold[k0:k1], cascade.left_val[k0:k1],
         cascade.right_val[k0:k1], ii_b, inv_b, tile=tile,
-        interpret=interpret))(iip, invp)
+        interpret=interpret_mode()))(iip, invp)
     return out[:, :ny, :nx]
 
 
 # -------------------------------------------------------------------- fused
-# One-dispatch dense head: SAT + 1/sigma + every dense stage's vote sums
-# from a single fused_head_kernel call (kernels/fused_head.py), with the
-# intermediates resident in VMEM.  Bit-identical to the split three-dispatch
-# path (integral_images -> window_inv_sigma -> dense_stage_sums per stage),
-# which is what Detector executes when the plan's head mode is "split".
+# One-dispatch dense head: every dense stage's vote sums from a single
+# fused_head_kernel call (kernels/fused_head.py), with the SAT and 1/sigma
+# grid — the split path's own XLA ops — resident in VMEM.  Bit-identical to
+# the split path (integral_images -> window_inv_sigma -> one
+# dense_stage_sums dispatch per stage), which is what Detector executes
+# when the plan's head mode is "split".
 
 def fused_head(cascade: Cascade, cascade_static: Cascade, s0: int, s1: int,
-               img: jax.Array, *, tile=DEFAULT_TILE,
-               interpret: bool = True):
+               img: jax.Array, *, tile=DEFAULT_TILE):
     """Fused dense head for stages ``[s0, s1)`` over one image.
 
     Returns ``(ii, inv_sigma_grid, stage_sums)``: the (H+1, W+1) padded
@@ -219,7 +218,8 @@ def fused_head(cascade: Cascade, cascade_static: Cascade, s0: int, s1: int,
     return fused_head_kernel(
         cascade.rect_xywh[k0:k1], cascade.rect_w[k0:k1],
         cascade.wc_threshold[k0:k1], cascade.left_val[k0:k1],
-        cascade.right_val[k0:k1], rel, img, tile=tile, interpret=interpret)
+        cascade.right_val[k0:k1], rel, img, tile=tile,
+        interpret=interpret_mode())
 
 
 def fused_head_ref(cascade: Cascade, cascade_static: Cascade, s0: int,
@@ -233,8 +233,7 @@ def fused_head_ref(cascade: Cascade, cascade_static: Cascade, s0: int,
 
 
 def fused_head_batch(cascade: Cascade, cascade_static: Cascade, s0: int,
-                     s1: int, imgs: jax.Array, *, tile=DEFAULT_TILE,
-                     interpret: bool = True):
+                     s1: int, imgs: jax.Array, *, tile=DEFAULT_TILE):
     """(B, H, W) stack -> batched :func:`fused_head` (same per-image
     contract): ``(B, H+1, W+1)`` SATs, ``(B, ny, nx)`` 1/sigma grids,
     ``(B, s1-s0, ny, nx)`` stage sums.  vmap lifts the batch axis into an
@@ -244,7 +243,7 @@ def fused_head_batch(cascade: Cascade, cascade_static: Cascade, s0: int,
         cascade.rect_xywh[k0:k1], cascade.rect_w[k0:k1],
         cascade.wc_threshold[k0:k1], cascade.left_val[k0:k1],
         cascade.right_val[k0:k1], rel, im, tile=tile,
-        interpret=interpret))(imgs.astype(jnp.float32))
+        interpret=interpret_mode()))(imgs.astype(jnp.float32))
 
 
 def fused_head_batch_ref(cascade: Cascade, cascade_static: Cascade, s0: int,
@@ -276,7 +275,7 @@ def packed_stage_sums(cascade: Cascade, cascade_static: Cascade, s0: int,
                       s1: int, ii_flat: jax.Array, img: jax.Array,
                       base: jax.Array, stride: jax.Array, ys: jax.Array,
                       xs: jax.Array, inv_sigma: jax.Array, *,
-                      tile=DEFAULT_TILE, interpret: bool = True) -> jax.Array:
+                      tile=DEFAULT_TILE) -> jax.Array:
     """Stage sums for stages ``[s0, s1)`` over a packed window list.
 
     ``ii_flat`` is (B, sum_l (h_l+1)*(w_l+1)) — every level's SAT flattened
@@ -308,7 +307,7 @@ def packed_stage_sums(cascade: Cascade, cascade_static: Cascade, s0: int,
         cascade.right_val[k0:k1], rel, sat_flat,
         blocks(off, jnp.int32), blocks(stride, jnp.int32),
         blocks(ys, jnp.int32), blocks(xs, jnp.int32),
-        blocks(inv_sigma, jnp.float32), tile=tile, interpret=interpret)
+        blocks(inv_sigma, jnp.float32), tile=tile, interpret=interpret_mode())
     return out.reshape(s1 - s0, cap_pad)[:, :cap]
 
 

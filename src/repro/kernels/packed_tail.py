@@ -44,7 +44,8 @@ import jax.numpy as jnp
 
 from repro.core.cascade import Cascade, WINDOW
 
-__all__ = ["BACKENDS", "stage_sums", "select_backend", "measure_rungs"]
+__all__ = ["BACKENDS", "stage_sums", "select_backend", "tail_backends",
+           "measure_rungs"]
 
 _AREA = float(WINDOW * WINDOW)
 
@@ -53,6 +54,11 @@ BACKENDS = ("gather", "bulk", "pallas")
 # capacity-ladder sizes at which measure_rungs races the backends; chosen to
 # bracket the real ladders (BATCH_CAP_FLOOR=128 .. stream rung doublings)
 DEFAULT_RUNG_SIZES = (128, 512, 2048, 8192)
+
+# lanes per bulk-gather block: each (K, 3, lanes) corner temporary stays
+# under ~K * 3 * 256 KiB, where a whole calibrated VGA batch list of
+# millions of lanes would need gigabytes of device memory per gather
+BULK_LANE_BLOCK = 1 << 16
 
 
 def _gather_stage_sum(cascade: Cascade, ii_flat: jax.Array, img: jax.Array,
@@ -101,9 +107,31 @@ def _bulk_stage_sum(cascade: Cascade, ii_flat: jax.Array, img: jax.Array,
     ascending-``k`` order), but restructured for XLA: instead of a
     ``fori_loop`` issuing 12 tiny gathers per weak classifier, all
     ``K = k1 - k0`` weak classifiers' corner lookups are batched into 4
-    gathers of shape (K, 3, cap).  ``k0``/``k1`` must be Python ints
-    (stage bounds are static).
+    gathers of shape (K, 3, lanes).  Lists longer than
+    :data:`BULK_LANE_BLOCK` run block by block (``lax.map``), so those
+    temporaries stay bounded at any capacity.  ``k0``/``k1`` must be
+    Python ints (stage bounds are static).
     """
+    cap = ys.shape[0]
+    if cap <= BULK_LANE_BLOCK:
+        return _bulk_block(cascade, ii_flat, img, base, stride, ys, xs,
+                           inv_sigma, k0, k1)
+    n = -(-cap // BULK_LANE_BLOCK)
+
+    def blocks(v):          # padded lanes address SAT entry 0; dropped below
+        return jnp.pad(v, (0, n * BULK_LANE_BLOCK - cap)).reshape(
+            n, BULK_LANE_BLOCK)
+
+    out = jax.lax.map(
+        lambda a: _bulk_block(cascade, ii_flat, *a, k0, k1),
+        tuple(blocks(v) for v in (img, base, stride, ys, xs, inv_sigma)))
+    return out.reshape(-1)[:cap]
+
+
+def _bulk_block(cascade: Cascade, ii_flat: jax.Array, img: jax.Array,
+                base: jax.Array, stride: jax.Array, ys: jax.Array,
+                xs: jax.Array, inv_sigma: jax.Array,
+                k0: int, k1: int) -> jax.Array:
     rects = cascade.rect_xywh[k0:k1]            # (K, 3, 4) int32
     w = cascade.rect_w[k0:k1]                   # (K, 3)
     rx = rects[:, :, 0][:, :, None]
@@ -137,7 +165,7 @@ def stage_sums(cascade: Cascade, cascade_static: Cascade, s0: int, s1: int,
                ii_flat: jax.Array, img: jax.Array, base: jax.Array,
                stride: jax.Array, ys: jax.Array, xs: jax.Array,
                inv_sigma: jax.Array, *, backend: str = "bulk",
-               tile: tuple = (), interpret: bool = True) -> jax.Array:
+               tile: tuple = ()) -> jax.Array:
     """(s1 - s0, cap) vote sums for stages ``[s0, s1)`` over a packed list.
 
     One call per tail *segment*: stage thresholds are applied by the
@@ -156,7 +184,7 @@ def stage_sums(cascade: Cascade, cascade_static: Cascade, s0: int, s1: int,
         kw = {"tile": tuple(tile)} if tile else {}
         return ops.packed_stage_sums(
             cascade, cascade_static, s0, s1, ii_flat, img, base, stride,
-            ys, xs, inv_sigma, interpret=interpret, **kw)
+            ys, xs, inv_sigma, **kw)
     bounds = np.asarray(cascade_static.stage_offsets)
     if backend == "bulk":
         fn = _bulk_stage_sum
@@ -169,6 +197,15 @@ def stage_sums(cascade: Cascade, cascade_static: Cascade, s0: int, s1: int,
         fn(cascade, ii_flat, img, base, stride, ys, xs, inv_sigma,
            int(bounds[s]), int(bounds[s + 1]))
         for s in range(s0, s1)])
+
+
+def tail_backends() -> tuple[str, ...]:
+    """The backends that run on this process's JAX backend: Mosaic refuses
+    the packed-window kernel (``packed_window.MOSAIC_REFUSAL``), so
+    ``pallas`` runs only in the CPU interpreter."""
+    if jax.default_backend() == "cpu":
+        return BACKENDS
+    return tuple(b for b in BACKENDS if b != "pallas")
 
 
 def select_backend(config, n_windows: int) -> str:
@@ -246,9 +283,8 @@ def _build_workload(workload, rng):
     return ii_flat, sample, n_windows
 
 
-def measure_rungs(cascade: Cascade, *, interpret: bool = True,
-                  sizes: tuple = DEFAULT_RUNG_SIZES, repeats: int = 3,
-                  inner: int = 10, seed: int = 0,
+def measure_rungs(cascade: Cascade, *, sizes: tuple = DEFAULT_RUNG_SIZES,
+                  repeats: int = 3, inner: int = 10, seed: int = 0,
                   workload: list | None = None) -> dict:
     """Race the packed-tail backends at capacity-ladder sizes.
 
@@ -273,6 +309,7 @@ def measure_rungs(cascade: Cascade, *, interpret: bool = True,
 
     ``crossover`` is the smallest rung won by the Pallas kernel (-1 if it
     never wins — a legitimate outcome on hardware where gathers are cheap).
+    Only :func:`tail_backends` race, so on TPU ``pallas`` is left out.
     """
     rng = np.random.default_rng(seed)
     if workload is None:
@@ -280,15 +317,16 @@ def measure_rungs(cascade: Cascade, *, interpret: bool = True,
                      1.0)]
     ii_flat, sample, n_windows = _build_workload(workload, rng)
     n_stages = cascade.n_stages
-    ms: dict[str, list] = {b: [] for b in BACKENDS}
+    backends = tail_backends()
+    ms: dict[str, list] = {b: [] for b in backends}
 
     for size in sizes:
         imgi, base, stride, ys, xs, inv = sample(size)
-        for bk in BACKENDS:
+        for bk in backends:
             # repro: ignore[JIT_CACHE] bench harness: one fresh jitted fn per (size, backend) point is the measurement unit; compile cost is excluded by the warm-up call below
             fn = jax.jit(lambda c, iif, iv, _bk=bk: stage_sums(
                 c, cascade, 0, n_stages, iif, imgi, base, stride, ys, xs,
-                iv, backend=_bk, interpret=interpret))
+                iv, backend=_bk))
             jax.block_until_ready(fn(cascade, ii_flat, inv))   # compile
             best = float("inf")
             for _ in range(repeats):
@@ -300,7 +338,7 @@ def measure_rungs(cascade: Cascade, *, interpret: bool = True,
             ms[bk].append(best * 1e3)
 
     rungs = tuple(
-        (size, min(BACKENDS, key=lambda b: ms[b][i]))
+        (size, min(backends, key=lambda b: ms[b][i]))
         for i, size in enumerate(sizes))
     crossover = next((size for size, bk in rungs if bk == "pallas"), -1)
     return {"sizes": list(sizes), "n_windows": n_windows,

@@ -28,10 +28,12 @@ gather oracle bit-for-bit: same corner combination order
 ``(d - b - c + a)``, same ``feat * inv_sigma / AREA`` normalization, weak
 votes summed in ascending-``k`` order within each stage.
 
-Validated in interpret mode (CPU container).  On real TPU the wholesale
-SMEM reads and the in-kernel index-loads lower through Mosaic's dynamic
-gather; like the rest of this package, the BlockSpec/SMEM layout is
-written for TPU but awaits on-hardware validation (see ROADMAP).
+Runs in the Pallas interpreter only.  Mosaic refuses it for TPU: the
+wholesale reads of the scalar-prefetch refs load vectors from SMEM, and
+``jnp.take`` over the flat SAT is a table lookup, while Mosaic's gather
+only permutes within a 2-D vector.  So on TPU a ``"pallas"`` tail raises
+:data:`MOSAIC_REFUSAL` instead of running (it is never swapped for
+another backend), and the tail tuners leave this backend out there.
 """
 
 from __future__ import annotations
@@ -46,7 +48,18 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.cascade import WINDOW
 
 from .autotune import DEFAULT_TILE
+
 _AREA = float(WINDOW * WINDOW)
+
+# what Mosaic (jax 0.9.0, libtpu 0.0.34) says when compiling this kernel
+# for TPU v5e, first for the SMEM reads and then, with those unrolled, for
+# the flat-SAT lookups; tests/test_tpu_compile.py checks it still does
+MOSAIC_REFUSAL = (
+    "the packed-window Pallas kernel does not compile for TPU: Mosaic "
+    "refuses its whole-array scalar-prefetch reads ('Can only load scalars "
+    "from SMEM') and its flat-SAT lookups ('Only 2D gather is "
+    "supported'); use tail_backend 'bulk' or 'gather', or tail_rungs "
+    "measured on this backend")
 
 
 def _packed_kernel(rx_ref, rw_ref, th_ref, lv_ref, rv_ref,  # SMEM (prefetch)
@@ -98,15 +111,27 @@ def packed_stage_sums_kernel(rect_xywh: jax.Array, rect_w: jax.Array,
                              sat_flat: jax.Array, off: jax.Array,
                              stride: jax.Array, ys: jax.Array, xs: jax.Array,
                              inv_sigma: jax.Array, *, tile=DEFAULT_TILE,
-                             interpret: bool = True) -> jax.Array:
+                             interpret: bool) -> jax.Array:
     """Stage-run vote sums over a blocked packed window list.
 
     sat_flat: (1, N) every image's every level's SAT, flattened+concatenated.
     off/stride/ys/xs: (n_rows, tx) int32 per-window addressing, tile-aligned
       (``n_rows`` a multiple of ``tile[0]``; the ops wrapper pads).
     inv_sigma: (n_rows, tx) float32 normalization.
-    Returns (n_stages_run, n_rows, tx) float32 stage sums.
+    Returns (n_stages_run, n_rows, tx) float32 stage sums.  Raises
+    ``NotImplementedError(MOSAIC_REFUSAL)`` when asked to compile
+    (``interpret=False``).
     """
+    if not interpret:
+        raise NotImplementedError(MOSAIC_REFUSAL)
+    return _packed_call(rect_xywh, rect_w, wc_threshold, left_val,
+                        right_val, rel_bounds, sat_flat, off, stride, ys, xs,
+                        inv_sigma, tile=tile, interpret=interpret)
+
+
+def _packed_call(rect_xywh, rect_w, wc_threshold, left_val, right_val,
+                 rel_bounds, sat_flat, off, stride, ys, xs, inv_sigma, *,
+                 tile, interpret: bool) -> jax.Array:
     n_rows, tx = off.shape
     ty = tile[0]
     assert tx == tile[1] and n_rows % ty == 0, (off.shape, tile)

@@ -4,8 +4,9 @@ Replaces the reference code's per-window ``int_sqrt`` (11–13 % of the
 paper's profile, Fig. 13).  For a stride-1 grid of 24x24 windows, the
 window sums of the centred image and its square are four constant-shift
 slices of each SAT (same trick as the Haar kernel, with *static* offsets
-0 and 24 — no scalar prefetch needed), followed by an element-wise
-``rsqrt`` on the VPU.  Output is 1/sigma with sigma clamped to >= 1
+0 and 24 — no scalar prefetch needed; the 24-lane shift is a register
+rotate of an aligned slab, as in the Haar kernel), followed by an
+element-wise ``rsqrt`` on the VPU.  Output is 1/sigma with sigma clamped to >= 1
 (paper Eq. 5 plus the reference implementation's flat-window guard).
 """
 
@@ -20,6 +21,8 @@ from jax.experimental import pallas as pl
 from repro.core.cascade import WINDOW
 
 from .autotune import DEFAULT_TILE
+from .haar_stage import col_shift, row_band, sat_pad_shape
+
 _N = float(WINDOW * WINDOW)
 
 
@@ -29,10 +32,12 @@ def _inv_sigma_kernel(ii2_ref, iic_ref, o_ref, *, tile):
     x0 = pl.program_id(1) * tx
 
     def window_sum(ref):
-        a = pl.load(ref, (pl.ds(y0, ty), pl.ds(x0, tx)))
-        b = pl.load(ref, (pl.ds(y0, ty), pl.ds(x0 + WINDOW, tx)))
-        c = pl.load(ref, (pl.ds(y0 + WINDOW, ty), pl.ds(x0, tx)))
-        d = pl.load(ref, (pl.ds(y0 + WINDOW, ty), pl.ds(x0 + WINDOW, tx)))
+        top = row_band(ref, y0, x0, 0, tile)
+        bot = row_band(ref, y0, x0, WINDOW, tile)
+        a = top[:, :tx]
+        b = col_shift(top, WINDOW, tx)
+        c = bot[:, :tx]
+        d = col_shift(bot, WINDOW, tx)
         return (d - b) - (c - a)
 
     s2 = window_sum(ii2_ref)
@@ -43,12 +48,14 @@ def _inv_sigma_kernel(ii2_ref, iic_ref, o_ref, *, tile):
 
 def window_inv_sigma_kernel(ii2_padded: jax.Array, iic_padded: jax.Array,
                             ny: int, nx: int, *, tile=DEFAULT_TILE,
-                            interpret: bool = True) -> jax.Array:
-    """(ny, nx) inv-sigma grid; ny/nx must be tile-aligned (wrapper pads)."""
+                            interpret: bool) -> jax.Array:
+    """(ny, nx) inv-sigma grid; ny/nx must be tile-aligned and the SATs
+    padded to :func:`~repro.kernels.haar_stage.sat_pad_shape` (wrapper
+    pads)."""
     ty, tx = tile
     assert ny % ty == 0 and nx % tx == 0
-    assert ii2_padded.shape[0] >= ny + WINDOW
-    assert ii2_padded.shape[1] >= nx + WINDOW
+    need_h, need_w = sat_pad_shape(ny, nx)
+    assert ii2_padded.shape[0] >= need_h and ii2_padded.shape[1] >= need_w
 
     kernel = functools.partial(_inv_sigma_kernel, tile=tile)
     return pl.pallas_call(

@@ -11,6 +11,7 @@ jax device state."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_smoke_mesh", "mesh_chips"]
 
@@ -18,13 +19,14 @@ __all__ = ["make_production_mesh", "make_smoke_mesh", "mesh_chips"]
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_smoke_mesh(data: int = 2, model: int = 4):
     """Small mesh for CPU distributed tests (needs
     xla_force_host_platform_device_count ≥ data·model)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def mesh_chips(mesh) -> int:

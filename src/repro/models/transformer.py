@@ -33,14 +33,6 @@ from .layers import init_norm, apply_norm, init_gated_mlp, gated_mlp, \
 
 __all__ = ["Model", "build_model", "param_count"]
 
-# jax.shard_map is only a top-level alias on newer jax; fall back to the
-# experimental home it has on the pinned toolchain.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
 # ----------------------------------------------------------------- grouping
 def layer_groups(cfg: ModelConfig):
     """(prelude_kinds, superblock_kinds, n_scan, postlude_kinds)."""
@@ -297,8 +289,8 @@ class Model:
                          "wo": P("model", None, dp)},
                         P(None, None, None))
             out_specs = (P(None, None, dp), P())
-            return _shard_map(local2d, mesh=r.mesh, in_specs=in_specs,
-                              out_specs=out_specs)(p, x)
+            return jax.shard_map(local2d, mesh=r.mesh, in_specs=in_specs,
+                                 out_specs=out_specs)(p, x)
 
         def local(pp, xx):
             y, aux = moe_mod.moe_ffn(pp, xx, cfg, axis_name="model",
@@ -313,8 +305,8 @@ class Model:
                      "wo": P("model", None, None)},
                     P(dp, None, None))
         out_specs = (P(dp, None, None), P())
-        return _shard_map(local, mesh=r.mesh, in_specs=in_specs,
-                          out_specs=out_specs)(p, x)
+        return jax.shard_map(local, mesh=r.mesh, in_specs=in_specs,
+                             out_specs=out_specs)(p, x)
 
     def _embed(self, params, tokens, prefix_embeds=None):
         cfg, r = self.cfg, self.rules

@@ -83,10 +83,12 @@ def rate_weighted_split(n_items: int, rates: Sequence[float],
     for i in order[:rem]:
         base[i] += 1
     shares = tuple(int(b) * quantum for b in base)
-    # any leftover (n_items % quantum) goes to the fastest pod
+    # any leftover (n_items % quantum) goes to the fastest pod; among
+    # equally fast pods, to the one the rounding gave least
     left = n_items - sum(shares)
     if left:
-        fast = int(np.argmax(rates))
+        fastest = np.flatnonzero(rates == rates.max())
+        fast = int(fastest[np.argmin(base[fastest])])
         shares = tuple(s + left if i == fast else s
                        for i, s in enumerate(shares))
     return HeteroPodPlan(names, tuple(float(r) for r in rates), shares,

@@ -64,6 +64,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 import repro.plan as planlib
+from repro.core.engine import CapacityOverflow
 from repro.scheduling.dvfs import (GovernorDecision, binding_slo,
                                    evaluate_operating_points,
                                    select_operating_points)
@@ -694,10 +695,11 @@ class DetectorService:
             try:
                 rects = self.detector.detect_batch(images,
                                                    strategy=self.strategy)
-            except Exception:                      # noqa: BLE001
-                # overflow (or any pathological input) somewhere in the
-                # batch: isolate per image so one bad request completes
-                # with an error instead of failing its whole flush
+            except CapacityOverflow:
+                # overflow somewhere in the batch: isolate per image so one
+                # crowded request completes with an error instead of
+                # failing its whole flush (compile and runtime errors of
+                # the program itself propagate)
                 rects = []
                 for r in chunk:
                     try:
@@ -838,7 +840,7 @@ class DetectorService:
             try:
                 levels = self.detector.detect_batch_raw(
                     [frame for _fr, frame, _dev in chunk])
-            except Exception:                  # noqa: BLE001
+            except CapacityOverflow:
                 levels = None                  # isolate per frame below
         for i, (fr, frame, dev_frame) in enumerate(chunk):
             try:

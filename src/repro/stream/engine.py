@@ -167,7 +167,6 @@ class StreamEngine:
         cap, backend = seg.capacity, seg.backend
         n_slots = plan.n_slots
         cascade_static = det.cascade
-        interpret = det.config.interpret
         self.program_builds += 1
         layout = plan.layout
         lvl_of_slot = jnp.asarray(layout.lvl_of_slot)
@@ -210,7 +209,7 @@ class StreamEngine:
             ss_run = packed_tail.stage_sums(
                 cascade, cascade_static, seg.s0, seg.s1, ii_flat, b_sel,
                 base_sel, stride_sel, y_sel, x_sel, inv_sel,
-                backend=backend, tile=plan.lane_block, interpret=interpret)
+                backend=backend, tile=plan.lane_block)
             for j, s in enumerate(range(seg.s0, seg.s1)):
                 valid = valid & (ss_run[j] >= cascade.stage_threshold[s])
             # scatter survivors back onto the full (B, n_slots) grid; dead
@@ -331,7 +330,6 @@ class StreamEngine:
         cap, backend = seg.capacity, seg.backend
         n_slots = plan.n_slots
         cascade_static = det.cascade
-        interpret = det.config.interpret
         self.program_builds += 1
         layout = plan.layout
         lvl_of_slot = jnp.asarray(layout.lvl_of_slot)
@@ -422,8 +420,7 @@ class StreamEngine:
                 ss_run = packed_tail.stage_sums(
                     cascade, cascade_static, seg.s0, seg.s1, ii_flat,
                     b_sel, base_sel, stride_sel, y_sel, x_sel, inv_sel,
-                    backend=backend, tile=plan.lane_block,
-                    interpret=interpret)
+                    backend=backend, tile=plan.lane_block)
                 for j, s in enumerate(range(seg.s0, seg.s1)):
                     valid = valid & (ss_run[j] >= cascade.stage_threshold[s])
                 target = jnp.where(valid, sel, n_slots)

@@ -115,19 +115,16 @@ def test_compressed_psum_correct():
     r = run_in_mesh_subprocess("""
         from repro.distributed.compression import compressed_psum
         from jax.sharding import PartitionSpec as P
-        mesh = jax.make_mesh((8,), ("d",))
+        mesh = jax.make_mesh((8,), ("d",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         x = jnp.asarray(np.random.default_rng(0)
                         .standard_normal((8, 64)), jnp.float32)
 
         def f(x):
             return compressed_psum(x, "d")
 
-        try:
-            shard_map = jax.shard_map
-        except AttributeError:
-            from jax.experimental.shard_map import shard_map
-        y = jax.jit(shard_map(f, mesh=mesh, in_specs=P("d"),
-                              out_specs=P("d")))(x)
+        y = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("d"),
+                                  out_specs=P("d")))(x)
         # compressed mean-psum ≈ plain mean over the axis
         want = jnp.broadcast_to(x.reshape(8, 1, 64).mean(0), (8, 1, 64))
         want = want.reshape(8, 64)

@@ -1,5 +1,6 @@
-"""Pallas kernels vs pure-jnp oracle: shape/dtype sweeps (hypothesis) in
-interpret mode (CPU container; kernels target TPU BlockSpec tiling)."""
+"""Pallas kernels vs pure-jnp oracle: shape/dtype sweeps (hypothesis).
+Under ``JAX_PLATFORMS=cpu`` the kernels run in the Pallas interpreter;
+tests/test_tpu_compile.py compiles them for TPU."""
 
 import numpy as np
 import jax
@@ -21,7 +22,7 @@ CASC, _ = load_cascade(DEFAULT_PRETRAINED)
 def test_integral_image_kernel_matches_ref(h, w, scale):
     rng = np.random.default_rng(h * 1000 + w)
     img = jnp.asarray(rng.random((h, w), np.float32) * scale)
-    got = ops.integral_image(img, interpret=True, use_kernel=True)
+    got = ops.integral_image(img, use_kernel=True)
     want = ops.integral_image(img, use_kernel=False)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-2 * scale)
@@ -51,7 +52,7 @@ def test_haar_stage_kernel_matches_ref(stage, hw):
     ii, ii_pair = integral_images(img)
     ny, nx = h - 24 + 1, w - 24 + 1
     inv = ops.window_inv_sigma_grid(ii_pair, ny, nx, use_kernel=False)
-    got = ops.dense_stage_sums(CASC, CASC, stage, ii, inv, interpret=True)
+    got = ops.dense_stage_sums(CASC, CASC, stage, ii, inv)
     want = ops.dense_stage_sums_ref(CASC, CASC, stage, ii, inv)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-3)
@@ -60,8 +61,7 @@ def test_haar_stage_kernel_matches_ref(stage, hw):
 def test_integral_image_property_last_cell_is_total():
     rng = np.random.default_rng(0)
     img = rng.integers(0, 255, (48, 64)).astype(np.float32)
-    ii = np.asarray(ops.integral_image(jnp.asarray(img), use_kernel=True,
-                                       interpret=True))
+    ii = np.asarray(ops.integral_image(jnp.asarray(img), use_kernel=True))
     assert abs(ii[-1, -1] - img.sum()) < 1e-2 * img.size
     assert (ii[0] == 0).all() and (ii[:, 0] == 0).all()
 
@@ -84,7 +84,7 @@ def test_dense_stage_sums_all_stages_match_ref(stage):
     ii, ii_pair = integral_images(img)
     ny, nx = h - 24 + 1, w - 24 + 1
     inv = ops.window_inv_sigma_grid(ii_pair, ny, nx, use_kernel=False)
-    got = ops.dense_stage_sums(CASC, CASC, stage, ii, inv, interpret=True)
+    got = ops.dense_stage_sums(CASC, CASC, stage, ii, inv)
     want = ops.dense_stage_sums_ref(CASC, CASC, stage, ii, inv)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-3)
@@ -92,29 +92,27 @@ def test_dense_stage_sums_all_stages_match_ref(stage):
 
 def test_integral_image_batch_matches_ref():
     imgs, _, _ = _batch_inputs(3, 37, 61)     # non-tile-aligned H and W
-    got = ops.integral_image_batch(imgs, interpret=True, use_kernel=True)
+    got = ops.integral_image_batch(imgs, use_kernel=True)
     want = ops.integral_image_batch(imgs, use_kernel=False)
     assert got.shape == (3, 38, 62)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=2.0)
     # per-slice equal to the single-image wrapper (same contract)
     for i in range(3):
-        one = ops.integral_image(imgs[i], interpret=True, use_kernel=True)
+        one = ops.integral_image(imgs[i], use_kernel=True)
         np.testing.assert_array_equal(np.asarray(got[i]), np.asarray(one))
 
 
 def test_window_inv_sigma_batch_matches_ref():
     _, _, pair = _batch_inputs(2, 45, 70, seed=3)
     ny, nx = 45 - 24 + 1, 70 - 24 + 1
-    got = ops.window_inv_sigma_grid_batch(pair, ny, nx, use_kernel=True,
-                                          interpret=True)
+    got = ops.window_inv_sigma_grid_batch(pair, ny, nx, use_kernel=True)
     want = ops.window_inv_sigma_grid_batch(pair, ny, nx, use_kernel=False)
     assert got.shape == (2, ny, nx)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-6)
     for i in range(2):
-        one = ops.window_inv_sigma_grid(pair[i], ny, nx, use_kernel=True,
-                                        interpret=True)
+        one = ops.window_inv_sigma_grid(pair[i], ny, nx, use_kernel=True)
         np.testing.assert_array_equal(np.asarray(got[i]), np.asarray(one))
 
 
@@ -123,16 +121,14 @@ def test_dense_stage_sums_batch_all_stages_match_ref(stage):
     _, ii, pair = _batch_inputs(2, 40, 56, seed=stage)
     ny, nx = 40 - 24 + 1, 56 - 24 + 1
     inv = ops.window_inv_sigma_grid_batch(pair, ny, nx, use_kernel=False)
-    got = ops.dense_stage_sums_batch(CASC, CASC, stage, ii, inv,
-                                     interpret=True)
+    got = ops.dense_stage_sums_batch(CASC, CASC, stage, ii, inv)
     want = ops.dense_stage_sums_batch_ref(CASC, CASC, stage, ii, inv)
     assert got.shape == (2, ny, nx)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-3)
     # each slice bit-equal to the single-image kernel (batch = vmap of it)
     for i in range(2):
-        one = ops.dense_stage_sums(CASC, CASC, stage, ii[i], inv[i],
-                                   interpret=True)
+        one = ops.dense_stage_sums(CASC, CASC, stage, ii[i], inv[i])
         np.testing.assert_array_equal(np.asarray(got[i]), np.asarray(one))
 
 
@@ -144,7 +140,7 @@ def test_dense_stage_sums_batch_all_stages_match_ref(stage):
 def test_integral_image_vs_oracle_twin():
     rng = np.random.default_rng(7)
     img = jnp.asarray(rng.integers(0, 255, (48, 72)).astype(np.float32))
-    got = ops.integral_image(img, interpret=True, use_kernel=True)
+    got = ops.integral_image(img, use_kernel=True)
     want = jnp.pad(ref.integral_image_ref(img), ((1, 0), (1, 0)))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-2)
@@ -153,7 +149,7 @@ def test_integral_image_vs_oracle_twin():
 def test_integral_image_batch_vs_oracle_twin():
     rng = np.random.default_rng(11)
     imgs = jnp.asarray(rng.integers(0, 255, (3, 40, 56)).astype(np.float32))
-    got = ops.integral_image_batch(imgs, interpret=True, use_kernel=True)
+    got = ops.integral_image_batch(imgs, use_kernel=True)
     want = jnp.pad(ref.integral_image_batch_ref(imgs),
                    ((0, 0), (1, 0), (1, 0)))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -192,8 +188,7 @@ def test_fused_head_vs_oracle_twin():
     h, w = 40, 56
     rng = np.random.default_rng(23)
     img = jnp.asarray(rng.integers(0, 255, (h, w)).astype(np.float32))
-    ii, inv, sums = ops.fused_head(CASC, CASC, 0, N_RUN, img,
-                                   interpret=True)
+    ii, inv, sums = ops.fused_head(CASC, CASC, 0, N_RUN, img)
     ii_r, inv_r, sums_r = ops.fused_head_ref(CASC, CASC, 0, N_RUN, img)
     assert sums.shape == (N_RUN, h - 24 + 1, w - 24 + 1)
     np.testing.assert_allclose(np.asarray(ii), np.asarray(ii_r),
@@ -216,8 +211,7 @@ def test_fused_head_vs_oracle_twin():
 def test_fused_head_batch_vs_oracle_twin():
     rng = np.random.default_rng(29)
     imgs = jnp.asarray(rng.integers(0, 255, (3, 40, 56)).astype(np.float32))
-    ii, inv, sums = ops.fused_head_batch(CASC, CASC, 0, N_RUN, imgs,
-                                         interpret=True)
+    ii, inv, sums = ops.fused_head_batch(CASC, CASC, 0, N_RUN, imgs)
     ii_r, inv_r, sums_r = ops.fused_head_batch_ref(CASC, CASC, 0, N_RUN,
                                                    imgs)
     assert sums.shape == (3, N_RUN, 40 - 24 + 1, 56 - 24 + 1)
@@ -230,7 +224,7 @@ def test_fused_head_batch_vs_oracle_twin():
     assert "fused_head_batch_ref" in dir(ref)
     # each slice bit-equal to the single-image kernel (batch = vmap of it)
     for i in range(3):
-        one = ops.fused_head(CASC, CASC, 0, N_RUN, imgs[i], interpret=True)
+        one = ops.fused_head(CASC, CASC, 0, N_RUN, imgs[i])
         for got_b, want_b in zip((ii[i], inv[i], sums[i]), one):
             np.testing.assert_array_equal(np.asarray(got_b),
                                           np.asarray(want_b))
@@ -253,13 +247,12 @@ def test_fused_head_bit_identical_to_split_path(hw):
         ii, pair = integral_images(im)
         inv = window_inv_sigma(pair, jnp.arange(ny)[:, None],
                                jnp.arange(nx)[None, :], 24)
-        sums = jnp.stack([ops.dense_stage_sums(c, CASC, s, ii, inv,
-                                               interpret=True)
+        sums = jnp.stack([ops.dense_stage_sums(c, CASC, s, ii, inv)
                           for s in range(N_RUN)])
         return ii, inv, sums
 
     def fused(c, im):
-        return ops.fused_head(c, CASC, 0, N_RUN, im, interpret=True)
+        return ops.fused_head(c, CASC, 0, N_RUN, im)
 
     want = jax.jit(split)(CASC, img)
     got = jax.jit(fused)(CASC, img)
@@ -274,8 +267,7 @@ def test_fused_head_tile_shape_does_not_change_bits(tile):
     shapes can never change what the cascade computes."""
     rng = np.random.default_rng(37)
     img = jnp.asarray(rng.integers(0, 255, (40, 56)).astype(np.float32))
-    base = ops.fused_head(CASC, CASC, 0, N_RUN, img, interpret=True)
-    other = ops.fused_head(CASC, CASC, 0, N_RUN, img, tile=tile,
-                           interpret=True)
+    base = ops.fused_head(CASC, CASC, 0, N_RUN, img)
+    other = ops.fused_head(CASC, CASC, 0, N_RUN, img, tile=tile)
     for g, wnt in zip(other, base):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(wnt))

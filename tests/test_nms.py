@@ -122,3 +122,64 @@ def test_batched_empty():
                                  n_batches=3)
     assert len(got) == 3
     assert all(g.shape == (0, 4) for g in got)
+
+
+# ------------------------------------------------------- pairwise reference
+def _reference_groups(rects, min_neighbors, eps=0.2):
+    """OpenCV groupRectangles by brute force: the SimilarRects predicate on
+    every pair, then union-find; clusters as a sorted list of mean rects."""
+    r = np.asarray(rects, np.float64)
+    n = len(r)
+    parent = list(range(n))
+
+    def find(i):
+        while parent[i] != i:
+            i = parent[i]
+        return i
+
+    for i in range(n):
+        for j in range(i + 1, n):
+            delta = eps * (min(r[i, 2], r[j, 2]) + min(r[i, 3], r[j, 3])) / 2
+            if (abs(r[i, 0] - r[j, 0]) <= delta
+                    and abs(r[i, 1] - r[j, 1]) <= delta
+                    and abs(r[i, 0] + r[i, 2] - r[j, 0] - r[j, 2]) <= delta
+                    and abs(r[i, 1] + r[i, 3] - r[j, 1] - r[j, 3]) <= delta):
+                parent[find(j)] = find(i)
+    roots = np.asarray([find(i) for i in range(n)])
+    return sorted(tuple(np.rint(r[roots == c].mean(axis=0)).astype(int))
+                  for c in np.unique(roots)
+                  if (roots == c).sum() >= min_neighbors + 1)
+
+
+@pytest.mark.parametrize("mn", [0, 1, 3])
+@pytest.mark.parametrize("seed", [0, 1])
+def test_matches_pairwise_reference(seed, mn):
+    """Banded pair search == all-pairs predicate, on integer window grids
+    at pyramid scales and on arbitrary float rects."""
+    rng = np.random.default_rng(seed)
+    n = 300
+    s = rng.choice([24, 29, 35, 41, 50, 60], n)
+    grid = np.stack([rng.integers(0, 200, n), rng.integers(0, 200, n), s, s],
+                    axis=1)
+    floats = np.concatenate([rng.uniform(-50, 300, (n, 2)),
+                             np.repeat(rng.uniform(5, 80, (n, 1)), 2, axis=1)
+                             * rng.uniform(0.8, 1.2, (n, 2))], axis=1)
+    for rects in (grid, floats):
+        got = sorted(map(tuple, group_rectangles(rects, min_neighbors=mn)))
+        assert got == _reference_groups(rects, mn)
+
+
+def test_dense_camera_frame_scale():
+    """A camera frame's worth of raw windows (every origin of a 150x200
+    grid, plus a coarser scale) groups without an (N, N) matrix: one
+    chained cluster per scale."""
+    ys, xs = np.mgrid[0:150, 0:200]
+    fine = np.stack([xs.ravel(), ys.ravel(), np.full(xs.size, 24),
+                     np.full(xs.size, 24)], axis=1)
+    coarse = np.stack([xs.ravel()[::4] * 2, ys.ravel()[::4] * 2,
+                       np.full(xs.size // 4, 48), np.full(xs.size // 4, 48)],
+                      axis=1)
+    rects = np.concatenate([fine, coarse])
+    assert len(rects) == 37_500
+    got = group_rectangles(rects, min_neighbors=3)
+    assert len(got) == 2

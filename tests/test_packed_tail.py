@@ -82,7 +82,7 @@ def test_pallas_rung_alignment(cap):
 
 def test_kernel_wrapper_matches_ref_twin():
     got = np.asarray(ops.packed_stage_sums(
-        CASC, CASC, 1, N_STAGES, *WORKLOAD, interpret=True))
+        CASC, CASC, 1, N_STAGES, *WORKLOAD))
     want = np.asarray(ops.packed_stage_sums_ref(
         CASC, CASC, 1, N_STAGES, *WORKLOAD))
     assert got.shape == want.shape == (N_STAGES - 1, 317)

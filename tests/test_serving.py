@@ -181,3 +181,19 @@ def test_service_replans_on_straggle(detector, images):
     # once, so the straggle replanner must have fired
     assert st.replans >= 1
     assert st.pods[0].rate != st.pods[1].rate
+
+
+def test_program_errors_propagate_from_flush(detector, images, monkeypatch):
+    """Only capacity overflow is isolated per request: any other error of
+    the batch program (a compile or runtime failure) fails the flush
+    instead of being retried image by image."""
+    svc = DetectorService(detector)
+
+    def broken(*_a, **_k):
+        raise ValueError("program failed to compile")
+
+    monkeypatch.setattr(svc.detector, "detect_batch", broken)
+    monkeypatch.setattr(svc.detector, "detect", broken)
+    svc.submit(images[0])
+    with pytest.raises(ValueError, match="failed to compile"):
+        svc.flush()
