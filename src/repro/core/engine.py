@@ -75,6 +75,7 @@ from .integral import integral_images, window_inv_sigma_grid
 from .features import run_sums_grid, stage_sum_windows
 from .pyramid import downscale_nearest, downscale_indices
 from . import nms
+from repro import obs
 from repro.kernels import packed_tail
 from repro.kernels.platform import kernels_by_default, mode_by_default
 import repro.plan as planlib
@@ -662,24 +663,31 @@ class Detector:
             else:
                 raise ValueError(f"unknown batch strategy: {strategy!r}")
             for i, rects in zip(idxs, per_img_rects):
-                out[i] = (nms.group_rectangles(rects,
-                                               self.config.min_neighbors)
-                          if group else rects)
+                if group:
+                    with obs.span("nms.group", n=len(rects)):
+                        rects = nms.group_rectangles(
+                            rects, self.config.min_neighbors)
+                out[i] = rects
         return out
 
     def batch_result(self, images) -> BatchResult:
         """Pre-NMS survivors of a same-bucket stack: the :class:`BatchResult`
         of the packed batch program that ``detect_batch`` runs (per-stage
-        alive counts, surviving windows, overflow flag)."""
-        imgs = [np.asarray(im, np.float32) for im in images]
-        hws = {self._bucket_hw(*im.shape) for im in imgs}
-        if len(hws) != 1:
-            raise ValueError(
-                f"batch_result needs a single shape bucket, got {hws}")
-        (hp, wp), = hws
-        stack, valid_hw = self._pack_stack(imgs, hp, wp)
-        return self._batch_fn(hp, wp, len(imgs))(
-            self.cascade, stack, jnp.asarray(valid_hw))
+        alive counts, surviving windows, overflow flag).  The span
+        ``engine.pack`` covers packing, the upload (``bytes``) and the
+        dispatch; the result is not waited for."""
+        with obs.span("engine.pack", n=len(images)) as attrs:
+            imgs = [np.asarray(im, np.float32) for im in images]
+            hws = {self._bucket_hw(*im.shape) for im in imgs}
+            if len(hws) != 1:
+                raise ValueError(
+                    f"batch_result needs a single shape bucket, got {hws}")
+            (hp, wp), = hws
+            stack, valid_hw = self._pack_stack(imgs, hp, wp)
+            valid_hw = jnp.asarray(valid_hw)
+            attrs["bytes"] = stack.nbytes + valid_hw.nbytes
+            return self._batch_fn(hp, wp, len(imgs))(
+                self.cascade, stack, valid_hw)
 
     def _detect_bucket_packed(self, imgs: list, hp: int, wp: int) -> list:
         n = len(imgs)
@@ -687,21 +695,25 @@ class Detector:
         if not plan.levels:  # bucket smaller than the detection window
             return [np.zeros((0, 4), np.int32) for _ in range(n)]
         res = self.batch_result(imgs)
+        # wait here, so that the fetch span times the copies alone
+        jax.block_until_ready(res)
         if bool(np.asarray(res.overflow)):
             raise CapacityOverflow(
                 "batched-engine shared capacity overflow; raise "
                 "batch_capacity_fracs / capacity_fracs (see "
                 "Detector.calibrated)")
-        scales = np.asarray([lp.scale for lp in plan.levels])
-        val = np.asarray(res.valid)
-        b = np.asarray(res.img)[val]
-        lvl = np.asarray(res.lvl)[val]
-        ys = np.asarray(res.ys)[val]
-        xs = np.asarray(res.xs)[val]
-        out = []
-        for i in range(n):
-            m = b == i
-            out.append(self._decode_rects(ys[m], xs[m], scales[lvl[m]]))
+        with obs.span("engine.fetch") as attrs:
+            val, b, lvl, ys, xs = host = [
+                np.asarray(a) for a in (res.valid, res.img, res.lvl,
+                                        res.ys, res.xs)]
+            attrs["bytes"] = sum(a.nbytes for a in host)
+        with obs.span("engine.decode"):
+            scales = np.asarray([lp.scale for lp in plan.levels])
+            b, lvl, ys, xs = b[val], lvl[val], ys[val], xs[val]
+            out = []
+            for i in range(n):
+                m = b == i
+                out.append(self._decode_rects(ys[m], xs[m], scales[lvl[m]]))
         return out
 
     def _detect_bucket_vmap(self, imgs: list, idxs: list) -> list:

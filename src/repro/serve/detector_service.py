@@ -64,6 +64,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 import repro.plan as planlib
+from repro import obs
 from repro.core.engine import CapacityOverflow
 from repro.scheduling.dvfs import (GovernorDecision, binding_slo,
                                    evaluate_operating_points,
@@ -317,6 +318,7 @@ class DetectorService:
         self._flush_lock = threading.Lock()  # serializes whole flushes
         self._queue: list[Request] = []
         self._next_id = 0
+        self._flushes = 0                    # flush ids (under _flush_lock)
         # nominal relative speeds until the first real observation (or
         # warmup) rescales them into absolute window-units/s — mixing the
         # two scales in the EMA would starve never-observed pods
@@ -465,26 +467,28 @@ class DetectorService:
                     self._queue = [r for r in self._queue if r.tier != tier]
             if not batch:
                 return 0
-            images = [r for r in batch if r.session is None]
-            frames = [r for r in batch if r.session is not None]
-            if images:
-                self._shard_across_pods(
-                    images, self._run_shard,
-                    [self._request_units(r) for r in images],
-                    tiers=self._tiers_present(images))
-            while frames:
-                round_, rest, seen = [], [], set()
-                for fr in frames:
-                    if fr.session.stream_id in seen:
-                        rest.append(fr)
-                    else:
-                        seen.add(fr.session.stream_id)
-                        round_.append(fr)
-                frames = rest
-                self._shard_across_pods(
-                    round_, self._run_stream_shard,
-                    [self._request_units(fr) for fr in round_],
-                    tiers=self._tiers_present(round_))
+            self._flushes += 1
+            with obs.span("serve.flush", flush=self._flushes, n=len(batch)):
+                images = [r for r in batch if r.session is None]
+                frames = [r for r in batch if r.session is not None]
+                if images:
+                    self._shard_across_pods(
+                        images, self._run_shard,
+                        [self._request_units(r) for r in images],
+                        tiers=self._tiers_present(images))
+                while frames:
+                    round_, rest, seen = [], [], set()
+                    for fr in frames:
+                        if fr.session.stream_id in seen:
+                            rest.append(fr)
+                        else:
+                            seen.add(fr.session.stream_id)
+                            round_.append(fr)
+                    frames = rest
+                    self._shard_across_pods(
+                        round_, self._run_stream_shard,
+                        [self._request_units(fr) for fr in round_],
+                        tiers=self._tiers_present(round_))
             return len(batch)
 
     def _tiers_present(self, items: list[Request]) -> dict[str, float]:
@@ -692,6 +696,10 @@ class DetectorService:
     def _run_shard(self, shard: list[Request]) -> None:
         for chunk in self._chunks(shard):
             images = [r.image for r in chunk]
+            now = time.perf_counter_ns()
+            for r in chunk:     # submit to the dispatch of the request's chunk
+                obs.interval("serve.queue", int(r.t_submit * 1e9), now,
+                             req=r.req_id)
             try:
                 rects = self.detector.detect_batch(images,
                                                    strategy=self.strategy)
