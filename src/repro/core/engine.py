@@ -77,6 +77,7 @@ from .pyramid import downscale_nearest, downscale_indices
 from . import nms
 from repro import obs
 from repro.kernels import packed_tail
+from repro.kernels.autotune import DEFAULT_TILE
 from repro.kernels.platform import kernels_by_default, mode_by_default
 import repro.plan as planlib
 
@@ -157,6 +158,9 @@ class BatchResult(NamedTuple):
     alive_counts: jax.Array  # (n_stages, B) int32 — per-image survivors after
     #                          each stage, summed over pyramid levels
     overflow: jax.Array      # () bool — shared capacity exceeded
+    head_work: jax.Array     # (B, 2) int32 — per image, weak-classifier
+    #                          evaluations of (ty, tx) tiles the dense head
+    #                          ran, and those of the dense head with no exit
 
 
 def calibrate_capacities(alive_counts: np.ndarray, n_windows: int,
@@ -245,10 +249,11 @@ class Detector:
         def level_fn(cascade: Cascade, img: jax.Array,
                      limits: jax.Array) -> LevelResult:
             if fused:
-                # whole dense head — SAT and 1/sigma (XLA), then every
-                # dense stage's sums in one megakernel dispatch
-                # (bit-identical to the split path below; the plan chose
-                # per measured crossover)
+                # whole dense head — SAT and 1/sigma (XLA), then the sums
+                # of every dense stage its tiles reach in one megakernel
+                # dispatch (the split path's bits wherever a tile ran,
+                # -inf where it exited, so the chain below decides as the
+                # split path does; the plan chose per measured crossover)
                 ii, inv_sigma_grid, dsums = kops.fused_head(
                     cascade, cascade_static, 0, n_dense_lp, img,
                     tile=head_tile)
@@ -436,12 +441,13 @@ class Detector:
         cascade_static = self.cascade  # static feature geometry for Pallas
         use_pallas = cfg.use_pallas and step == 1
         self.program_builds += 1
-        head_tile = plan.head_tile
+        head_tile = plan.head_tile or DEFAULT_TILE
         lane_block = plan.lane_block
         if use_pallas:
             from repro.kernels import ops as kops
-            if not head_tile:
-                from repro.kernels.autotune import DEFAULT_TILE as head_tile
+        # weak classifiers per dense stage: the head's work per tile-stage
+        n_weak = np.diff(bounds)[:n_dense]
+        ty, tx = head_tile
 
         layout = plan.layout
         lvl_of_slot = jnp.asarray(layout.lvl_of_slot)
@@ -457,6 +463,8 @@ class Detector:
                     valid_hw: jax.Array):
             # stack: (B, hp, wp) f32; valid_hw: (B, 2) int32 true shapes
             counts = jnp.zeros((n_stages, batch), jnp.int32)
+            work = jnp.zeros((batch,), jnp.int32)
+            dense_work = 0
             # per-level SATs, flattened per level and concatenated, feed the
             # packed tail's gathers; dense mode (no tail) never builds them
             sat_parts: list = []
@@ -473,15 +481,24 @@ class Detector:
                                                 WINDOW)
                     return ii, inv                            # (ny, nx) grid
 
+                dense_l = (-(-lp.ny // ty) * -(-lp.nx // tx)
+                           * int(n_weak.sum()))
+                dense_work += dense_l
                 if fused_l:
-                    # SAT and 1/sigma, then every dense stage's sums for
-                    # the whole stack in one batched megakernel dispatch
-                    # (bit-identical to the split path; the plan chose per
-                    # level from the measured fused-vs-split crossover)
+                    # SAT and 1/sigma, then the sums of every dense stage
+                    # its tiles reach for the whole stack in one batched
+                    # megakernel dispatch (the split path's bits wherever
+                    # a tile ran; the plan chose per level from the
+                    # measured fused-vs-split crossover)
                     ii_l, inv_grid_l, sums_l = kops.fused_head_batch(
                         cascade, cascade_static, 0, n_dense, img_l,
                         tile=head_tile)
+                    # a tile's origin reads -inf at each stage it skipped
+                    ran = sums_l[:, :, ::ty, ::tx] > -jnp.inf
+                    work = work + (ran.sum(axis=(2, 3)) * jnp.asarray(
+                        n_weak, jnp.int32)).sum(axis=1)
                 else:
+                    work = work + dense_l
                     ii_l, inv_grid_l = jax.vmap(head)(img_l)  # (B,h+1,w+1),(B,ny,nx)
                     if not use_pallas:
                         sums_l = jax.vmap(
@@ -519,10 +536,13 @@ class Detector:
             inv_flat = jnp.concatenate(inv_parts, axis=1).reshape(-1)
             ii_flat = (jnp.concatenate(sat_parts, axis=1) if tail_segs
                        else None)                         # (B, sum sat sizes)
-            return alive_flat, inv_flat, ii_flat, counts
+            head_work = jnp.stack(
+                [work, jnp.full((batch,), dense_work, jnp.int32)], axis=1)
+            return alive_flat, inv_flat, ii_flat, counts, head_work
 
         def tail_fn(cascade: Cascade, alive_flat: jax.Array,
-                    inv_flat: jax.Array, ii_flat, counts) -> BatchResult:
+                    inv_flat: jax.Array, ii_flat, counts,
+                    head_work: jax.Array) -> BatchResult:
             # ---- shared compactions across the whole (batch x pyramid):
             # survivors from every image and level share one window list,
             # recompacted per tail segment like the single-image wave engine
@@ -569,7 +589,8 @@ class Detector:
                 lvl=jnp.where(valid, lvl_sel, -1),
                 ys=jnp.where(valid, y_sel, -1),
                 xs=jnp.where(valid, x_sel, -1),
-                valid=valid, alive_counts=counts, overflow=overflow)
+                valid=valid, alive_counts=counts, overflow=overflow,
+                head_work=head_work)
 
         def batch_fn(cascade: Cascade, stack: jax.Array,
                      valid_hw: jax.Array) -> BatchResult:
@@ -590,9 +611,9 @@ class Detector:
 
         ``head_fn(cascade, stack, valid_hw)`` runs the per-level dense
         waves and returns the flat pre-compaction state
-        ``(alive_flat, inv_flat, ii_flat, counts)``; ``tail_fn(cascade,
-        *that)`` runs the shared compactions + packed tail to a
-        :class:`BatchResult`.  Benchmarks jit and time the halves
+        ``(alive_flat, inv_flat, ii_flat, counts, head_work)``;
+        ``tail_fn(cascade, *that)`` runs the shared compactions + packed
+        tail to a :class:`BatchResult`.  Benchmarks jit and time the halves
         directly, so the head/tail split in BENCH_detector is a pair of
         real measurements rather than a subtraction.
         """
@@ -703,10 +724,12 @@ class Detector:
                 "batch_capacity_fracs / capacity_fracs (see "
                 "Detector.calibrated)")
         with obs.span("engine.fetch") as attrs:
-            val, b, lvl, ys, xs = host = [
+            val, b, lvl, ys, xs, work = host = [
                 np.asarray(a) for a in (res.valid, res.img, res.lvl,
-                                        res.ys, res.xs)]
+                                        res.ys, res.xs, res.head_work)]
             attrs["bytes"] = sum(a.nbytes for a in host)
+            attrs["head_work"], attrs["head_dense"] = (
+                int(v) for v in work.sum(axis=0))
         with obs.span("engine.decode"):
             scales = np.asarray([lp.scale for lp in plan.levels])
             b, lvl, ys, xs = b[val], lvl[val], ys[val], xs[val]

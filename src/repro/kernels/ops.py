@@ -198,62 +198,67 @@ def dense_stage_sums_batch(cascade: Cascade, cascade_static: Cascade, s: int,
 
 
 # -------------------------------------------------------------------- fused
-# One-dispatch dense head: every dense stage's vote sums from a single
+# One-dispatch head: the vote sums of every stage of the run from a single
 # fused_head_kernel call (kernels/fused_head.py), with the SAT and 1/sigma
-# grid — the split path's own XLA ops — resident in VMEM.  Bit-identical to
-# the split path (integral_images -> window_inv_sigma -> one
-# dense_stage_sums dispatch per stage), which is what Detector executes
-# when the plan's head mode is "split".
+# grid — the split path's own XLA ops — resident in VMEM, and early exit
+# per (ty, tx) tile on the run's stage thresholds.  Where a tile entered a
+# stage its sums are the split path's bits (integral_images ->
+# window_inv_sigma -> one dense_stage_sums dispatch per stage, what
+# Detector executes when the plan's head mode is "split"); elsewhere -inf.
 
 def fused_head(cascade: Cascade, cascade_static: Cascade, s0: int, s1: int,
                img: jax.Array, *, tile=DEFAULT_TILE):
-    """Fused dense head for stages ``[s0, s1)`` over one image.
+    """Fused head for stages ``[s0, s1)`` over one image.
 
     Returns ``(ii, inv_sigma_grid, stage_sums)``: the (H+1, W+1) padded
     SAT (feeds the compacted tail's gathers), the (ny, nx) 1/sigma grid,
-    and (s1 - s0, ny, nx) per-stage vote sums — each bit-identical to the
-    split path's corresponding array.
+    and (s1 - s0, ny, nx) per-stage vote sums — the split path's wherever
+    the window's tile entered the stage under ``cascade.stage_threshold``,
+    ``-inf`` where it did not.
     """
     k0, k1, rel = _stage_run_slices(cascade_static, s0, s1)
     return fused_head_kernel(
         cascade.rect_xywh[k0:k1], cascade.rect_w[k0:k1],
         cascade.wc_threshold[k0:k1], cascade.left_val[k0:k1],
-        cascade.right_val[k0:k1], rel, img, tile=tile,
-        interpret=interpret_mode())
+        cascade.right_val[k0:k1], cascade.stage_threshold[s0:s1], rel, img,
+        tile=tile, interpret=interpret_mode())
 
 
 def fused_head_ref(cascade: Cascade, cascade_static: Cascade, s0: int,
-                   s1: int, img: jax.Array):
+                   s1: int, img: jax.Array, *, tile=DEFAULT_TILE):
     """Oracle twin of :func:`fused_head` (same signature contract)."""
     k0, k1, rel = _stage_run_slices(cascade_static, s0, s1)
     return ref.fused_head_ref(
         cascade.rect_xywh[k0:k1], cascade.rect_w[k0:k1],
         cascade.wc_threshold[k0:k1], cascade.left_val[k0:k1],
-        cascade.right_val[k0:k1], rel, img)
+        cascade.right_val[k0:k1], cascade.stage_threshold[s0:s1], rel, img,
+        tile=tile)
 
 
 def fused_head_batch(cascade: Cascade, cascade_static: Cascade, s0: int,
                      s1: int, imgs: jax.Array, *, tile=DEFAULT_TILE):
     """(B, H, W) stack -> batched :func:`fused_head` (same per-image
-    contract): ``(B, H+1, W+1)`` SATs, ``(B, ny, nx)`` 1/sigma grids,
-    ``(B, s1-s0, ny, nx)`` stage sums.  vmap lifts the batch axis into an
-    extra Pallas grid dimension, so one dispatch covers the stack."""
+    contract, each image's tiles exiting on their own): ``(B, H+1, W+1)``
+    SATs, ``(B, ny, nx)`` 1/sigma grids, ``(B, s1-s0, ny, nx)`` stage
+    sums.  vmap lifts the batch axis into an extra Pallas grid dimension,
+    so one dispatch covers the stack."""
     k0, k1, rel = _stage_run_slices(cascade_static, s0, s1)
     return jax.vmap(lambda im: fused_head_kernel(
         cascade.rect_xywh[k0:k1], cascade.rect_w[k0:k1],
         cascade.wc_threshold[k0:k1], cascade.left_val[k0:k1],
-        cascade.right_val[k0:k1], rel, im, tile=tile,
-        interpret=interpret_mode()))(imgs.astype(jnp.float32))
+        cascade.right_val[k0:k1], cascade.stage_threshold[s0:s1], rel, im,
+        tile=tile, interpret=interpret_mode()))(imgs.astype(jnp.float32))
 
 
 def fused_head_batch_ref(cascade: Cascade, cascade_static: Cascade, s0: int,
-                         s1: int, imgs: jax.Array):
+                         s1: int, imgs: jax.Array, *, tile=DEFAULT_TILE):
     """Oracle twin of :func:`fused_head_batch` (same signature contract)."""
     k0, k1, rel = _stage_run_slices(cascade_static, s0, s1)
     return ref.fused_head_batch_ref(
         cascade.rect_xywh[k0:k1], cascade.rect_w[k0:k1],
         cascade.wc_threshold[k0:k1], cascade.left_val[k0:k1],
-        cascade.right_val[k0:k1], rel, imgs)
+        cascade.right_val[k0:k1], cascade.stage_threshold[s0:s1], rel, imgs,
+        tile=tile)
 
 
 # ------------------------------------------------------------------- packed

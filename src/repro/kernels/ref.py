@@ -13,6 +13,8 @@ import jax.numpy as jnp
 from repro.core.cascade import WINDOW
 from repro.core.integral import CENTRE, rect_sum
 
+from .autotune import DEFAULT_TILE
+
 _AREA = float(WINDOW * WINDOW)
 
 
@@ -69,16 +71,37 @@ def dense_stage_sums_ref(rect_xywh: jax.Array, rect_w: jax.Array,
 
 
 # ----------------------------------------------------------------- fused
+def tile_exit_ref(sums: jax.Array, stage_threshold: jax.Array,
+                  tile=DEFAULT_TILE) -> jax.Array:
+    """Dense (n_run, ny, nx) stage sums with the fused head's early exit
+    per (ty, tx) tile applied: a stage's sums are kept where some window
+    of the tile is still alive entering it (``sums >= stage_threshold``
+    at every earlier stage of the run), and read ``-inf`` elsewhere."""
+    n_run, ny, nx = sums.shape
+    ty, tx = tile
+    nty, ntx = -(-ny // ty), -(-nx // tx)
+    pad = ((0, nty * ty - ny), (0, ntx * tx - nx))
+    alive = jnp.ones((ny, nx), bool)
+    out = []
+    for s in range(n_run):
+        entered = jnp.pad(alive, pad).reshape(nty, ty, ntx, tx).any((1, 3))
+        entered = jnp.repeat(jnp.repeat(entered, ty, 0), tx, 1)[:ny, :nx]
+        out.append(jnp.where(entered, sums[s], -jnp.inf))
+        alive = alive & (sums[s] >= stage_threshold[s])
+    return jnp.stack(out)
+
+
 def fused_head_ref(rect_xywh: jax.Array, rect_w: jax.Array,
                    wc_threshold: jax.Array, left_val: jax.Array,
-                   right_val: jax.Array, rel_bounds: tuple,
-                   img: jax.Array):
-    """Oracle twin of the fused dense-head megakernel
-    (kernels/fused_head.py): the split path composed from this module's
-    own pieces.  The weak-classifier arrays cover one dense stage run;
-    ``rel_bounds`` are its per-stage boundaries.  Returns
-    ``(ii, inv_sigma, sums)`` — the (H+1, W+1) padded SAT, the (ny, nx)
-    1/sigma grid, and (n_run, ny, nx) per-stage vote sums.
+                   right_val: jax.Array, stage_threshold: jax.Array,
+                   rel_bounds: tuple, img: jax.Array, *, tile=DEFAULT_TILE):
+    """Oracle twin of the fused head megakernel (kernels/fused_head.py):
+    the split path composed from this module's own pieces, then
+    :func:`tile_exit_ref`.  The weak-classifier arrays and
+    ``stage_threshold`` cover one stage run; ``rel_bounds`` are its
+    per-stage boundaries.  Returns ``(ii, inv_sigma, sums)`` — the
+    (H+1, W+1) padded SAT, the (ny, nx) 1/sigma grid, and (n_run, ny, nx)
+    per-stage vote sums, ``-inf`` where the window's tile exited before.
     """
     img = img.astype(jnp.float32)
     h, w = img.shape
@@ -94,18 +117,19 @@ def fused_head_ref(rect_xywh: jax.Array, rect_w: jax.Array,
                              wc_threshold[a:b], left_val[a:b],
                              right_val[a:b], ii, inv)
         for a, b in zip(rel_bounds[:-1], rel_bounds[1:])])
-    return ii, inv, sums
+    return ii, inv, tile_exit_ref(sums, stage_threshold, tile)
 
 
 def fused_head_batch_ref(rect_xywh: jax.Array, rect_w: jax.Array,
                          wc_threshold: jax.Array, left_val: jax.Array,
-                         right_val: jax.Array, rel_bounds: tuple,
-                         imgs: jax.Array):
+                         right_val: jax.Array, stage_threshold: jax.Array,
+                         rel_bounds: tuple, imgs: jax.Array, *,
+                         tile=DEFAULT_TILE):
     """(B, H, W) stack -> per-image :func:`fused_head_ref` (oracle twin of
     the batched fused-head wrapper, same per-image contract)."""
     return jax.vmap(lambda im: fused_head_ref(
-        rect_xywh, rect_w, wc_threshold, left_val, right_val, rel_bounds,
-        im))(imgs)
+        rect_xywh, rect_w, wc_threshold, left_val, right_val,
+        stage_threshold, rel_bounds, im, tile=tile))(imgs)
 
 
 # ---------------------------------------------------------------- packed

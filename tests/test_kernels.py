@@ -179,12 +179,44 @@ def test_window_inv_sigma_grid_batch_vs_oracle_twin():
 
 
 # ------------------------------------------------------------------ fused
-N_RUN = min(3, CASC.n_stages)     # the megakernel's dense stage run
+N_RUN = min(3, CASC.n_stages)     # the megakernel's stage run
+TH = np.asarray(CASC.stage_threshold[:N_RUN])
+
+
+def _thresholds(casc, th):
+    """``casc`` with its first stages' thresholds set to ``th``."""
+    full = np.asarray(casc.stage_threshold).copy()
+    full[:len(th)] = th
+    return casc._replace(stage_threshold=jnp.asarray(full, jnp.float32))
+
+
+def _split_sums(c, im):
+    """The split path: jnp SAT and 1/sigma, one haar_stage dispatch per
+    stage, every stage on every window (no exit)."""
+    from repro.core.integral import window_inv_sigma
+
+    ny, nx = im.shape[0] - 24 + 1, im.shape[1] - 24 + 1
+    ii, pair = integral_images(im)
+    inv = window_inv_sigma(pair, jnp.arange(ny)[:, None],
+                           jnp.arange(nx)[None, :], 24)
+    sums = jnp.stack([ops.dense_stage_sums(c, CASC, s, ii, inv)
+                      for s in range(N_RUN)])
+    return ii, inv, sums
+
+
+def _alive_chain(sums, th):
+    """The engine's cumulative survivors after each stage."""
+    alive = np.ones(sums.shape[1:], bool)
+    out = []
+    for s in range(sums.shape[0]):
+        alive = alive & (sums[s] >= th[s])
+        out.append(alive)
+    return np.stack(out)
 
 
 def test_fused_head_vs_oracle_twin():
     """ops.fused_head vs ref.fused_head_ref on a non-tile-aligned grid
-    (ny=17, nx=33), all three outputs."""
+    (ny=17, nx=33), all three outputs; the twin skips the same tiles."""
     h, w = 40, 56
     rng = np.random.default_rng(23)
     img = jnp.asarray(rng.integers(0, 255, (h, w)).astype(np.float32))
@@ -195,6 +227,7 @@ def test_fused_head_vs_oracle_twin():
                                rtol=1e-5, atol=1e-2)
     np.testing.assert_allclose(np.asarray(inv), np.asarray(inv_r),
                                rtol=1e-4, atol=1e-6)
+    np.testing.assert_array_equal(np.isneginf(sums), np.isneginf(sums_r))
     np.testing.assert_allclose(np.asarray(sums), np.asarray(sums_r),
                                rtol=1e-4, atol=1e-3)
     # the module-level oracle twin is the same function ops re-exports
@@ -204,6 +237,7 @@ def test_fused_head_vs_oracle_twin():
         CASC.wc_threshold[:CASC.stage_offsets[N_RUN]],
         CASC.left_val[:CASC.stage_offsets[N_RUN]],
         CASC.right_val[:CASC.stage_offsets[N_RUN]],
+        CASC.stage_threshold[:N_RUN],
         tuple(int(b) for b in CASC.stage_offsets[:N_RUN + 1]), img)
     np.testing.assert_array_equal(np.asarray(sums_r), np.asarray(sums_m))
 
@@ -219,6 +253,7 @@ def test_fused_head_batch_vs_oracle_twin():
                                rtol=1e-5, atol=1e-2)
     np.testing.assert_allclose(np.asarray(inv), np.asarray(inv_r),
                                rtol=1e-4, atol=1e-6)
+    np.testing.assert_array_equal(np.isneginf(sums), np.isneginf(sums_r))
     np.testing.assert_allclose(np.asarray(sums), np.asarray(sums_r),
                                rtol=1e-4, atol=1e-3)
     assert "fused_head_batch_ref" in dir(ref)
@@ -234,40 +269,128 @@ def test_fused_head_batch_vs_oracle_twin():
 def test_fused_head_bit_identical_to_split_path(hw):
     """The engine's bit-exactness contract: under jit, the fused megakernel
     reproduces the split three-dispatch path (jnp SAT + jnp 1/sigma + one
-    haar_stage dispatch per stage) to the last ulp, on tile-aligned and
-    non-tile-aligned grids alike."""
-    from repro.core.integral import window_inv_sigma
-
+    haar_stage dispatch per stage) to the last ulp wherever the window's
+    tile entered the stage, and reads -inf wherever it did not, on
+    tile-aligned and non-tile-aligned grids alike; the engine's survivors
+    after every stage are the split path's."""
     h, w = hw
     rng = np.random.default_rng(h * 31 + w)
     img = jnp.asarray(rng.integers(0, 255, (h, w)).astype(np.float32))
-    ny, nx = h - 24 + 1, w - 24 + 1
-
-    def split(c, im):
-        ii, pair = integral_images(im)
-        inv = window_inv_sigma(pair, jnp.arange(ny)[:, None],
-                               jnp.arange(nx)[None, :], 24)
-        sums = jnp.stack([ops.dense_stage_sums(c, CASC, s, ii, inv)
-                          for s in range(N_RUN)])
-        return ii, inv, sums
 
     def fused(c, im):
         return ops.fused_head(c, CASC, 0, N_RUN, im)
 
-    want = jax.jit(split)(CASC, img)
+    want = jax.jit(_split_sums)(CASC, img)
     got = jax.jit(fused)(CASC, img)
+    for g, wnt in zip(got[:2], want[:2]):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(wnt))
+    np.testing.assert_array_equal(
+        np.asarray(got[2]), np.asarray(ref.tile_exit_ref(want[2], TH)))
+    np.testing.assert_array_equal(_alive_chain(np.asarray(got[2]), TH),
+                                  _alive_chain(np.asarray(want[2]), TH))
+
+
+@pytest.mark.parametrize("hw", [(40, 56), (31, 140)])
+def test_fused_head_thresholds_that_reject_nothing_run_dense(hw):
+    """Thresholds at -inf reject no window: every tile runs every stage,
+    and the output is the dense split path's, bit for bit."""
+    h, w = hw
+    rng = np.random.default_rng(h + w)
+    img = jnp.asarray(rng.integers(0, 255, (h, w)).astype(np.float32))
+    open_c = _thresholds(CASC, [-np.inf] * N_RUN)
+    want = jax.jit(_split_sums)(open_c, img)
+    got = jax.jit(lambda c, im: ops.fused_head(c, CASC, 0, N_RUN, im))(
+        open_c, img)
+    assert not np.isinf(np.asarray(got[2])).any()
     for g, wnt in zip(got, want):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(wnt))
+
+
+def test_fused_head_thresholds_that_reject_everything_run_stage_0():
+    """Thresholds at +inf: every tile runs stage 0, whose sums are the
+    split path's, and writes -inf for every later stage."""
+    rng = np.random.default_rng(41)
+    img = jnp.asarray(rng.integers(0, 255, (40, 160)).astype(np.float32))
+    shut = _thresholds(CASC, [np.inf] * N_RUN)
+    want = _split_sums(shut, img)[2]
+    got = np.asarray(ops.fused_head(shut, CASC, 0, N_RUN, img)[2])
+    np.testing.assert_array_equal(got[0], np.asarray(want[0]))
+    assert np.isneginf(got[1:]).all()
+
+
+def test_fused_head_padded_edge_windows_keep_no_tile_alive():
+    """On a grid that is not tile-aligned (ny=17, nx=33), the kernel's
+    padded window origins would pass stage 0 at this threshold while no
+    real window does: the tiles still exit after stage 0."""
+    from repro.core.integral import window_inv_sigma
+    from repro.kernels.autotune import DEFAULT_TILE
+    from repro.kernels.haar_stage import (haar_stage_sums_kernel,
+                                          sat_pad_shape)
+    from repro.kernels.platform import interpret_mode
+
+    h, w = 40, 56
+    ny, nx = h - 24 + 1, w - 24 + 1
+    rng = np.random.default_rng(0)
+    img = jnp.asarray(rng.integers(0, 255, (h, w)).astype(np.float32))
+    # stage 0's sums over the padded grid, as the kernel's tiles see it
+    ii, pair = integral_images(img)
+    inv = window_inv_sigma(pair, jnp.arange(ny)[:, None],
+                           jnp.arange(nx)[None, :], 24)
+    ty, tx = DEFAULT_TILE
+    ny_pad, nx_pad = ny + (-ny) % ty, nx + (-nx) % tx
+    hp, wp = sat_pad_shape(ny_pad, nx_pad)
+    k1 = int(CASC.stage_offsets[1])
+    padded = np.asarray(haar_stage_sums_kernel(
+        CASC.rect_xywh[:k1], CASC.rect_w[:k1], CASC.wc_threshold[:k1],
+        CASC.left_val[:k1], CASC.right_val[:k1],
+        jnp.pad(ii, ((0, hp - h - 1), (0, wp - w - 1)), mode="edge"),
+        jnp.pad(inv, ((0, ny_pad - ny), (0, nx_pad - nx)), mode="edge"),
+        interpret=interpret_mode()))
+    real = np.zeros(padded.shape, bool)
+    real[:ny, :nx] = True
+    th0 = (padded[real].max() + padded[~real].max()) / 2
+    assert padded[real].max() < th0 <= padded[~real].max()
+
+    got = np.asarray(ops.fused_head(_thresholds(CASC, [th0]), CASC, 0, N_RUN,
+                                    img)[2])
+    np.testing.assert_array_equal(got[0], padded[:ny, :nx])
+    assert np.isneginf(got[1:]).all()
+
+
+def test_fused_head_batch_images_exit_independently():
+    """A flat image (every window rejected at stage 0) beside a textured
+    one: each image of the batch skips its own tiles, and each equals the
+    single-image call bit for bit."""
+    rng = np.random.default_rng(43)
+    textured = rng.integers(0, 255, (40, 160)).astype(np.float32)
+    imgs = jnp.asarray(np.stack([textured, np.full_like(textured, 90.0)]))
+    flat0 = np.asarray(_split_sums(CASC, imgs[1])[2])[0].max()
+    casc = _thresholds(CASC, [max(TH[0], np.nextafter(flat0, np.inf)),
+                              -np.inf, -np.inf])
+    _, _, sums = ops.fused_head_batch(casc, CASC, 0, N_RUN, imgs)
+    sums = np.asarray(sums)
+    assert np.isneginf(sums[1, 1:]).all()
+    assert not np.isneginf(sums[0, 1]).all()
+    for i in range(2):
+        one = ops.fused_head(casc, CASC, 0, N_RUN, imgs[i])[2]
+        np.testing.assert_array_equal(sums[i], np.asarray(one))
 
 
 @pytest.mark.parametrize("tile", [(16, 128), (8, 256)])
 def test_fused_head_tile_shape_does_not_change_bits(tile):
     """Autotuned block shapes are bit-exact-safe by construction: every
     per-window operation is elementwise over the tile, so racing candidate
-    shapes can never change what the cascade computes."""
+    shapes never changes a sum a tile computes, nor the survivors; only
+    which stages read -inf follows the tile."""
     rng = np.random.default_rng(37)
     img = jnp.asarray(rng.integers(0, 255, (40, 56)).astype(np.float32))
+    dense = _split_sums(CASC, img)[2]
     base = ops.fused_head(CASC, CASC, 0, N_RUN, img)
     other = ops.fused_head(CASC, CASC, 0, N_RUN, img, tile=tile)
-    for g, wnt in zip(other, base):
+    for g, wnt in zip(other[:2], base[:2]):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(wnt))
+    np.testing.assert_array_equal(
+        np.asarray(other[2]),
+        np.asarray(ref.tile_exit_ref(dense, TH, tile)))
+    np.testing.assert_array_equal(_alive_chain(np.asarray(other[2]), TH),
+                                  _alive_chain(np.asarray(base[2]), TH))

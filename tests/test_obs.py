@@ -209,8 +209,13 @@ def test_pack_and_fetch_count_the_bytes_they_move(flushed):
     rng = np.random.default_rng(5)
     res = det.batch_result([render_scene(rng, *HW, n_faces=1)[0]
                             for _ in range(b)])
-    want = sum(a.nbytes for a in (res.valid, res.img, res.lvl, res.ys,
-                                  res.xs))
-    assert want == det.batch_plan(hp, wp, b).capacities[0] * (4 * 4 + 1)
+    lanes = sum(a.nbytes for a in (res.valid, res.img, res.lvl, res.ys,
+                                   res.xs))
+    assert lanes == det.batch_plan(hp, wp, b).capacities[0] * (4 * 4 + 1)
+    # and the head's work counter: two int32 per image
+    assert res.head_work.nbytes == b * 8
+    want = lanes + res.head_work.nbytes
     fetches = [s for s in got if s.name == "engine.fetch"]
     assert [s.attrs["bytes"] for s in fetches] == [want, want]
+    for s in fetches:
+        assert 0 < s.attrs["head_work"] <= s.attrs["head_dense"]

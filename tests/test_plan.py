@@ -333,15 +333,71 @@ def test_forced_head_modes_bit_identical_end_to_end():
         vd = VideoDetector(det, StreamConfig(tile=16, threshold=0.0,
                                              keyframe_interval=0),
                            engine=StreamEngine(det, 0.5))
+        res = det.batch_result(imgs)
         got = ([det.detect(imgs[0])]
                + list(det.detect_batch(imgs, strategy="packed"))
                + list(det.detect_batch(imgs, strategy="vmap"))
-               + [vd.process(f)[0] for f, _gt in video])
+               + [vd.process(f)[0] for f, _gt in video]
+               + [np.asarray(res.alive_counts), _survivors(res)])
         if ref is None:
             ref = got
         else:
             for a, b in zip(ref, got):
                 assert np.array_equal(a, b), hm
+
+
+def _survivors(res) -> np.ndarray:
+    """The pre-NMS survivors of a BatchResult, (image, level, y, x) rows."""
+    val = np.asarray(res.valid)
+    return np.stack([np.asarray(a)[val] for a in
+                     (res.img, res.lvl, res.ys, res.xs)], axis=1)
+
+
+def test_dense_mode_tile_exit_keeps_the_split_path_survivors():
+    """Dense mode, the TPU default: the fused head runs every stage with
+    its per-tile exit over a multi-level pyramid.  Per-stage alive counts,
+    survivors and detections equal the split path's; the head's work
+    counter reads the tiles that ran, as the oracle's tile exit over the
+    dense sums counts them, and the split head reads dense work."""
+    from repro.core.pyramid import downscale_indices
+    from repro.kernels import ops, ref
+    from repro.kernels.autotune import DEFAULT_TILE
+
+    rng = np.random.default_rng(11)
+    imgs = [render_scene(rng, 64, 64, n_faces=1)[0] for _ in range(2)]
+    out = {}
+    for hm in ("split", "fused"):
+        det = Detector(CASC, PALL._replace(mode="dense", head_mode=hm))
+        res = det.batch_result(imgs)
+        out[hm] = (np.asarray(res.alive_counts), _survivors(res),
+                   det.detect_batch(imgs), np.asarray(res.head_work))
+    (counts, surv, rects, work), fused = out["split"], out["fused"]
+    np.testing.assert_array_equal(fused[0], counts)
+    np.testing.assert_array_equal(fused[1], surv)
+    for a, b in zip(fused[2], rects):
+        np.testing.assert_array_equal(a, b)
+    assert counts[0].min() > counts[-1].max()      # the thresholds reject
+    np.testing.assert_array_equal(work[:, 0], work[:, 1])
+    np.testing.assert_array_equal(fused[3][:, 1], work[:, 1])
+
+    # the counter, recounted from the dense sums with the oracle's exit
+    plan = det.batch_plan(64, 64, len(imgs))
+    stack = np.stack(imgs).astype(np.float32)
+    n_weak = np.diff(np.asarray(CASC.stage_offsets))
+    no_exit = CASC._replace(stage_threshold=np.full(N_STAGES, -np.inf,
+                                                    np.float32))
+    ty, tx = plan.head_tile or DEFAULT_TILE
+    want = np.zeros(len(imgs), np.int64)
+    for lp in plan.levels:
+        img_l = stack[:, downscale_indices(64, lp.height)[:, None],
+                      downscale_indices(64, lp.width)[None, :]]
+        dense = ops.fused_head_batch(no_exit, CASC, 0, N_STAGES, img_l)[2]
+        for i in range(len(imgs)):
+            sums = ref.tile_exit_ref(dense[i], CASC.stage_threshold)
+            ran = np.isfinite(np.asarray(sums)[:, ::ty, ::tx])
+            want[i] += (ran.sum(axis=(1, 2)) * n_weak).sum()
+    np.testing.assert_array_equal(fused[3][:, 0], want)
+    assert (want < work[:, 1]).all()
 
 
 def test_validate_config_through_plan():

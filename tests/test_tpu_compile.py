@@ -67,12 +67,14 @@ def _compile(fn, args):
                          ids=["wave_head", "dense_all_stages"])
 def test_fused_head_compiles_for_v5e(v5e, n_stages):
     """The wave plan's dense head, and dense mode's whole cascade (the
-    TPU default), whose weak-classifier tables must fit SMEM."""
+    TPU default), whose weak-classifier tables must fit SMEM; the stage
+    loop with its per-tile exit lowers through Mosaic."""
     rel = tuple(int(b) for b in OFF[:n_stages + 1])
-    args = _weak_specs(v5e, 0, rel[-1]) + [_spec(v5e, (H, W), jnp.float32)]
+    args = _weak_specs(v5e, 0, rel[-1]) + [
+        _spec(v5e, (n_stages,), jnp.float32), _spec(v5e, (H, W), jnp.float32)]
     compiled = _compile(
-        lambda a, b, c, d, e, img: fused_head_kernel(
-            a, b, c, d, e, rel, img, interpret=False), args)
+        lambda a, b, c, d, e, th, img: fused_head_kernel(
+            a, b, c, d, e, th, rel, img, interpret=False), args)
     # the resident SAT fits VMEM without spilling to HBM
     assert compiled.memory_analysis().temp_size_in_bytes == 0
 
