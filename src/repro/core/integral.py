@@ -10,25 +10,32 @@ half-open rectangle ``[y0, y0+h) x [x0, x0+w)`` is::
 
 (4 memory accesses — Fig. 4 of the paper).
 
-dtype: float32 throughout.  For uint8 images up to 1024x1024 the maximum
-cumulative value is ~2.7e8, i.e. the f32 ulp at the top-right corner is ~16
-pixel units; rectangle *differences* used by 24x24-window Haar features are
-self-consistent with the training pipeline (which uses the same arithmetic),
-so this loss does not affect detection.  The squared integral image reaches
-~6.8e10 where the f32 ulp is ~4096; window variance over 24x24 windows is
-O(1e7), so ``window_variance`` uses a centred formulation to keep the
-relative error of sigma below 1e-4 (see ``window_inv_sigma``).
+dtype: int32, wrapping.  Pixels are whole numbers (uint8 imagery; other
+values are rounded), so every table is an exact integer prefix sum taken
+modulo 2^32: ``jnp.cumsum`` in int32 wraps past 2^31 on purpose (a VGA
+squared table reaches ~5e9).  Every rectangle sum of a 24x24 window fits
+in int32 (at most 576 * 255 for the plain table, 576 * 128^2 for the
+centred squared one), and two's-complement differences are exact modulo
+2^32, so the four-corner difference of a wrapped table is the exact sum at
+any image size.  Readers take the corner differences in int32 and convert
+to float32 only afterwards (:func:`rect_sum`, :func:`grid_rect_sum`, the
+kernels' ``weak_vote``); normalization, votes and stage sums stay float32.
+A float32 table would round once its entries pass 2^24 (255 * 640 * 480
+~ 7.8e7 at VGA), and the rounding of a window's corners would depend on
+every pixel above and left of it.
 
-The centring constant is *fixed* (``CENTRE = 128``, mid-range of uint8
-imagery) rather than the per-image mean: a content-dependent centre makes
-every window's normalization float-coupled to every pixel of the image,
-which breaks window-locality — the property the streaming engine
-(:mod:`repro.stream`) relies on to reuse cached per-window decisions for
-unchanged tiles across frames.  With a fixed centre, a window's stage sums
-are a pure function of the pixels under the window, so identical pixels
-give bit-identical decisions in any frame, batch, or padding context.  The
-cancellation-error argument is unchanged: pixels lie in [0, 255], so
-|x - 128| <= 128 bounds the squared table the same way mean-centring does.
+Window-locality.  With exact integer tables, a window's rectangle sums,
+its 1/sigma and so its stage sums are a pure function of the pixels under
+the window: identical pixels give bit-identical decisions in any frame,
+batch, bucket or padding context.  The streaming engine
+(:mod:`repro.stream`) relies on this to reuse cached per-window decisions
+for unchanged tiles across frames.  The centring constant of the squared
+and centred tables is *fixed* (``CENTRE = 128``, mid-range of uint8
+imagery) rather than the per-image mean, for the same reason: a
+content-dependent centre would couple every window's normalization to
+every pixel of the image.  Centring also bounds the squared table's
+window sums (|x - 128| <= 128), which keeps them exact in float32 (below
+2^24) once differenced.
 """
 
 from __future__ import annotations
@@ -44,19 +51,26 @@ __all__ = [
     "window_inv_sigma",
     "window_inv_sigma_grid",
     "integral_value",
+    "pixels",
     "CENTRE",
 ]
 
 # fixed centring constant of the squared/centred SATs (see module docstring):
 # content-independent so window normalization is window-local, which is what
 # lets repro.stream reuse cached per-window results across video frames.
-CENTRE = 128.0
+CENTRE = 128
+
+
+def pixels(img: jax.Array) -> jax.Array:
+    """The image as int32 whole numbers, the tables' input."""
+    if jnp.issubdtype(img.dtype, jnp.integer):
+        return img.astype(jnp.int32)
+    return jnp.round(img).astype(jnp.int32)
 
 
 def integral_image(img: jax.Array) -> jax.Array:
-    """Padded summed-area table, shape (H+1, W+1), float32."""
-    img = img.astype(jnp.float32)
-    ii = jnp.cumsum(jnp.cumsum(img, axis=0), axis=1)
+    """Padded summed-area table, shape (H+1, W+1), int32 (wrapping)."""
+    ii = jnp.cumsum(jnp.cumsum(pixels(img), axis=0), axis=1)
     return jnp.pad(ii, ((1, 0), (1, 0)))
 
 
@@ -64,11 +78,11 @@ def integral_images(img: jax.Array) -> tuple[jax.Array, jax.Array]:
     """(integral, squared-integral) of a grayscale image.
 
     The squared integral is computed over the *centred* image (fixed
-    ``CENTRE`` shift) to keep float32 cancellation error small (see module
-    docstring); the constant shift cancels in the variance identity used by
-    :func:`window_inv_sigma`.
+    ``CENTRE`` shift, see module docstring); the constant shift cancels in
+    the variance identity used by :func:`window_inv_sigma`.  All three
+    tables are int32 and wrap.
     """
-    img = img.astype(jnp.float32)
+    img = pixels(img)
     centred = img - CENTRE
     ii = integral_image(img)
     ii2 = integral_image(centred * centred)
@@ -80,10 +94,13 @@ def integral_images(img: jax.Array) -> tuple[jax.Array, jax.Array]:
 
 def rect_sum(ii: jax.Array, ys: jax.Array, xs: jax.Array,
              h: jax.Array, w: jax.Array) -> jax.Array:
-    """Sum of pixels in ``[ys, ys+h) x [xs, xs+w)`` — broadcasts over ys/xs."""
+    """Sum of pixels in ``[ys, ys+h) x [xs, xs+w)`` — broadcasts over ys/xs.
+    The corners are differenced in the table's dtype (int32: exact under
+    wrap-around), then converted to float32."""
     y1 = ys + h
     x1 = xs + w
-    return ii[y1, x1] - ii[ys, x1] - ii[y1, xs] + ii[ys, xs]
+    return (ii[y1, x1] - ii[ys, x1] - ii[y1, xs] + ii[ys, xs]
+            ).astype(jnp.float32)
 
 
 def grid_rect_sum(ii: jax.Array, ny: int, nx: int, step: int,
@@ -101,7 +118,7 @@ def grid_rect_sum(ii: jax.Array, ny: int, nx: int, step: int,
         return jax.lax.dynamic_slice(ii, (cy, cx), span)[::step, ::step]
 
     return (corner(y + h, x + w) - corner(y, x + w) - corner(y + h, x)
-            + corner(y, x))
+            + corner(y, x)).astype(jnp.float32)
 
 
 def _inv_sigma(s2: jax.Array, s1: jax.Array, window: int) -> jax.Array:

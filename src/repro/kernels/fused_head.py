@@ -18,6 +18,13 @@ stage no window of the tile entered is not run, and its sums read
 cumulative ``alive & (sums >= threshold)`` chain keeps it dead, so its
 survivors and per-stage counts are those of the dense head.
 
+The masked variant (``live``, a (ny, nx) bool map) starts each tile from
+its real origins that are also live: the stream step passes the map of
+windows whose pixels changed, so a tile none of whose windows changed
+stops after one mask check and writes ``-inf`` for every stage.  Both
+variants share one kernel body (``masked`` is a ``functools.partial``
+flag); without ``live`` the call and its lowering are the photo path's.
+
 Bit-exactness contract (the engine's tests hold fused against split to
 the last ulp wherever the tile entered the stage; thresholds that reject
 nothing reproduce the dense split path everywhere, and the oracle twin
@@ -27,9 +34,10 @@ nothing reproduce the dense split path everywhere, and the oracle twin
   program, by :func:`repro.core.integral.integral_images` and
   :func:`repro.core.integral.window_inv_sigma_grid` — the very XLA ops
   the split and jnp paths run, so they are identical on every backend.
-  (A prefix sum has no Mosaic lowering, an in-kernel scan would round
-  sums past 2^24 in another order than XLA's cumsum, and Mosaic's
-  sqrt/divide need not round as XLA's do.)
+  The SAT is int32 and wraps; the kernel differences its corners in
+  int32 (exact) and converts each rectangle sum to float32 after.
+  (A prefix sum has no Mosaic lowering, and Mosaic's sqrt/divide need
+  not round as XLA's do.)
 - stage sums of the stages a tile runs:
   :func:`repro.kernels.haar_stage.weak_vote` — the split kernel's own
   per-classifier body — accumulated in ascending k.
@@ -56,8 +64,9 @@ from .haar_stage import sat_pad_shape, weak_tables, weak_vote
 
 
 def _fused_kernel(rx_ref, rw_ref, th_ref, lv_ref, rv_ref,  # SMEM (prefetch)
-                  bd_ref, st_ref, ii_ref, inv_ref, o_ref, *, n_run, tile,
-                  ny, nx):
+                  bd_ref, st_ref, ii_ref, inv_ref, *refs, n_run, tile,
+                  ny, nx, masked):
+    live_ref, o_ref = refs if masked else (None, refs[0])
     ty, tx = tile
     y0 = pl.program_id(0) * ty
     x0 = pl.program_id(1) * tx
@@ -69,10 +78,13 @@ def _fused_kernel(rx_ref, rw_ref, th_ref, lv_ref, rv_ref,  # SMEM (prefetch)
 
     # the tile's alive mask, int32 (Mosaic cannot carry a bool vector
     # through a loop): the origins that are real windows of the level, so
-    # the edge padding never keeps a tile running
+    # the edge padding never keeps a tile running, and live if masked
     iy = jax.lax.broadcasted_iota(jnp.int32, tile, 0)
     ix = jax.lax.broadcasted_iota(jnp.int32, tile, 1)
-    alive0 = ((y0 + iy < ny) & (x0 + ix < nx)).astype(jnp.int32)
+    real = (y0 + iy < ny) & (x0 + ix < nx)
+    if masked:
+        real = real & (live_ref[...] > 0)
+    alive0 = real.astype(jnp.int32)
 
     def more(carry):
         si, alive = carry
@@ -102,18 +114,21 @@ def fused_head_kernel(rect_xywh: jax.Array, rect_w: jax.Array,
                       wc_threshold: jax.Array, left_val: jax.Array,
                       right_val: jax.Array, stage_threshold: jax.Array,
                       rel_bounds: tuple, img: jax.Array, *,
-                      tile=DEFAULT_TILE, interpret: bool):
+                      tile=DEFAULT_TILE, interpret: bool,
+                      live: jax.Array | None = None):
     """One-dispatch head over a full image, with early exit per tile.
 
     The weak-classifier arrays and ``stage_threshold`` cover stages
     ``[s0, s1)`` of the cascade (already sliced by the ops wrapper);
     ``rel_bounds`` are that run's stage boundaries relative to its first
-    weak classifier.  Returns ``(ii, inv_sigma, sums)``: the (H+1, W+1)
-    padded SAT (identical to ``integral_images(img)[0]`` — it feeds the
-    tail's gathers), the (ny, nx) 1/sigma grid, and (n_run, ny, nx)
-    per-stage vote sums — the split path's wherever the window's tile
-    entered the stage, ``-inf`` where it did not.  Handles
-    non-tile-aligned grids by padding and slicing here.
+    weak classifier.  ``live`` (optional, (ny, nx) bool) is the initial
+    alive mask of the masked variant.  Returns ``(ii, inv_sigma, sums)``:
+    the (H+1, W+1) padded int32 SAT (identical to
+    ``integral_images(img)[0]`` — it feeds the tail's gathers), the
+    (ny, nx) 1/sigma grid, and (n_run, ny, nx) per-stage vote sums — the
+    split path's wherever the window's tile entered the stage, ``-inf``
+    where it did not.  Handles non-tile-aligned grids by padding and
+    slicing here.
     """
     h, w = img.shape
     ny = h - WINDOW + 1
@@ -133,16 +148,21 @@ def fused_head_kernel(rect_xywh: jax.Array, rect_w: jax.Array,
     ii_p = jnp.pad(ii, ((0, hp - h - 1), (0, wp - w - 1)), mode="edge")
     inv_p = jnp.pad(inv, ((0, ny_pad - ny), (0, nx_pad - nx)), mode="edge")
 
+    masked = live is not None
+    tiles = [ii_p, inv_p]
+    if masked:
+        tiles.append(jnp.pad(live.astype(jnp.int32),
+                             ((0, ny_pad - ny), (0, nx_pad - nx))))
     kernel = functools.partial(_fused_kernel, n_run=n_run, tile=tile,
-                               ny=ny, nx=nx)
+                               ny=ny, nx=nx, masked=masked)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
         grid=(ny_pad // ty, nx_pad // tx),
         in_specs=[
             # the SAT stays resident for the whole grid
             pl.BlockSpec((hp, wp), lambda i, j, *_: (0, 0)),
-            pl.BlockSpec((ty, tx), lambda i, j, *_: (i, j)),
-        ],
+        ] + [pl.BlockSpec((ty, tx), lambda i, j, *_: (i, j))] * (
+            len(tiles) - 1),
         out_specs=pl.BlockSpec((n_run, ty, tx), lambda i, j, *_: (0, i, j)),
     )
     sums = pl.pallas_call(
@@ -152,5 +172,5 @@ def fused_head_kernel(rect_xywh: jax.Array, rect_w: jax.Array,
         interpret=interpret,
     )(*weak_tables(rect_xywh, rect_w, wc_threshold, left_val, right_val),
       jnp.asarray(rel_bounds, jnp.int32),
-      stage_threshold.astype(jnp.float32), ii_p, inv_p)
+      stage_threshold.astype(jnp.float32), *tiles)
     return ii, inv, sums[:, :ny, :nx]

@@ -84,6 +84,11 @@ def weak_vote(k, rx_ref, rw_ref, th_ref, lv_ref, rv_ref, ii_ref,
     float ordering (``d - b - c + a`` corners; XLA turns its ``/ AREA``
     into this multiply by the reciprocal).
 
+    ``ii_ref`` is the int32 summed-area table
+    (:mod:`repro.core.integral`): the four corners are differenced in
+    int32, exact under wrap-around, and the rectangle sum is converted to
+    float32 after.
+
     The weak-classifier tables are flat SMEM vectors — ``rx_ref`` holds
     (x, y, w, h) per rect, 12 entries per classifier, ``rw_ref`` 3 rect
     weights per classifier — because SMEM pads a (K, 3, 4) table to far
@@ -100,7 +105,7 @@ def weak_vote(k, rx_ref, rw_ref, th_ref, lv_ref, rv_ref, ii_ref,
         bot = row_band(ii_ref, y0, x0, y + h, tile)
         rect = (col_shift(bot, x + w, tx) - col_shift(top, x + w, tx)
                 - col_shift(bot, x, tx) + col_shift(top, x, tx))
-        feat = feat + rw_ref[k * 3 + r] * rect
+        feat = feat + rw_ref[k * 3 + r] * rect.astype(jnp.float32)
     f_norm = feat * inv_sigma * _INV_AREA
     return jnp.where(f_norm < th_ref[k], lv_ref[k], rv_ref[k])
 
@@ -135,9 +140,9 @@ def haar_stage_sums_kernel(rect_xywh: jax.Array, rect_w: jax.Array,
                            interpret: bool) -> jax.Array:
     """Stage sums over a stride-1 window grid.
 
-    ii_padded: padded SAT of at least :func:`sat_pad_shape` ``(ny_pad,
-      nx_pad)`` (the wrapper pads, so every slab the kernel loads is
-      in-bounds).
+    ii_padded: padded int32 SAT of at least :func:`sat_pad_shape`
+      ``(ny_pad, nx_pad)`` (the wrapper pads, so every slab the kernel
+      loads is in-bounds).
     inv_sigma: (ny_pad, nx_pad) normalization grid, tile-aligned.
     Returns (ny_pad, nx_pad) float32 stage sums.
     """
@@ -166,4 +171,4 @@ def haar_stage_sums_kernel(rect_xywh: jax.Array, rect_w: jax.Array,
         out_shape=jax.ShapeDtypeStruct((ny, nx), jnp.float32),
         interpret=interpret,
     )(*weak_tables(rect_xywh, rect_w, wc_threshold, left_val, right_val),
-      ii_padded.astype(jnp.float32), inv_sigma.astype(jnp.float32))
+      ii_padded, inv_sigma.astype(jnp.float32))

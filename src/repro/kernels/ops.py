@@ -207,32 +207,34 @@ def dense_stage_sums_batch(cascade: Cascade, cascade_static: Cascade, s: int,
 # Detector executes when the plan's head mode is "split"); elsewhere -inf.
 
 def fused_head(cascade: Cascade, cascade_static: Cascade, s0: int, s1: int,
-               img: jax.Array, *, tile=DEFAULT_TILE):
+               img: jax.Array, *, tile=DEFAULT_TILE, live=None):
     """Fused head for stages ``[s0, s1)`` over one image.
 
     Returns ``(ii, inv_sigma_grid, stage_sums)``: the (H+1, W+1) padded
-    SAT (feeds the compacted tail's gathers), the (ny, nx) 1/sigma grid,
-    and (s1 - s0, ny, nx) per-stage vote sums — the split path's wherever
-    the window's tile entered the stage under ``cascade.stage_threshold``,
-    ``-inf`` where it did not.
+    int32 SAT (feeds the compacted tail's gathers), the (ny, nx) 1/sigma
+    grid, and (s1 - s0, ny, nx) per-stage vote sums — the split path's
+    wherever the window's tile entered the stage under
+    ``cascade.stage_threshold``, ``-inf`` where it did not.  ``live``
+    ((ny, nx) bool) selects the masked variant: tiles start from their
+    live windows only, so a tile with none runs no stage.
     """
     k0, k1, rel = _stage_run_slices(cascade_static, s0, s1)
     return fused_head_kernel(
         cascade.rect_xywh[k0:k1], cascade.rect_w[k0:k1],
         cascade.wc_threshold[k0:k1], cascade.left_val[k0:k1],
         cascade.right_val[k0:k1], cascade.stage_threshold[s0:s1], rel, img,
-        tile=tile, interpret=interpret_mode())
+        tile=tile, interpret=interpret_mode(), live=live)
 
 
 def fused_head_ref(cascade: Cascade, cascade_static: Cascade, s0: int,
-                   s1: int, img: jax.Array, *, tile=DEFAULT_TILE):
+                   s1: int, img: jax.Array, *, tile=DEFAULT_TILE, live=None):
     """Oracle twin of :func:`fused_head` (same signature contract)."""
     k0, k1, rel = _stage_run_slices(cascade_static, s0, s1)
     return ref.fused_head_ref(
         cascade.rect_xywh[k0:k1], cascade.rect_w[k0:k1],
         cascade.wc_threshold[k0:k1], cascade.left_val[k0:k1],
         cascade.right_val[k0:k1], cascade.stage_threshold[s0:s1], rel, img,
-        tile=tile)
+        tile=tile, live=live)
 
 
 def fused_head_batch(cascade: Cascade, cascade_static: Cascade, s0: int,

@@ -78,7 +78,7 @@ def _gather_stage_sum(cascade: Cascade, ii_flat: jax.Array, img: jax.Array,
         return (ii_flat[img, base + y1 * stride + x1]
                 - ii_flat[img, base + y0 * stride + x1]
                 - ii_flat[img, base + y1 * stride + x0]
-                + ii_flat[img, base + y0 * stride + x0])
+                + ii_flat[img, base + y0 * stride + x0]).astype(jnp.float32)
 
     def body(k, acc):
         rects = jax.lax.dynamic_index_in_dim(cascade.rect_xywh, k, 0, False)
@@ -147,7 +147,8 @@ def _bulk_block(cascade: Cascade, ii_flat: jax.Array, img: jax.Array,
         return ii_flat[img[None, None, :],
                        base[None, None, :] + y * stride[None, None, :] + x]
 
-    area = g(y1, x1) - g(y0, x1) - g(y1, x0) + g(y0, x0)   # (K, 3, cap)
+    area = (g(y1, x1) - g(y0, x1) - g(y1, x0) + g(y0, x0)  # (K, 3, cap)
+            ).astype(jnp.float32)                  # int32 corners, exact
     feat = jnp.zeros((area.shape[0], area.shape[2]), jnp.float32)
     for r in range(rects.shape[1]):
         feat = feat + w[:, r, None] * area[:, r]

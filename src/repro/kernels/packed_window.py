@@ -91,7 +91,8 @@ def _packed_kernel(rx_ref, rw_ref, th_ref, lv_ref, rv_ref,  # SMEM (prefetch)
         return jnp.take(sat, off[None, None] + y * st[None, None] + x,
                         mode="clip")
 
-    area = g(y1, x1) - g(y0, x1) - g(y1, x0) + g(y0, x0)    # (K, 3, ty, tx)
+    area = (g(y1, x1) - g(y0, x1) - g(y1, x0) + g(y0, x0)   # (K, 3, ty, tx)
+            ).astype(jnp.float32)                   # int32 corners, exact
     feat = jnp.zeros((rects.shape[0],) + tile, jnp.float32)
     for r in range(3):                      # static unroll: <= 3 rects
         feat = feat + w[:, r, None, None] * area[:, r]
@@ -114,7 +115,8 @@ def packed_stage_sums_kernel(rect_xywh: jax.Array, rect_w: jax.Array,
                              interpret: bool) -> jax.Array:
     """Stage-run vote sums over a blocked packed window list.
 
-    sat_flat: (1, N) every image's every level's SAT, flattened+concatenated.
+    sat_flat: (1, N) int32 every image's every level's SAT,
+      flattened+concatenated.
     off/stride/ys/xs: (n_rows, tx) int32 per-window addressing, tile-aligned
       (``n_rows`` a multiple of ``tile[0]``; the ops wrapper pads).
     inv_sigma: (n_rows, tx) float32 normalization.
@@ -160,7 +162,7 @@ def _packed_call(rect_xywh, rect_w, wc_threshold, left_val, right_val,
         interpret=interpret,
     )(rect_xywh.astype(jnp.int32), rect_w.astype(jnp.float32),
       wc_threshold.astype(jnp.float32), left_val.astype(jnp.float32),
-      right_val.astype(jnp.float32), sat_flat.astype(jnp.float32),
+      right_val.astype(jnp.float32), sat_flat,
       off.astype(jnp.int32), stride.astype(jnp.int32),
       ys.astype(jnp.int32), xs.astype(jnp.int32),
       inv_sigma.astype(jnp.float32))

@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.cascade import WINDOW
-from repro.core.integral import CENTRE, rect_sum
+from repro.core.integral import CENTRE, pixels, rect_sum
 
 from .autotune import DEFAULT_TILE
 
@@ -19,17 +19,25 @@ _AREA = float(WINDOW * WINDOW)
 
 
 def integral_image_ref(img: jax.Array) -> jax.Array:
-    """Inclusive 2-D cumulative sum (unpadded), float32 — kernel contract."""
+    """Inclusive 2-D cumulative sum (unpadded), float32 — the contract of
+    the standalone scan kernel (:mod:`repro.kernels.integral_image`)."""
     img = img.astype(jnp.float32)
     return jnp.cumsum(jnp.cumsum(img, axis=0), axis=1)
+
+
+def _int_sat(x: jax.Array) -> jax.Array:
+    """Padded (H+1, W+1) int32 summed-area table of whole-number pixels,
+    wrapping like :func:`repro.core.integral.integral_image`."""
+    return jnp.pad(jnp.cumsum(jnp.cumsum(x, axis=0), axis=1),
+                   ((1, 0), (1, 0)))
 
 
 def window_inv_sigma_ref(ii2: jax.Array, iic: jax.Array, ny: int, nx: int,
                          window: int = WINDOW) -> jax.Array:
     """(ny, nx) grid of 1/sigma per window origin (stride 1).
 
-    ii2/iic are *padded* SATs of the centred-squared / centred image
-    (see repro.core.integral.integral_images).
+    ii2/iic are *padded* int32 SATs of the centred-squared / centred
+    image (see repro.core.integral.integral_images).
     """
     n = float(window * window)
     ys = jnp.arange(ny)[:, None]
@@ -72,16 +80,19 @@ def dense_stage_sums_ref(rect_xywh: jax.Array, rect_w: jax.Array,
 
 # ----------------------------------------------------------------- fused
 def tile_exit_ref(sums: jax.Array, stage_threshold: jax.Array,
-                  tile=DEFAULT_TILE) -> jax.Array:
+                  tile=DEFAULT_TILE, live: jax.Array | None = None
+                  ) -> jax.Array:
     """Dense (n_run, ny, nx) stage sums with the fused head's early exit
     per (ty, tx) tile applied: a stage's sums are kept where some window
-    of the tile is still alive entering it (``sums >= stage_threshold``
-    at every earlier stage of the run), and read ``-inf`` elsewhere."""
+    of the tile is still alive entering it (``live``, default every
+    window, and ``sums >= stage_threshold`` at every earlier stage of the
+    run), and read ``-inf`` elsewhere."""
     n_run, ny, nx = sums.shape
     ty, tx = tile
     nty, ntx = -(-ny // ty), -(-nx // tx)
     pad = ((0, nty * ty - ny), (0, ntx * tx - nx))
-    alive = jnp.ones((ny, nx), bool)
+    alive = (jnp.ones((ny, nx), bool) if live is None
+             else jnp.asarray(live, bool))
     out = []
     for s in range(n_run):
         entered = jnp.pad(alive, pad).reshape(nty, ty, ntx, tx).any((1, 3))
@@ -94,30 +105,32 @@ def tile_exit_ref(sums: jax.Array, stage_threshold: jax.Array,
 def fused_head_ref(rect_xywh: jax.Array, rect_w: jax.Array,
                    wc_threshold: jax.Array, left_val: jax.Array,
                    right_val: jax.Array, stage_threshold: jax.Array,
-                   rel_bounds: tuple, img: jax.Array, *, tile=DEFAULT_TILE):
+                   rel_bounds: tuple, img: jax.Array, *, tile=DEFAULT_TILE,
+                   live: jax.Array | None = None):
     """Oracle twin of the fused head megakernel (kernels/fused_head.py):
     the split path composed from this module's own pieces, then
     :func:`tile_exit_ref`.  The weak-classifier arrays and
     ``stage_threshold`` cover one stage run; ``rel_bounds`` are its
-    per-stage boundaries.  Returns ``(ii, inv_sigma, sums)`` — the
-    (H+1, W+1) padded SAT, the (ny, nx) 1/sigma grid, and (n_run, ny, nx)
-    per-stage vote sums, ``-inf`` where the window's tile exited before.
+    per-stage boundaries; ``live`` is the masked variant's (ny, nx)
+    initial alive mask.  Returns ``(ii, inv_sigma, sums)`` — the
+    (H+1, W+1) padded int32 SAT, the (ny, nx) 1/sigma grid, and
+    (n_run, ny, nx) per-stage vote sums, ``-inf`` where the window's tile
+    exited before.
     """
-    img = img.astype(jnp.float32)
-    h, w = img.shape
+    x = pixels(img)
+    h, w = x.shape
     ny, nx = h - WINDOW + 1, w - WINDOW + 1
-    pad = ((1, 0), (1, 0))
-    ii = jnp.pad(integral_image_ref(img), pad)
-    centred = img - CENTRE
-    ii2 = jnp.pad(integral_image_ref(centred * centred), pad)
-    iic = jnp.pad(integral_image_ref(centred), pad)
+    ii = _int_sat(x)
+    centred = x - CENTRE
+    ii2 = _int_sat(centred * centred)
+    iic = _int_sat(centred)
     inv = window_inv_sigma_ref(ii2, iic, ny, nx)
     sums = jnp.stack([
         dense_stage_sums_ref(rect_xywh[a:b], rect_w[a:b],
                              wc_threshold[a:b], left_val[a:b],
                              right_val[a:b], ii, inv)
         for a, b in zip(rel_bounds[:-1], rel_bounds[1:])])
-    return ii, inv, tile_exit_ref(sums, stage_threshold, tile)
+    return ii, inv, tile_exit_ref(sums, stage_threshold, tile, live)
 
 
 def fused_head_batch_ref(rect_xywh: jax.Array, rect_w: jax.Array,
@@ -155,7 +168,7 @@ def packed_stage_sums_ref(rect_xywh: jax.Array, rect_w: jax.Array,
         return (ii_flat[img, base + y1 * stride + x1]
                 - ii_flat[img, base + y0 * stride + x1]
                 - ii_flat[img, base + y1 * stride + x0]
-                + ii_flat[img, base + y0 * stride + x0])
+                + ii_flat[img, base + y0 * stride + x0]).astype(jnp.float32)
 
     def body(k, acc):
         rects = jax.lax.dynamic_index_in_dim(rect_xywh, k, 0, False)
@@ -220,27 +233,33 @@ def window_inv_sigma_grid_batch_ref(ii_pairs: jax.Array, ny: int, nx: int,
 
 
 # Oracle twins of the device tile-planning kernels (repro.kernels
-# .tile_change): independent algorithms — direct per-tile reshape
-# reductions instead of SAT corner lookups, and a boolean range-matmul
-# instead of the integer SAT — so a SAT indexing bug cannot hide in its
-# own oracle.  Masks match bit-for-bit; float *scores* agree to
-# summation-order tolerance (the kernel sums through a float32 SAT).
+# .tile_change): independent algorithms — SAT corner lookups (the paper's
+# Fig. 4 arithmetic) instead of per-tile reductions, and an integer SAT
+# instead of the range matmuls — so a reduction or indicator bug cannot
+# hide in its own oracle.  Masks match bit-for-bit; float *scores* agree
+# to summation-order tolerance (this oracle sums through a float32 SAT).
 
 def tile_change_mask_ref(prev: jax.Array, cur: jax.Array,
                          threshold: jax.Array, *, tile: int, halo: int = 0,
                          exact: bool = True) -> tuple[jax.Array, jax.Array]:
-    """(changed, scores) per tile via direct zero-padded reshape sums."""
+    """(changed, scores) per tile via four SAT corner lookups per tile."""
     h, w = cur.shape
     ty, tx = -(-h // tile), -(-w // tile)
     d = cur.astype(jnp.float32) - prev.astype(jnp.float32)
-    pad = ((0, ty * tile - h), (0, tx * tile - w))
-    sq = jnp.pad(d * d, pad).reshape(ty, tile, tx, tile)
-    area = jnp.pad(jnp.ones((h, w), jnp.float32), pad
-                   ).reshape(ty, tile, tx, tile).sum(axis=(1, 3))
-    scores = sq.sum(axis=(1, 3)) / jnp.maximum(area, 1.0)
+    ys = jnp.minimum(jnp.arange(ty + 1) * tile, h)
+    xs = jnp.minimum(jnp.arange(tx + 1) * tile, w)
+
+    def tile_sums(x):
+        sat = jnp.pad(jnp.cumsum(jnp.cumsum(x, axis=0), axis=1),
+                      ((1, 0), (1, 0)))
+        c = sat[ys[:, None], xs[None, :]]
+        return c[1:, 1:] - c[:-1, 1:] - c[1:, :-1] + c[:-1, :-1]
+
+    area = (jnp.diff(ys)[:, None] * jnp.diff(xs)[None, :]
+            ).astype(jnp.float32)
+    scores = tile_sums(d * d) / jnp.maximum(area, 1.0)
     if exact:
-        changed = jnp.pad(d != 0.0, pad).reshape(
-            ty, tile, tx, tile).any(axis=(1, 3))
+        changed = tile_sums((d != 0.0).astype(jnp.int32)) > 0
     else:
         changed = scores > threshold
     for _ in range(halo):
@@ -255,11 +274,10 @@ def tile_change_mask_ref(prev: jax.Array, cur: jax.Array,
 def changed_window_map_ref(changed: jax.Array, ty0: jax.Array,
                            ty1: jax.Array, tx0: jax.Array, tx1: jax.Array,
                            valid: jax.Array) -> jax.Array:
-    """Flat window mask via explicit range-indicator integer matmuls."""
-    ty, tx = changed.shape
-    ry = ((jnp.arange(ty)[None, :] >= ty0[:, None])
-          & (jnp.arange(ty)[None, :] <= ty1[:, None])).astype(jnp.int32)
-    rx = ((jnp.arange(tx)[None, :] >= tx0[:, None])
-          & (jnp.arange(tx)[None, :] <= tx1[:, None])).astype(jnp.int32)
-    cnt = ry @ changed.astype(jnp.int32) @ rx.T
+    """Flat window mask via an integer SAT over the changed-tile grid."""
+    sat = jnp.pad(jnp.cumsum(jnp.cumsum(changed.astype(jnp.int32), axis=0),
+                             axis=1), ((1, 0), (1, 0)))
+    y1, x1 = (ty1 + 1)[:, None], (tx1 + 1)[None, :]
+    y0, x0 = ty0[:, None], tx0[None, :]
+    cnt = sat[y1, x1] - sat[y0, x1] - sat[y1, x0] + sat[y0, x0]
     return (cnt > 0).reshape(-1) & valid

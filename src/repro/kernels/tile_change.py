@@ -5,13 +5,15 @@ jax ports of :mod:`repro.stream.tiles` (`tile_change_scores`,
 device-resident stream step (:meth:`repro.stream.StreamEngine.stream_step`)
 can compute a whole frame plan without a host round-trip:
 
-- :func:`tile_change_mask_kernel` — per-tile change scores from the SAT of
-  the squared frame delta (the paper's Fig. 4 arithmetic, four corner
-  lookups per tile), the exact/thresholded changed mask, and the halo
-  dilation, in one pass;
+- :func:`tile_change_mask_kernel` — per-tile change scores as per-tile
+  reductions of the squared frame delta, the exact/thresholded changed
+  mask, and the halo dilation, in one pass;
 - :func:`changed_window_map_kernel` — the changed-tile -> window range-OR
-  per pyramid level, answered with an *integer* SAT over the tile mask
-  (exact in int32: counts are bounded by the tile-grid size).
+  per pyramid level, answered with two boolean range-indicator matmuls
+  (exact: 0/1 operands, and only ``> 0`` is read of each product).
+
+Neither gathers: both run as reductions, compares and matmuls, which the
+TPU streams, where an XLA gather serializes (ROADMAP 1.3a).
 
 Geometry never originates here: the per-level receptive-field tile-range
 tables and window-limit masks are compiled once by
@@ -20,13 +22,14 @@ tables and window-limit masks are compiled once by
 ``exact=True`` the changed test is a per-tile any-reduction of
 ``delta != 0`` — IEEE subtraction is exact at zero (``RN(x - y) == 0``
 iff ``x == y``), so the float32 device test equals the host's float64
-one bit-for-bit.  Positive-threshold *scores* are float32 SAT sums here
+one bit-for-bit.  Positive-threshold *scores* are float32 sums here
 vs float64 on host, so near-threshold tiles may classify differently
 (documented divergence; threshold 0 is the bit-identity contract).
 """
 
 from __future__ import annotations
 
+import numpy as np
 import jax
 import jax.numpy as jnp
 
@@ -48,19 +51,15 @@ def tile_change_mask_kernel(prev: jax.Array, cur: jax.Array,
     h, w = cur.shape
     ty, tx = -(-h // tile), -(-w // tile)
     d = cur.astype(jnp.float32) - prev.astype(jnp.float32)
-    sat = jnp.pad(jnp.cumsum(jnp.cumsum(d * d, axis=0), axis=1),
-                  ((1, 0), (1, 0)))
-    ys = jnp.minimum(jnp.arange(ty + 1) * tile, h)
-    xs = jnp.minimum(jnp.arange(tx + 1) * tile, w)
-    corners = sat[ys[:, None], xs[None, :]]
-    sums = (corners[1:, 1:] - corners[:-1, 1:]
-            - corners[1:, :-1] + corners[:-1, :-1])
-    areas = (jnp.diff(ys)[:, None] * jnp.diff(xs)[None, :]
-             ).astype(jnp.float32)
-    scores = sums / jnp.maximum(areas, 1.0)
+    pad = ((0, ty * tile - h), (0, tx * tile - w))
+    sums = jnp.pad(d * d, pad).reshape(ty, tile, tx, tile).sum(axis=(1, 3))
+    ys = np.minimum(np.arange(ty + 1) * tile, h)
+    xs = np.minimum(np.arange(tx + 1) * tile, w)
+    areas = (np.diff(ys)[:, None] * np.diff(xs)[None, :]).astype(np.float32)
+    scores = sums / np.maximum(areas, 1.0)
 
     if exact:
-        nz = jnp.pad(d != 0.0, ((0, ty * tile - h), (0, tx * tile - w)))
+        nz = jnp.pad(d != 0.0, pad)
         changed = nz.reshape(ty, tile, tx, tile).any(axis=(1, 3))
     else:
         changed = scores > threshold
@@ -82,13 +81,21 @@ def changed_window_map_kernel(changed: jax.Array, ty0: jax.Array,
     ``ty0/ty1`` (ny,) and ``tx0/tx1`` (nx,) are the closed tile-range
     brackets of each window origin's receptive field (compiled host-side
     by the plan layer); ``valid`` is the flat window-limit mask.  The
-    range-OR is an integer SAT over the changed-tile grid — exact, the
-    same arithmetic as the host :func:`repro.stream.tiles
-    .changed_window_mask`.
+    range-OR is two matmuls with the (ny, ty) and (nx, tx) indicator
+    matrices of those ranges: rows that meet a changed tile, then windows
+    whose columns meet one of those rows — exact, since every operand is
+    0 or 1 and only ``> 0`` is read.
     """
-    sat = jnp.pad(jnp.cumsum(jnp.cumsum(changed.astype(jnp.int32), axis=0),
-                             axis=1), ((1, 0), (1, 0)))
-    y1, x1 = (ty1 + 1)[:, None], (tx1 + 1)[None, :]
-    y0, x0 = ty0[:, None], tx0[None, :]
-    cnt = sat[y1, x1] - sat[y0, x1] - sat[y1, x0] + sat[y0, x0]
+    n_ty, n_tx = changed.shape
+
+    def indicator(lo, hi, n):
+        t = jnp.arange(n)[None, :]
+        return ((t >= lo[:, None]) & (t <= hi[:, None])).astype(jnp.float32)
+
+    ry = indicator(ty0, ty1, n_ty)                      # (ny, n_ty)
+    rx = indicator(tx0, tx1, n_tx)                      # (nx, n_tx)
+    rows = jnp.dot(ry, changed.astype(jnp.float32),
+                   preferred_element_type=jnp.float32) > 0     # (ny, n_tx)
+    cnt = jnp.dot(rows.astype(jnp.float32), rx.T,
+                  preferred_element_type=jnp.float32)          # (ny, nx)
     return (cnt > 0).reshape(-1) & valid

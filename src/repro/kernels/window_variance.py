@@ -38,7 +38,7 @@ def _inv_sigma_kernel(ii2_ref, iic_ref, o_ref, *, tile):
         b = col_shift(top, WINDOW, tx)
         c = bot[:, :tx]
         d = col_shift(bot, WINDOW, tx)
-        return (d - b) - (c - a)
+        return ((d - b) - (c - a)).astype(jnp.float32)   # int32 corners
 
     s2 = window_sum(ii2_ref)
     s1 = window_sum(iic_ref)
@@ -68,4 +68,4 @@ def window_inv_sigma_kernel(ii2_padded: jax.Array, iic_padded: jax.Array,
         out_specs=pl.BlockSpec((ty, tx), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((ny, nx), jnp.float32),
         interpret=interpret,
-    )(ii2_padded.astype(jnp.float32), iic_padded.astype(jnp.float32))
+    )(ii2_padded, iic_padded)

@@ -42,7 +42,8 @@ class Span(NamedTuple):
     thread: int       # threading.get_ident() of the recording thread
     id: int
     parent: int       # id of the enclosing span on that thread; 0 if none
-    attrs: dict       # small counts: flush, req, n, bytes (fun_name)
+    attrs: dict       # small counts: flush, req, n, bytes, head_work
+    #                   (fun_name; head_windows, a tuple per stage)
 
 
 _ring: collections.deque = collections.deque(maxlen=RING_SIZE)

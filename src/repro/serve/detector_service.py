@@ -48,7 +48,9 @@ sessions that share a *plan key* (their shape bucket, hence their compiled
 packed incremental engine — one compaction for every co-keyed stream's
 changed windows — and sessions that need a full refresh (first frame,
 keyframe, over-budget change) are batched through
-``Detector.detect_batch_raw``.  This is the content-dependent,
+``Detector.detect_batch_raw``.  Sessions with device-resident state
+(``StreamConfig.device_state``) dispatch one device step per frame, which
+runs keyframes and refreshes too.  This is the content-dependent,
 variable-size task stream the asymmetric-scheduling literature targets:
 mostly-static streams produce tiny work items, busy streams produce big
 ones, and the rate-weighted split keeps the pods balanced either way.
@@ -751,11 +753,13 @@ class DetectorService:
     def _run_stream_shard(self, shard: list[Request]) -> None:
         """Process one round of frames (<= 1 per session).
 
-        Plans every session's frame, then batches the work *across*
-        sessions: incremental frames of sessions sharing a plan key go
-        through one shared-compaction call on the packed engine (grouped by
-        the key, chopped to ``batch_sizes``), and frames needing a full
-        refresh go through ``detect_batch_raw`` together.  Any failure or
+        Host-planned sessions: plans every session's frame, then batches
+        the work *across* sessions: incremental frames of sessions sharing
+        a plan key go through one shared-compaction call on the packed
+        engine (grouped by the key, chopped to ``batch_sizes``), and frames
+        needing a full refresh go through ``detect_batch_raw`` together.
+        Device-resident sessions run every frame, keyframes and full
+        refreshes included, in their own device step.  Any failure or
         overflow degrades per frame, never the whole round.
         """
         incr: list[tuple[Request, np.ndarray, object]] = []
@@ -781,7 +785,7 @@ class DetectorService:
                 rects, stats = video.commit_cached(frame, plan)
                 self._complete(fr, rects, stats)
             elif plan.mode == "full":
-                full.append((fr, frame, None))
+                full.append((fr, frame))
             else:
                 incr.append((fr, frame, plan))
 
@@ -808,8 +812,7 @@ class DetectorService:
                         self._complete(fr, e)
                     continue
                 if overflow:   # shared capacity blown: full-refresh chunk
-                    full.extend((fr, frame, None)
-                                for (fr, frame, _plan) in chunk)
+                    full.extend((fr, frame) for (fr, frame, _plan) in chunk)
                     continue
                 for (fr, frame, plan), bm in zip(chunk, bitmaps):
                     rects, stats = fr.session.video.commit_incremental(
@@ -817,20 +820,11 @@ class DetectorService:
                     self._complete(fr, rects, stats)
 
         # ---- device-resident sessions, dispatched up-front: collect each
-        # step's verdict; cached/incremental frames finish straight off the
-        # device state, full-needed frames join the batched keyframe flush
+        # step's verdict and finish the frame off the committed state
         for fr, tok in dev:
-            video = fr.session.video
             try:
-                mode = video.poll(tok)
-                if mode == "full":
-                    # carry the step's device frame so the session's state
-                    # re-seed after the batched detect skips re-uploading it
-                    full.append((fr, video.discard_token(tok),
-                                 tok.dev_frame))
-                else:
-                    rects, stats = video.commit_token(tok)
-                    self._complete(fr, rects, stats)
+                rects, stats = fr.session.video.retire(tok)
+                self._complete(fr, rects, stats)
             except Exception as e:             # noqa: BLE001
                 self._complete(fr, e)
 
@@ -847,15 +841,14 @@ class DetectorService:
         if len(chunk) > 1:
             try:
                 levels = self.detector.detect_batch_raw(
-                    [frame for _fr, frame, _dev in chunk])
+                    [frame for _fr, frame in chunk])
             except CapacityOverflow:
                 levels = None                  # isolate per frame below
-        for i, (fr, frame, dev_frame) in enumerate(chunk):
+        for i, (fr, frame) in enumerate(chunk):
             try:
                 wins = (level_windows_from_raw(levels, i)
                         if levels is not None else None)
-                rects, stats = fr.session.video.commit_full(
-                    frame, wins, dev_frame=dev_frame)
+                rects, stats = fr.session.video.commit_full(frame, wins)
                 self._complete(fr, rects, stats)
             except Exception as e:             # noqa: BLE001
                 self._complete(fr, e)

@@ -1,30 +1,36 @@
-"""Packed incremental cascade evaluation over changed windows.
+"""Incremental cascade evaluation over changed windows: two executors.
 
-This is ``Detector._build_batch_fn``'s shared-compaction tail with the
-dense-wave head cut off: the initial alive set is not "every window that
-survived the dense waves" but "every window whose tile content changed"
-(computed on host by :mod:`repro.stream.tiles`).  Changed windows from
-every frame in the stack and every pyramid level are compacted into one
-shared window list and run through *all* cascade stages by the shared
-packed-tail evaluator (:mod:`repro.kernels.packed_tail`) — whose three
-backends (gather oracle, bulk gather, blocked Pallas kernel) are
-bit-identical per window to the baseline engine's tail, so a recomputed
-window reaches exactly the decision a full-frame ``detect`` would.
+**Device-resident streams** (:meth:`StreamEngine.stream_step`, the
+``StreamConfig.device_state`` path): one jitted step per (stream plan,
+exactness) scores the frame's tiles against the reference, maps changed
+tiles to changed windows per pyramid level, decides cached / incremental /
+full, and evaluates every window to recompute — the changed windows, or
+every live window on a keyframe or a full refresh — through one head call
+per level whose initial alive mask is that level's map: the masked fused
+head (:func:`repro.kernels.ops.fused_head` with ``live``), whose tiles
+without a live window stop after one mask check.  There is no packed tail
+on this path, no compacted window list, so no capacity and no rung: the
+program's shape does not depend on how much changed, and nothing on the
+device path gathers SAT corners.  Levels with nothing to evaluate are
+skipped by ``lax.cond``.  Survivors merge into the donated state's bitmap
+(``(bitmap & ~recomputed) | survivors``).
 
-One jitted program per :class:`repro.plan.CascadePlan` — the plan layer
-compiles (bucket shape, batch size, capacity rung, active level subset)
-into the typed IR this executor consumes: the rung is the smallest
-power-of-two holding the flush's actual changed count
-(:func:`repro.plan.stream_capacity_rung`; the host built the masks, so
-the count is known before dispatch), the *level subset* is the set of
-pyramid levels that actually have changed windows this flush, and the
-rung's packed-tail backend is the plan's per-segment decision off the
-measured ``EngineConfig.tail_rungs`` crossover ladder.  Levels whose
-windows are all cached are skipped entirely — no SAT is built for them,
-and the packed flat slot/SAT layout covers only the active subset.
-Concurrent streams' changed-tile work items share the single compaction,
-which is what makes many mostly-static streams cheap: the packed list is
-sized to the *sum* of their (small) changed sets, paid once per flush.
+**Host-planned streams** (:meth:`StreamEngine.incremental`): this is
+``Detector._build_batch_fn``'s shared-compaction tail with the dense-wave
+head cut off — the initial alive set is "every window whose tile content
+changed" (computed on host by :mod:`repro.stream.tiles`).  Changed windows
+from every frame in the stack and every pyramid level are compacted into
+one shared window list and run through *all* cascade stages by the shared
+packed-tail evaluator (:mod:`repro.kernels.packed_tail`), bit-identical
+per window to the baseline engine's tail.  One jitted program per
+:class:`repro.plan.CascadePlan` (bucket shape, batch size, capacity rung —
+the smallest power of two holding the flush's changed count — and active
+level subset: levels whose windows are all cached build no SAT).
+Concurrent streams' changed-tile work items share the single compaction.
+
+Both read int32 summed-area tables (:mod:`repro.core.integral`), so a
+window's decision depends on the pixels under it alone and a cached
+decision is exact at threshold 0.
 """
 
 from __future__ import annotations
@@ -37,9 +43,11 @@ import jax.numpy as jnp
 
 from repro.core.cascade import Cascade, WINDOW
 from repro.core.engine import Detector
-from repro.core.integral import integral_images
+from repro.core.features import run_sums_grid
+from repro.core.integral import integral_images, window_inv_sigma_grid
 from repro.core.pyramid import downscale_indices
 from repro.kernels import packed_tail
+from repro.kernels.autotune import DEFAULT_TILE
 from repro.kernels.tile_change import (tile_change_mask_kernel,
                                        changed_window_map_kernel)
 from repro.plan import (STREAM_CAP_BASE, LevelSubset,  # noqa: F401
@@ -71,7 +79,8 @@ def _packed_inv_sigma(pair_flat: jax.Array, img: jax.Array, base: jax.Array,
         return (pair_flat[img, tab, base + y1 * stride + x1]
                 - pair_flat[img, tab, base + y0 * stride + x1]
                 - pair_flat[img, tab, base + y1 * stride + x0]
-                + pair_flat[img, tab, base + y0 * stride + x0])
+                + pair_flat[img, tab, base + y0 * stride + x0]
+                ).astype(jnp.float32)        # int32 corners, exact
 
     s2 = rect(0, ys, xs)
     s1 = rect(1, ys, xs)
@@ -96,22 +105,23 @@ class StreamState(NamedTuple):
     #                       diagnostic: scoring is always vs the reference
     #                       frame, so sub-threshold drift never compounds)
     frame_idx: jax.Array  # () i32 stream frame counter
-    last_full: jax.Array  # () i32 frame index of the last full refresh
+    last_full: jax.Array  # () i32 frame index of the last full refresh;
+    #                       -1 before the stream's first frame
 
 
 class StreamStepOut(NamedTuple):
     """Per-frame result of the device plan-and-eval step (device arrays;
-    the host fetches the scalars, and the slot list only on incremental
-    commits)."""
+    the host fetches the scalars, then the slot list)."""
     mode: jax.Array           # () i32: 0 cached, 1 incremental, 2 full
     tiles_changed: jax.Array  # () i32 changed tiles after halo dilation
     n_rec: jax.Array          # () i32 windows to recompute
     levels_active: jax.Array  # () i32 levels with any changed window
-    retry: jax.Array          # () bool: packed rung overflow — nothing
-    #                           committed; re-dispatch at a larger rung
     n_surv: jax.Array         # () i32 survivors in the committed bitmap
     slots: jax.Array          # (decode_cap,) i32 ascending survivor slots
     #                           (fill value n_slots past n_surv)
+    head_tiles: jax.Array     # (n_stages,) i32 head tiles that ran each
+    #                           stage, summed over the levels evaluated
+    dense_tiles: jax.Array    # () i32 head tiles of the levels evaluated
 
 
 class StreamEngine:
@@ -129,6 +139,10 @@ class StreamEngine:
         self.sat_level_total = 0
         self.dispatches = 0
         self.program_builds = 0          # executor builds (plan-cache probe)
+        self.head_tile = tuple(DEFAULT_TILE)   # the device step's head tile
+        # weak classifiers per stage: the head-work counters' unit
+        # repro: ignore[HOST_SYNC] host constant of the cascade, read once at construction
+        self.n_weak = np.diff(np.asarray(detector.cascade.stage_offsets))
 
     @property
     def sat_level_frac(self) -> float:
@@ -239,112 +253,98 @@ class StreamEngine:
         return compile_stream_plan(det.config, det.n_stages, hp, wp, h, w,
                                    tile, halo, decode_cap=decode_cap)
 
-    def init_state(self, splan, frame: np.ndarray, bitmap: np.ndarray,
-                   frame_idx: int, last_full: int) -> StreamState:
-        """Upload a stream's temporal state (after a host full refresh)."""
-        ref = np.zeros((splan.hp, splan.wp), np.float32)
-        ref[:splan.h, :splan.w] = frame
-        # repro: ignore[HOST_SYNC] keyframe upload: host bitmap seeds the device state
-        bm = np.asarray(bitmap, bool)
-        return StreamState(jnp.asarray(ref), jnp.asarray(bm),
+    def init_state(self, splan, frame_idx: int) -> StreamState:
+        """A fresh device state: no reference, no cached decision, and
+        ``last_full`` -1, so the stream's next step runs a full refresh
+        (the stream's opening keyframe) on the device."""
+        return StreamState(jnp.zeros((splan.hp, splan.wp), jnp.float32),
+                           jnp.zeros(splan.n_slots, bool),
                            jnp.zeros((splan.ty, splan.tx), jnp.float32),
                            jnp.asarray(np.int32(frame_idx)),
-                           jnp.asarray(np.int32(last_full)))
+                           jnp.asarray(np.int32(-1)))
 
-    def refresh_state(self, splan):
-        """The fast-path twin of :meth:`init_state` for device streams
-        whose full-refresh frame is already device-resident (it was the
-        step's input): donates the stale state and the frame buffer, so
-        the only host→device traffic is the survivor bitmap and two
-        counters."""
-        key = ("stream_refresh", splan.key)
-        if key not in self._fns:
-            ty, tx = splan.ty, splan.tx
-            self.program_builds += 1
-
-            def refresh(state: StreamState, frame: jax.Array,
-                        bitmap: jax.Array, frame_idx: jax.Array,
-                        last_full: jax.Array) -> StreamState:
-                del state    # donated: its buffers back the new pytree
-                return StreamState(frame, bitmap,
-                                   jnp.zeros((ty, tx), jnp.float32),
-                                   frame_idx, last_full)
-
-            self._fns[key] = jax.jit(refresh, donate_argnums=(0, 1))
-        return self._fns[key]
-
-    def provisional_refresh(self, splan):
-        """Re-seed only the verdict-bearing half of the state — reference
-        pixels and counters — leaving the survivor bitmap stale.  The
-        step's mode decision never reads the bitmap, so a successor frame
-        can dispatch against this *before* the full refresh's host detect
-        produces the real bitmap; a committed verdict is then re-run
-        against the trued-up state (see ``VideoDetector.poll``)."""
-        key = ("stream_refresh_prov", splan.key)
-        if key not in self._fns:
-            ty, tx = splan.ty, splan.tx
-            self.program_builds += 1
-
-            def refresh(state: StreamState, frame: jax.Array,
-                        frame_idx: jax.Array, last_full: jax.Array
-                        ) -> StreamState:
-                return StreamState(frame, state.bitmap,
-                                   jnp.zeros((ty, tx), jnp.float32),
-                                   frame_idx, last_full)
-
-            self._fns[key] = jax.jit(refresh, donate_argnums=(0, 1))
-        return self._fns[key]
-
-    def stream_step(self, splan, rung: int, exact: bool,
-                    full_refresh_frac: float):
-        """The jitted donated plan-and-eval step for (plan, rung, exact,
-        refresh policy) — cached like every other program."""
+    def stream_step(self, splan, exact: bool, full_refresh_frac: float):
+        """The jitted donated plan-and-eval step for (plan, exactness,
+        refresh policy) — one program, cached like every other."""
         # the host float compares `n > frac * total` are reproduced on
         # device as integer compares against floor(frac * total): for
         # integer n and real c >= 0, n > c iff n > floor(c)
         tile_lim = int(full_refresh_frac * (splan.ty * splan.tx))
         win_lim = int(full_refresh_frac * max(splan.n_live, 1))
-        budget = stream_budget(splan.n_slots, 1, self.max_changed_frac)
-        key = ("stream_state", splan.key, rung, exact, tile_lim, win_lim,
-               budget)
+        key = ("stream_state", splan.key, exact, tile_lim, win_lim)
         if key not in self._fns:
-            self._fns[key] = self._build_stream_fn(
-                splan, rung, exact, tile_lim, win_lim, budget)
+            self._fns[key] = self._build_stream_fn(splan, exact, tile_lim,
+                                                   win_lim)
         return self._fns[key]
 
-    def _build_stream_fn(self, splan, rung: int, exact: bool, tile_lim: int,
-                         win_lim: int, budget: int):
-        """One fused jitted program per (stream plan, rung, exactness,
-        refresh limits): on-device tile change scoring, per-level window
-        mapping, the cached/incremental/full mode decision, and — only
-        when an incremental commit is on (``lax.cond``) — the per-level
-        SATs plus the packed all-stage tail at the fixed ``rung``
-        capacity.  The state argument is donated: steady-state frames
-        allocate nothing new."""
+    def _build_stream_fn(self, splan, exact: bool, tile_lim: int,
+                         win_lim: int):
+        """One fused jitted program per (stream plan, exactness, refresh
+        limits): on-device tile change scoring, per-level window mapping,
+        the cached/incremental/full mode decision, and the commit.  Each
+        level with a window to evaluate runs the whole cascade through one
+        head call whose initial alive mask is that level's recompute map —
+        the changed windows, or every live window on a keyframe or full
+        refresh — so the frame's work follows what changed with no
+        compacted list, no capacity and no gather of SAT corners.  The
+        head is the masked fused kernel with its tile exit where the
+        kernels run (``use_pallas``, step 1), else the jnp dense sums.
+        Levels with nothing to evaluate are skipped (``lax.cond``).  The
+        state argument is donated: steady-state frames allocate nothing
+        new."""
         det = self.detector
         hp, wp, h, w = splan.hp, splan.wp, splan.h, splan.w
         tile, halo = splan.tile, splan.halo
-        plan = compile_plan(det.config, det.n_stages, hp, wp, batch=1,
-                            capacity=rung)
-        seg = plan.segments[0]
-        cap, backend = seg.capacity, seg.backend
+        plan = compile_plan(det.config, det.n_stages, hp, wp, batch=1)
+        n_stages = det.n_stages
+        step_px = plan.step
         n_slots = plan.n_slots
         cascade_static = det.cascade
+        fused = bool(det.config.use_pallas) and step_px == 1
+        head_tile = self.head_tile = tuple(plan.head_tile or DEFAULT_TILE)
+        hty, htx = head_tile
+        if fused:
+            from repro.kernels import ops as kops
         self.program_builds += 1
-        layout = plan.layout
-        lvl_of_slot = jnp.asarray(layout.lvl_of_slot)
-        y_of_slot = jnp.asarray(layout.y_of_slot)
-        x_of_slot = jnp.asarray(layout.x_of_slot)
-        sat_base_of_lvl = jnp.asarray(layout.sat_base_of_lvl)
-        sat_stride_of_lvl = jnp.asarray(layout.sat_stride_of_lvl)
         ranges = [tuple(jnp.asarray(a) for a in r)
                   for r in splan.level_tile_ranges]
         offs = [0]
         for lp in plan.levels:
             offs.append(offs[-1] + lp.n_windows)
-        valid_parts = [jnp.asarray(splan.limit_mask[offs[li]:offs[li + 1]])
-                       for li in range(len(plan.levels))]
+        valid_np = [splan.limit_mask[offs[li]:offs[li + 1]]
+                    for li in range(len(plan.levels))]
+        valid_parts = [jnp.asarray(v) for v in valid_np]
+        # levels with a live window: what a full refresh evaluates
+        full_levels = jnp.asarray([bool(v.any()) for v in valid_np])
+        level_tiles = [-(-lp.ny // hty) * -(-lp.nx // htx)
+                       for lp in plan.levels]
         decode_cap = splan.decode_cap
+
+        def level_head(cascade, frame, li, live):
+            """Survivors of level ``li`` among its ``live`` windows, and
+            the head tiles that ran each stage."""
+            lp = plan.levels[li]
+            ys_idx = downscale_indices(hp, lp.height)
+            xs_idx = downscale_indices(wp, lp.width)
+            img_l = frame[ys_idx[:, None], xs_idx[None, :]]
+            live = live.reshape(lp.ny, lp.nx)
+            if fused:
+                _ii, _inv, sums = kops.fused_head(
+                    cascade, cascade_static, 0, n_stages, img_l,
+                    tile=head_tile, live=live)
+                # a tile's origin reads -inf at each stage it skipped
+                tiles = (sums[:, ::hty, ::htx] > -jnp.inf).sum(
+                    axis=(1, 2)).astype(jnp.int32)
+            else:
+                ii, pair = integral_images(img_l)
+                inv = window_inv_sigma_grid(pair, lp.ny, lp.nx, step_px,
+                                            WINDOW)
+                sums = run_sums_grid(cascade, ii, inv, step_px, 0, n_stages)
+                tiles = jnp.full(n_stages, level_tiles[li], jnp.int32)
+            alive = live
+            for s in range(n_stages):
+                alive = alive & (sums[s] >= cascade.stage_threshold[s])
+            return alive.reshape(-1), tiles
 
         def step(cascade: Cascade, state: StreamState, frame: jax.Array,
                  threshold: jax.Array, kf_interval: jax.Array
@@ -356,106 +356,65 @@ class StreamEngine:
             n_tiles = changed.sum().astype(jnp.int32)
 
             def build_maps():
-                mask_parts = [changed_window_map_kernel(changed, ty0, ty1,
-                                                        tx0, tx1, valid)
-                              for (ty0, ty1, tx0, tx1), valid
-                              in zip(ranges, valid_parts)]
-                return (jnp.concatenate(mask_parts),
-                        jnp.stack([m.any() for m in mask_parts]))
+                return [changed_window_map_kernel(changed, ty0, ty1, tx0,
+                                                  tx1, valid)
+                        for (ty0, ty1, tx0, tx1), valid
+                        in zip(ranges, valid_parts)]
 
             def skip_maps():
-                # the tile count alone already forces a full refresh: the
-                # per-level maps would never be read (n_rec/levels_active
-                # report 0; host stats for full frames use constants)
-                return (jnp.zeros(offs[-1], bool),
-                        jnp.zeros(len(plan.levels), bool))
+                # nothing changed, or the tile count alone already forces
+                # a full refresh: the maps would be all-false or unread
+                return [jnp.zeros(v.shape, bool) for v in valid_parts]
 
-            mask_flat, lvl_any = jax.lax.cond(n_tiles <= tile_lim,
-                                              build_maps, skip_maps)
+            mask_parts = jax.lax.cond((n_tiles > 0) & (n_tiles <= tile_lim),
+                                      build_maps, skip_maps)
+            mask_flat = jnp.concatenate(mask_parts)
+            lvl_any = jnp.stack([m.any() for m in mask_parts])
             n_rec = mask_flat.sum().astype(jnp.int32)
             levels_active = lvl_any.astype(jnp.int32).sum()
 
-            due = (kf_interval > 0) & (state.frame_idx - state.last_full
-                                       >= kf_interval)
-            full_needed = (due | (n_tiles > tile_lim) | (n_rec > win_lim)
-                           | (n_rec > budget))
-            retry = (n_rec > cap) & ~full_needed
-            commit = ~full_needed & ~retry
-            mode = jnp.where(full_needed, 2,
+            due = (state.last_full < 0) | (
+                (kf_interval > 0)
+                & (state.frame_idx - state.last_full >= kf_interval))
+            full = due | (n_tiles > tile_lim) | (n_rec > win_lim)
+            mode = jnp.where(full, 2,
                              jnp.where(n_tiles > 0, 1, 0)).astype(jnp.int32)
+            run = jnp.where(full, full_levels, lvl_any)
 
-            def eval_tail() -> jax.Array:
-                sat_parts, pair_parts = [], []
-                for li, lp in enumerate(plan.levels):
-                    ys_idx = downscale_indices(hp, lp.height)
-                    xs_idx = downscale_indices(wp, lp.width)
+            surv_parts = []
+            head_tiles = jnp.zeros(n_stages, jnp.int32)
+            dense_tiles = jnp.zeros((), jnp.int32)
+            for li, lp in enumerate(plan.levels):
+                live = jnp.where(full, valid_parts[li], mask_parts[li])
 
-                    def build(ys_idx=ys_idx, xs_idx=xs_idx):
-                        img_l = frame[ys_idx[:, None], xs_idx[None, :]]
-                        ii_l, pair_l = integral_images(img_l)
-                        return ii_l.reshape(-1), pair_l.reshape(2, -1)
+                def skip(lp=lp):
+                    return (jnp.zeros(lp.n_windows, bool),
+                            jnp.zeros(n_stages, jnp.int32))
 
-                    def skip(lp=lp):
-                        return (jnp.zeros(lp.sat_size, jnp.float32),
-                                jnp.zeros((2, lp.sat_size), jnp.float32))
-
-                    # fully-cached levels build no SAT, like the host
-                    # subset programs — but resolved on device, per frame
-                    ii_l, pair_l = jax.lax.cond(lvl_any[li], build, skip)
-                    sat_parts.append(ii_l)
-                    pair_parts.append(pair_l)
-                ii_flat = jnp.concatenate(sat_parts)[None, :]
-                pair_flat = jnp.concatenate(pair_parts, axis=1)[None]
-                idx = jnp.nonzero(mask_flat, size=cap, fill_value=-1)[0]
-                sel = jnp.maximum(idx, 0)
-                valid = idx >= 0
-                b_sel = jnp.zeros_like(sel)
-                lvl_sel = jnp.take(lvl_of_slot, sel)
-                y_sel = jnp.take(y_of_slot, sel)
-                x_sel = jnp.take(x_of_slot, sel)
-                base_sel = jnp.take(sat_base_of_lvl, lvl_sel)
-                stride_sel = jnp.take(sat_stride_of_lvl, lvl_sel)
-                inv_sel = _packed_inv_sigma(pair_flat, b_sel, base_sel,
-                                            stride_sel, y_sel, x_sel)
-                ss_run = packed_tail.stage_sums(
-                    cascade, cascade_static, seg.s0, seg.s1, ii_flat,
-                    b_sel, base_sel, stride_sel, y_sel, x_sel, inv_sel,
-                    backend=backend, tile=plan.lane_block)
-                for j, s in enumerate(range(seg.s0, seg.s1)):
-                    valid = valid & (ss_run[j] >= cascade.stage_threshold[s])
-                target = jnp.where(valid, sel, n_slots)
-                return jnp.zeros(n_slots, bool).at[target].set(
-                    True, mode="drop")
-
-            def commit_step():
-                survivors = jax.lax.cond(
-                    n_rec > 0, eval_tail, lambda: jnp.zeros(n_slots, bool))
-                new_bitmap = (state.bitmap & ~mask_flat) | survivors
-                pix = jnp.repeat(jnp.repeat(changed, tile, axis=0),
-                                 tile, axis=1)[:h, :w]
-                pix = jnp.pad(pix, ((0, hp - h), (0, wp - w)))
-                new_ref = jnp.where(pix, frame, state.ref)
-                new_drift = jnp.where(changed, 0.0,
-                                      jnp.maximum(state.drift, scores))
-                slots = jnp.nonzero(new_bitmap, size=decode_cap,
-                                    fill_value=n_slots)[0].astype(jnp.int32)
-                n_surv = new_bitmap.sum().astype(jnp.int32)
-                return new_ref, new_bitmap, new_drift, slots, n_surv
-
-            def skip_step():
-                # full/retry verdict: nothing commits — the state passes
-                # through untouched and the decode outputs are never read
-                return (state.ref, state.bitmap, state.drift,
-                        jnp.full(decode_cap, n_slots, jnp.int32),
-                        jnp.zeros((), jnp.int32))
-
-            new_ref, new_bitmap, new_drift, slots, n_surv = jax.lax.cond(
-                commit, commit_step, skip_step)
-            new_fi = state.frame_idx + commit.astype(jnp.int32)
-            out = StreamStepOut(mode, n_tiles, n_rec, levels_active, retry,
-                                n_surv, slots)
-            return StreamState(new_ref, new_bitmap, new_drift, new_fi,
-                               state.last_full), out
+                surv, tiles = jax.lax.cond(
+                    run[li], lambda li=li, live=live: level_head(
+                        cascade, frame, li, live), skip)
+                surv_parts.append(surv)
+                head_tiles = head_tiles + tiles
+                dense_tiles = dense_tiles + jnp.where(run[li],
+                                                      level_tiles[li], 0)
+            survivors = jnp.concatenate(surv_parts)
+            new_bitmap = (state.bitmap & ~(mask_flat | full)) | survivors
+            pix = jnp.repeat(jnp.repeat(changed, tile, axis=0),
+                             tile, axis=1)[:h, :w]
+            pix = jnp.pad(pix, ((0, hp - h), (0, wp - w)))
+            new_ref = jnp.where(pix | full, frame, state.ref)
+            new_drift = jnp.where(changed | full, 0.0,
+                                  jnp.maximum(state.drift, scores))
+            slots = jnp.nonzero(new_bitmap, size=decode_cap,
+                                fill_value=n_slots)[0].astype(jnp.int32)
+            n_surv = new_bitmap.sum().astype(jnp.int32)
+            out = StreamStepOut(mode, n_tiles, n_rec, levels_active, n_surv,
+                                slots, head_tiles, dense_tiles)
+            new_state = StreamState(
+                new_ref, new_bitmap, new_drift, state.frame_idx + 1,
+                jnp.where(full, state.frame_idx, state.last_full))
+            return new_state, out
 
         return jax.jit(step, donate_argnums=(1,))
 

@@ -36,16 +36,16 @@ single-stream case.
 
 from __future__ import annotations
 
+import itertools
 from collections import deque
 from typing import NamedTuple
 
 import numpy as np
 import jax
-import jax.numpy as jnp
 
+from repro import obs
 from repro.core.engine import Detector
 from repro.core import nms
-from repro.plan import stream_capacity_rung
 from .engine import StreamEngine, StreamGeometry
 from .tiles import (tile_grid_shape, tile_change_scores, dilate_tiles,
                     changed_window_mask)
@@ -54,6 +54,7 @@ __all__ = ["StreamConfig", "FrameStats", "FramePlan", "VideoDetector",
            "level_windows_from_raw"]
 
 _MODES = ("cached", "incremental", "full")
+_SIDS = itertools.count()
 
 
 def level_windows_from_raw(levels, index: int | None = None
@@ -102,11 +103,14 @@ class StreamConfig(NamedTuple):
     # ---- device-resident state.  True moves the reference frame, survivor
     # bitmap and frame counters onto the device as a donated pytree: per
     # frame, change scoring, window mapping, the cached/incremental/full
-    # decision AND the incremental tail all run in one jitted step — the
+    # decision and the evaluation of every window to recompute — changed
+    # windows, or the whole frame on a keyframe or full refresh — run in
+    # one jitted step, one program per stream shape and exactness.  The
     # host uploads the new frame and fetches a handful of scalars plus the
     # survivor slot list.  Frames go through submit/retire (process
     # composes them); at threshold<=0 the output stays bit-identical to
     # the host-planned path and to per-frame Detector.detect.
+    # max_changed_frac does not apply here: the step has no capacity.
     device_state: bool = False
 
     def degraded(self, level: int) -> "StreamConfig":
@@ -168,21 +172,16 @@ class FramePlan(NamedTuple):
 class _DevToken:
     """One in-flight frame of a device-resident stream.
 
-    Created by :meth:`VideoDetector.submit`, resolved by ``poll`` and
-    finished by ``commit_token``/``discard_token`` (``retire`` composes
-    them).  ``out`` holds the step's device arrays while the frame is in
-    flight — fetching them is the only host sync of a steady-state frame.
+    Created (and its step dispatched) by :meth:`VideoDetector.submit`,
+    resolved by ``poll`` and finished by ``commit_token`` (``retire``
+    composes them).  ``out`` holds the step's device arrays while the
+    frame is in flight — fetching them is the only host sync of a frame.
     """
-    __slots__ = ("frame", "dev_frame", "out", "out_state", "version",
-                 "dispatched", "flags")
+    __slots__ = ("frame", "out", "flags")
 
     def __init__(self, frame: np.ndarray):
         self.frame = frame          # (h, w) f32 host pixels (for fallbacks)
-        self.dev_frame = None       # (hp, wp) device copy, set on dispatch
         self.out = None             # StreamStepOut device arrays
-        self.out_state = None       # the dispatch's output StreamState
-        self.version = -1           # state version the dispatch consumed
-        self.dispatched = False
         self.flags = None           # fetched scalar tuple, set by poll
 
 
@@ -212,12 +211,10 @@ class VideoDetector:
         self._decode_cap = decode_cap     # override for the slot-list size
         self._splan = None                # StreamStatePlan, built at open
         self._dev_state = None            # donated StreamState pytree
-        self._dev_rung = 0                # sticky packed-tail capacity rung
         self._pending: deque[_DevToken] = deque()   # in-flight frames, FIFO
-        self._state_version = 0           # bumped on re-upload/retry commits
-        self._prov = False                # device bitmap is provisional
         self._last_mode = "full"          # last committed frame's mode
         self.xfer_bytes = 0               # host<->device traffic accounting
+        self.sid = next(_SIDS)            # this stream's id in its spans
 
     # ------------------------------------------------------------ plumbing
     @property
@@ -251,7 +248,6 @@ class VideoDetector:
             self._splan = self.engine.stream_plan(
                 hp, wp, h, w, self.config.tile, self.config.halo,
                 decode_cap=self._decode_cap)
-            self._dev_rung = stream_capacity_rung(self._splan.n_slots, 1, 0)
 
     def _check_frame(self, frame) -> np.ndarray:
         # repro: ignore[HOST_SYNC] frame intake: callers hand in host pixels
@@ -316,14 +312,16 @@ class VideoDetector:
         returned array is marked read-only so cached frames can hand the
         same object back without a per-frame copy."""
         geo = self._geo
-        if len(idxs) == 0:
-            rects = np.zeros((0, 4), np.int32)
-        else:
-            rects = Detector._decode_rects(
-                geo.y_of_slot[idxs], geo.x_of_slot[idxs],
-                self._scales[geo.lvl_of_slot[idxs]])
-        rects = nms.group_rectangles(rects,
-                                     self.detector.config.min_neighbors)
+        with obs.span("stream.decode"):
+            if len(idxs) == 0:
+                rects = np.zeros((0, 4), np.int32)
+            else:
+                rects = Detector._decode_rects(
+                    geo.y_of_slot[idxs], geo.x_of_slot[idxs],
+                    self._scales[geo.lvl_of_slot[idxs]])
+            with obs.span("nms.group", n=len(rects)):
+                rects = nms.group_rectangles(
+                    rects, self.detector.config.min_neighbors)
         rects.setflags(write=False)
         return rects
 
@@ -343,45 +341,12 @@ class VideoDetector:
         # array, copy-free — callers must not mutate it
         return self._rects, stats
 
-    def commit_full(self, frame: np.ndarray,
-                    level_windows: list[tuple[np.ndarray, np.ndarray]] | None
-                    = None, *, dev_frame=None
-                    ) -> tuple[np.ndarray, FrameStats]:
-        """Full re-detect: refresh every cached decision from ``frame``.
-
-        ``level_windows`` (surviving (ys, xs) per pyramid level, as produced
-        by the detector's raw paths) lets the serving layer batch many
-        streams' keyframes through ``detect_batch_raw`` and feed each
-        session its slice; when omitted the detector runs directly.
-        ``dev_frame`` is the frame's already-device-resident padded copy
-        (a retired token's step input): with it, the state re-seed skips
-        re-uploading the reference pixels.
-        """
+    def _slots_of(self, level_windows: list[tuple[np.ndarray, np.ndarray]]
+                  ) -> np.ndarray:
+        """Flat survivor slots of a raw detector pass's (ys, xs) per
+        level, ascending."""
         geo = self._geo
-        prov = (self._splan is not None and dev_frame is not None
-                and level_windows is None and self._dev_state is not None
-                and bool(self._pending))
-        if prov:
-            # pipelined stream with a queued successor: re-seed only the
-            # verdict-bearing state (reference pixels + counters, both
-            # final before the detect) and dispatch the successor NOW, so
-            # its step overlaps the whole host-side refresh below.  Its
-            # bitmap input is stale — poll trues it up from the host
-            # mirrors if (and only if) the successor's verdict commits.
-            fi = self._frame_idx            # _finish increments it below
-            self._dev_state = self.engine.provisional_refresh(self._splan)(
-                self._dev_state, dev_frame, np.int32(fi + 1), np.int32(fi))
-            self.xfer_bytes += 8
-            self._state_version += 1
-            self._prov = True
-            self._dispatch_token(self._pending[0])
-        if level_windows is None:
-            level_windows = level_windows_from_raw(
-                self.detector.detect_raw(frame))
-        # full-detect traffic: frame up, surviving window coords back down
-        self.xfer_bytes += frame.nbytes + sum(
-            ys.nbytes + xs.nbytes for ys, xs in level_windows)
-        bitmap = np.zeros(geo.n_slots, bool)
+        parts = []
         for li, (ys, xs) in enumerate(level_windows):
             if len(ys) == 0:
                 continue
@@ -389,44 +354,36 @@ class VideoDetector:
             # keyframe decode: the raw survivor coords are this path's input
             ys = np.asarray(ys)  # repro: ignore[HOST_SYNC] keyframe decode input
             xs = np.asarray(xs)  # repro: ignore[HOST_SYNC] keyframe decode input
-            slots = (geo.slot_offsets[li] + (ys // geo.step) * nx
-                     + xs // geo.step)
-            bitmap[slots] = True
+            parts.append(geo.slot_offsets[li] + (ys // geo.step) * nx
+                         + xs // geo.step)
+        return (np.sort(np.concatenate(parts)) if parts
+                else np.zeros(0, np.int64))
+
+    def commit_full(self, frame: np.ndarray,
+                    level_windows: list[tuple[np.ndarray, np.ndarray]] | None
+                    = None) -> tuple[np.ndarray, FrameStats]:
+        """Full re-detect of a host-planned stream: refresh every cached
+        decision from ``frame``.
+
+        ``level_windows`` (surviving (ys, xs) per pyramid level, as produced
+        by the detector's raw paths) lets the serving layer batch many
+        streams' keyframes through ``detect_batch_raw`` and feed each
+        session its slice; when omitted the detector runs directly.
+        """
+        geo = self._geo
+        if level_windows is None:
+            level_windows = level_windows_from_raw(
+                self.detector.detect_raw(frame))
+        # full-detect traffic: frame up, surviving window coords back down
+        self.xfer_bytes += frame.nbytes + sum(
+            ys.nbytes + xs.nbytes for ys, xs in level_windows)
+        bitmap = np.zeros(geo.n_slots, bool)
+        bitmap[self._slots_of(level_windows)] = True
         self._bitmap = bitmap
         self._ref = frame.copy()
         self._last_full = self._frame_idx
-        out = self._finish(frame, "full", self._tiles_total, self._n_live,
-                           len(geo.plan))
-        if self._splan is not None and not prov:
-            self._upload_state(frame, dev_frame)
-        return out
-
-    def _upload_state(self, frame: np.ndarray, dev_frame=None) -> None:
-        """Re-seed the donated device state from the host mirrors after a
-        full refresh, then drop the mirrors — between full frames the
-        reference pixels and survivor bitmap live only on device.  When
-        the frame is already on device (``dev_frame``, a retired token's
-        step input) the stale state and that buffer are donated into the
-        new one and only the survivor bitmap + counters cross the bus."""
-        splan = self._splan
-        if dev_frame is not None and self._dev_state is not None:
-            self._dev_state = self.engine.refresh_state(splan)(
-                self._dev_state, dev_frame, jnp.asarray(self._bitmap),
-                np.int32(self._frame_idx), np.int32(self._last_full))
-            self.xfer_bytes += self._bitmap.nbytes + 8
-        else:
-            self._dev_state = self.engine.init_state(
-                splan, frame, self._bitmap, self._frame_idx,
-                self._last_full)
-            self.xfer_bytes += (splan.hp * splan.wp * 4
-                                + self._bitmap.nbytes
-                                + splan.ty * splan.tx * 4 + 8)
-        self._ref = None
-        self._bitmap = None
-        self._prov = False
-        # in-flight successors were planned against the pre-refresh state;
-        # versioning makes poll re-dispatch them against this one
-        self._state_version += 1
+        return self._finish(frame, "full", self._tiles_total, self._n_live,
+                            len(geo.plan))
 
     def commit_incremental(self, frame: np.ndarray, plan: FramePlan,
                            survivors_flat: np.ndarray
@@ -450,179 +407,126 @@ class VideoDetector:
 
     # ------------------------------------------- device-resident fast path
     def submit(self, frame) -> _DevToken:
-        """Queue ``frame`` on the device-resident stream and return its
-        token.  When the stream is steady (state exists, last frame wasn't
-        a full refresh) the plan-and-eval step is dispatched *immediately*
-        — jax dispatch is async, so frame N+1's change scoring and SAT
-        pass overlap the host-side decode of frame N (double-buffering).
-        Tokens must be retired in submit order."""
+        """Queue ``frame`` on the device-resident stream, dispatch its
+        plan-and-eval step and return its token.  jax dispatch is async
+        and every step commits (the donated state chains from one frame's
+        step to the next), so frame N+1's step runs while the host decodes
+        frame N.  Tokens must be retired in submit order."""
         if not self.config.device_state:
             raise RuntimeError(
                 "submit/retire need StreamConfig.device_state=True; use "
                 "process/plan_frame on host-planned streams")
         frame = self._check_frame(frame)
         tok = _DevToken(frame)
-        self._pending.append(tok)
-        # dispatch immediately when this token is next in line (jax
-        # dispatch is async, so its step runs while the host does other
-        # work); queued-behind tokens are dispatched by retire/poll the
-        # moment their predecessor's state is confirmed
-        if (self._splan is not None and self._dev_state is not None
-                and len(self._pending) == 1):
+        if self._splan is not None:
+            if self._dev_state is None:   # opening frame, or after reset
+                self._dev_state = self.engine.init_state(
+                    self._splan, self._frame_idx + len(self._pending))
             self._dispatch_token(tok)
+        self._pending.append(tok)
         return tok
 
     def _dispatch_token(self, tok: _DevToken) -> None:
-        """Run the device step for ``tok``'s frame, donating the confirmed
-        chain head and advancing it.  Only called when every predecessor
-        of ``tok`` is resolved (queue head, or dispatched by retire/poll
-        right after the predecessor's state was confirmed), so the head is
-        always the correct input; if the stream later retries or
-        full-refreshes under this token's feet, the version check in
-        ``poll`` re-dispatches it against the corrected state."""
+        """Run the device step for ``tok``'s frame, donating the state."""
         cfg = self.config
         splan = self._splan
-        padded = np.zeros((splan.hp, splan.wp), np.float32)
-        padded[:splan.h, :splan.w] = tok.frame
-        fn = self.engine.stream_step(splan, self._dev_rung,
-                                     cfg.threshold <= 0,
-                                     cfg.full_refresh_frac)
-        tok.dev_frame = jnp.asarray(padded)
-        new_state, tok.out = fn(
-            self.detector.cascade, self._dev_state, tok.dev_frame,
-            np.float32(cfg.threshold), np.int32(cfg.keyframe_interval))
-        tok.out_state = new_state
-        self._dev_state = new_state
-        tok.version = self._state_version
-        tok.dispatched = True
-        tok.flags = None
+        with obs.span("stream.step", sess=self.sid,
+                      frame=self._frame_idx + len(self._pending)):
+            padded = np.zeros((splan.hp, splan.wp), np.float32)
+            padded[:splan.h, :splan.w] = tok.frame
+            fn = self.engine.stream_step(splan, cfg.threshold <= 0,
+                                         cfg.full_refresh_frac)
+            self._dev_state, tok.out = fn(
+                self.detector.cascade, self._dev_state, padded,
+                np.float32(cfg.threshold), np.int32(cfg.keyframe_interval))
         self.xfer_bytes += padded.nbytes
 
-    def _fetch_flags(self, tok: _DevToken) -> tuple:
-        out = tok.out
-        # repro: ignore[HOST_SYNC] contract sync: the step's scalar verdict is what poll exists to fetch
-        tok.flags = jax.device_get((out.mode, out.tiles_changed, out.n_rec,
-                                    out.levels_active, out.retry,
-                                    out.n_surv))
-        self.xfer_bytes += 6 * 4
-        return tok.flags
-
     def poll(self, tok: _DevToken) -> str:
-        """Resolve ``tok``'s frame mode: ``'cached'`` / ``'incremental'``
-        (finish via :meth:`commit_token`) or ``'full'`` (the device did
-        not commit; take ``discard_token`` and run :meth:`commit_full`).
-        Blocks on the device step; re-dispatches stale or deferred
-        tokens, and transparently regrows the packed capacity rung when
-        the step reports overflow (``retry``)."""
+        """Resolve ``tok``'s frame mode — ``'cached'``, ``'incremental'``
+        or ``'full'`` (a keyframe or full refresh, committed on the device
+        like the others) — and finish it with :meth:`commit_token`.
+        Waits for the device step, then copies its verdict scalars and
+        head-work counters."""
         if not self._pending or tok is not self._pending[0]:
             raise RuntimeError("device tokens must be polled/retired in "
                                "submit order")
-        if self._dev_state is None:
-            # stream-opening keyframe, post-reset, or a degenerate stream
-            # with no windows (n_slots == 0): host semantics apply
-            return "cached" if self._splan is None \
-                and self._ref is not None else "full"
-        if not tok.dispatched or tok.version != self._state_version:
-            self._dispatch_token(tok)
-        flags = self._fetch_flags(tok)
-        retried = False
-        while True:
-            if bool(flags[4]):   # rung overflow: nothing was committed
-                self._dev_rung = stream_capacity_rung(
-                    self._splan.n_slots, 1, int(flags[2]))
-                retried = True
-                self._dispatch_token(tok)
-                flags = self._fetch_flags(tok)
-                continue
-            if self._prov and _MODES[int(flags[0])] != "full":
-                # the bitmap the provisional dispatch carried mattered
-                # after all (the verdict commits): true the device state
-                # up from the host mirrors and re-run the step
-                self._upload_state(self._ref)
-                self._dispatch_token(tok)
-                flags = self._fetch_flags(tok)
-                continue
-            break
-        # accept: the token's output becomes the confirmed chain head
-        self._dev_state = tok.out_state
-        mode = _MODES[int(flags[0])]
-        if retried and mode != "full":
-            # the retry committed against state an already-dispatched
-            # successor didn't see; version it so poll re-dispatches them
-            self._state_version += 1
-            tok.version = self._state_version
-        return mode
+        if self._splan is None:
+            # frame smaller than the detection window: nothing to run
+            tok.flags = (2 if self._rects is None else 0,) + (0,) * 4
+            return _MODES[tok.flags[0]]
+        out = tok.out
+        jax.block_until_ready(out.n_surv)
+        with obs.span("stream.poll") as attrs:
+            # repro: ignore[HOST_SYNC] contract sync: the step's scalar verdict is what poll exists to fetch
+            flags = jax.device_get((out.mode, out.tiles_changed, out.n_rec,
+                                    out.levels_active, out.n_surv,
+                                    out.head_tiles, out.dense_tiles))
+            tok.flags = tuple(int(v) for v in flags[:5])
+            attrs.update(zip(("mode", "n_tiles", "n_rec", "levels_active"),
+                             tok.flags[:4]))
+            attrs.update(self._head_counts(flags[5], int(flags[6])))
+        self.xfer_bytes += (7 + self.detector.n_stages) * 4
+        return _MODES[tok.flags[0]]
+
+    def _head_counts(self, head_tiles, dense_tiles: int) -> dict:
+        """The head's work of one step in the units of the photo path's
+        ``engine.fetch`` counters: weak-classifier evaluations of head
+        tiles (``head_work``) and those of a head without exit on the
+        levels the step evaluated (``head_dense``); ``head_windows`` per
+        stage counts the windows of the tiles that ran it."""
+        n_weak = self.engine.n_weak
+        area = int(np.prod(self.engine.head_tile))
+        # repro: ignore[HOST_SYNC] counters already fetched with the verdict
+        head_tiles = np.asarray(head_tiles, np.int64)
+        return {"head_work": int((head_tiles * n_weak).sum()),
+                "head_dense": int(dense_tiles * n_weak.sum()),
+                "head_windows": tuple(int(t) * area for t in head_tiles)}
 
     def commit_token(self, tok: _DevToken) -> tuple[np.ndarray, FrameStats]:
-        """Finish a polled ``'cached'``/``'incremental'`` token: fetch the
-        decoded survivor slots (incremental only), group rects, and mirror
-        the host path's engine counters."""
-        if self._splan is None:        # degenerate stream: host cached path
-            self._pending.popleft()
-            return self._finish(tok.frame, "cached", 0, 0, 0)
-        n_tiles, n_rec, lvls, n_surv = (int(tok.flags[i]) for i in
-                                        (1, 2, 3, 5))
-        mode = _MODES[int(tok.flags[0])]
+        """Finish a polled token: fetch the decoded survivor slots (frames
+        the step evaluated), group rects, and mirror the host path's
+        stats and engine counters."""
+        mode = _MODES[tok.flags[0]]
+        n_tiles, n_rec, lvls, n_surv = tok.flags[1:5]
         self._pending.popleft()
-        if mode == "incremental":
-            if n_surv > self._splan.decode_cap:
-                # survivor count overflows the static slot list (decode
-                # only — the committed device bitmap is fine).  Recover
-                # deterministically via a host full refresh: identical
-                # rects at threshold 0, counted as a full frame.
-                return self.commit_full(tok.frame, dev_frame=tok.dev_frame)
-            self.engine.dispatches += 1
-            self.engine.sat_level_builds += lvls
-            self.engine.sat_level_total += len(self._geo.plan)
-            # repro: ignore[HOST_SYNC] contract sync: decoded survivor slots are the frame's output
-            slots = np.asarray(jax.device_get(tok.out.slots))[:n_surv]
+        n_levels = len(self._geo.plan)
+        if mode == "full":
+            n_tiles, n_rec, lvls = self._tiles_total, self._n_live, n_levels
+        if self._splan is None:
+            if self._rects is None:
+                self._rects = self._decode_slots(np.zeros(0, np.int64))
+        elif n_surv > self._splan.decode_cap:
+            # survivor count overflows the static slot list (decode only —
+            # the committed device bitmap is fine): the frame's rects come
+            # from a host detect, identical at threshold 0, and it counts
+            # as a full frame
+            slots = self._slots_of(level_windows_from_raw(
+                self.detector.detect_raw(tok.frame)))
+            self.xfer_bytes += tok.frame.nbytes
+            self._rects = self._decode_slots(slots)
+            mode = "full"
+            n_tiles, n_rec, lvls = self._tiles_total, self._n_live, n_levels
+        elif mode != "cached":
+            if mode == "incremental":
+                self.engine.dispatches += 1
+                self.engine.sat_level_builds += lvls
+                self.engine.sat_level_total += n_levels
+            with obs.span("stream.fetch") as attrs:
+                # repro: ignore[HOST_SYNC] contract sync: decoded survivor slots are the frame's output
+                slots = np.asarray(jax.device_get(tok.out.slots))[:n_surv]
+                attrs["bytes"] = self._splan.decode_cap * 4
             self.xfer_bytes += self._splan.decode_cap * 4
             self._rects = self._decode_slots(slots)
         stats = FrameStats(self._frame_idx, mode, self._tiles_total,
-                           n_tiles, self._n_live, n_rec,
-                           len(self._geo.plan), lvls)
+                           n_tiles, self._n_live, n_rec, n_levels, lvls)
         self._frame_idx += 1
         self._last_mode = mode
         return self._rects, stats
 
-    def discard_token(self, tok: _DevToken) -> np.ndarray:
-        """Pop a polled ``'full'`` token and hand back its frame; the
-        caller finishes it through :meth:`commit_full` (possibly batched
-        with other streams' keyframes by the serving layer)."""
-        if not self._pending or tok is not self._pending[0]:
-            raise RuntimeError("device tokens must be polled/retired in "
-                               "submit order")
-        self._pending.popleft()
-        return tok.frame
-
     def retire(self, tok: _DevToken) -> tuple[np.ndarray, FrameStats]:
-        """Block on ``tok`` and finish its frame (single-stream path)."""
-        mode = self.poll(tok)
-        # double-buffer: poll just confirmed the chain head, so a queued
-        # successor can dispatch *now* and run its device step while this
-        # frame's host-side decode/NMS (or full re-detect) happens below.
-        # Skip when this frame goes full — its commit replaces the state
-        # and the dispatch would be thrown away.
-        if mode != "full" and len(self._pending) > 1 \
-                and self._splan is not None:
-            nxt = self._pending[1]
-            if not nxt.dispatched or nxt.version != self._state_version:
-                self._dispatch_token(nxt)
-        if mode == "full":
-            out = self.commit_full(self.discard_token(tok),
-                                   dev_frame=tok.dev_frame)
-        else:
-            out = self.commit_token(tok)
-        # a successor deferred by a full-refresh streak (or invalidated by
-        # a decode-overflow fallback) chains off the state the commit just
-        # re-uploaded; dispatching it here still overlaps the caller's
-        # next host phase
-        if self._pending and self._splan is not None \
-                and self._dev_state is not None:
-            head = self._pending[0]
-            if not head.dispatched or head.version != self._state_version:
-                self._dispatch_token(head)
-        return out
+        """Block on ``tok`` and finish its frame."""
+        self.poll(tok)
+        return self.commit_token(tok)
 
     def reconfigure(self, config: StreamConfig) -> None:
         """Swap the stream's threshold/keyframe policy mid-stream without
@@ -681,6 +585,4 @@ class VideoDetector:
         self._last_full = -1
         self._dev_state = None
         self._pending.clear()
-        self._state_version += 1
-        self._prov = False
         self._last_mode = "full"

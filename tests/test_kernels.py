@@ -394,3 +394,88 @@ def test_fused_head_tile_shape_does_not_change_bits(tile):
         np.asarray(ref.tile_exit_ref(dense, TH, tile)))
     np.testing.assert_array_equal(_alive_chain(np.asarray(other[2]), TH),
                                   _alive_chain(np.asarray(base[2]), TH))
+
+
+@pytest.mark.parametrize("mask", ["none", "all", "random"])
+def test_masked_fused_head_vs_oracle_twin(mask):
+    """The masked fused head starts each tile from its live windows: an
+    all-false map runs no stage anywhere, an all-true map is the unmasked
+    call bit for bit, and a random map matches the oracle twin and the
+    split path's sums under the same tile exit."""
+    h, w = 40, 160                      # two (8, 128) tile columns
+    rng = np.random.default_rng(59)
+    img = jnp.asarray(rng.integers(0, 255, (h, w)).astype(np.float32))
+    ny, nx = h - 24 + 1, w - 24 + 1
+    live = {"none": np.zeros((ny, nx), bool),
+            "all": np.ones((ny, nx), bool),
+            "random": rng.random((ny, nx)) < 0.002}[mask]
+
+    def masked(c, im, lv):
+        return ops.fused_head(c, CASC, 0, N_RUN, im, live=lv)
+
+    got = jax.jit(masked)(CASC, img, jnp.asarray(live))
+    plain = jax.jit(lambda c, im: ops.fused_head(c, CASC, 0, N_RUN, im))(
+        CASC, img)
+    want = ops.fused_head_ref(CASC, CASC, 0, N_RUN, img, live=live)
+    for g, p in zip(got[:2], plain[:2]):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(p))
+    np.testing.assert_array_equal(np.isneginf(got[2]), np.isneginf(want[2]))
+    np.testing.assert_allclose(np.asarray(got[2]), np.asarray(want[2]),
+                               rtol=1e-4, atol=1e-3)
+    split = jax.jit(_split_sums)(CASC, img)[2]
+    np.testing.assert_array_equal(
+        np.asarray(got[2]),
+        np.asarray(ref.tile_exit_ref(split, TH, live=jnp.asarray(live))))
+    if mask == "none":
+        assert np.isneginf(np.asarray(got[2])).all()
+    if mask == "all":
+        np.testing.assert_array_equal(np.asarray(got[2]),
+                                      np.asarray(plain[2]))
+    if mask == "random":                # some tiles ran, some did not
+        ran = ~np.isneginf(np.asarray(got[2][0, ::8, ::128]))
+        assert ran.any() and not ran.all()
+
+
+def test_int32_tables_are_exact_where_float32_rounds():
+    """Past 2^24 a float32 table rounds; the int32 tables are exact at any
+    frame size: on a 512 x 512 all-255 frame the bottom-right window sums
+    576 * 255 and every window's 1/sigma is 1 (a flat window), and on a
+    random frame a window's 1/sigma and stage sums (``weak_vote``) are
+    those of the same pixels cropped out — window-locality — where the
+    float32 table's rectangle sums are not."""
+    from repro.core.integral import rect_sum, window_inv_sigma_grid
+
+    flat = jnp.full((512, 512), 255.0, jnp.float32)
+    ii, pair = integral_images(flat)
+    assert ii.dtype == jnp.int32 and pair.dtype == jnp.int32
+    assert float(rect_sum(ii, 488, 488, 24, 24)) == 576 * 255
+    inv = window_inv_sigma_grid(pair, 489, 489, 1, 24)
+    assert (np.asarray(inv) == 1.0).all()
+
+    rng = np.random.default_rng(61)
+    big = rng.integers(0, 256, (512, 512)).astype(np.float32)
+    crop = big[-40:, -56:]
+    f32 = np.pad(np.cumsum(np.cumsum(big, 0, dtype=np.float32), 1,
+                           dtype=np.float32), ((1, 0), (1, 0)))
+    exact = np.pad(np.cumsum(np.cumsum(big.astype(np.int64), 0), 1),
+                   ((1, 0), (1, 0)))
+    ys, xs = np.mgrid[489 - 17:489, 489 - 33:489]
+    f32_sums = (f32[ys + 24, xs + 24] - f32[ys, xs + 24]
+                - f32[ys + 24, xs] + f32[ys, xs])
+    exact_sums = (exact[ys + 24, xs + 24] - exact[ys, xs + 24]
+                  - exact[ys + 24, xs] + exact[ys, xs])
+    assert (f32_sums != exact_sums).any()     # the float32 table rounds
+    ii_b, pair_b = integral_images(jnp.asarray(big))
+    np.testing.assert_array_equal(
+        np.asarray(rect_sum(ii_b, jnp.asarray(ys), jnp.asarray(xs), 24,
+                            24)), exact_sums.astype(np.float32))
+    ii_c, pair_c = integral_images(jnp.asarray(crop))
+    inv_b = window_inv_sigma_grid(pair_b, 489, 489, 1, 24)[-17:, -33:]
+    inv_c = window_inv_sigma_grid(pair_c, 17, 33, 1, 24)
+    np.testing.assert_array_equal(np.asarray(inv_b), np.asarray(inv_c))
+    # weak_vote through the split kernel on the bottom-right windows of
+    # the whole frame's table, against the crop's own table
+    pad_b = ii_b[-41:, -57:]
+    sums_b = ops.dense_stage_sums(CASC, CASC, 0, pad_b, inv_b)
+    sums_c = ops.dense_stage_sums(CASC, CASC, 0, ii_c, inv_c)
+    np.testing.assert_array_equal(np.asarray(sums_b), np.asarray(sums_c))

@@ -219,3 +219,44 @@ def test_pack_and_fetch_count_the_bytes_they_move(flushed):
     assert [s.attrs["bytes"] for s in fetches] == [want, want]
     for s in fetches:
         assert 0 < s.attrs["head_work"] <= s.attrs["head_dense"]
+
+
+def test_a_device_stream_records_step_poll_fetch_and_decode():
+    """Each frame of a device-resident stream dispatches under
+    ``stream.step`` (its session and frame), copies its verdict and the
+    head's counters under ``stream.poll``, and, when the step evaluated
+    windows, copies the survivor slots (``stream.fetch``, with their
+    bytes) and decodes and groups them (``stream.decode`` around
+    ``nms.group``)."""
+    from repro.stream import StreamConfig, VideoDetector
+
+    det = Detector(paper_shaped_cascade(0, stage_sizes=[3, 4, 5]),
+                   EngineConfig(step=2, scale_factor=1.3, min_neighbors=2))
+    vd = VideoDetector(det, StreamConfig(tile=16, keyframe_interval=0,
+                                         full_refresh_frac=0.9,
+                                         device_state=True))
+    rng = np.random.default_rng(5)
+    scene = rng.integers(0, 256, (64, 96)).astype(np.float32)
+    moved = scene.copy()
+    moved[2:6, 2:6] = 0.0
+    before = obs.spans()
+    modes = [vd.process(f)[1].mode for f in (scene, scene, moved)]
+    assert modes == ["full", "cached", "incremental"]
+    got = new_spans(before)
+    steps = [s for s in got if s.name == "stream.step"]
+    assert [s.attrs["frame"] for s in steps] == [0, 1, 2]
+    assert {s.attrs["sess"] for s in steps} == {vd.sid}
+    polls = [s for s in got if s.name == "stream.poll"]
+    assert [s.attrs["mode"] for s in polls] == [2, 0, 1]
+    n_weak = np.diff(np.asarray(det.cascade.stage_offsets))
+    for p in polls:
+        assert len(p.attrs["head_windows"]) == len(n_weak)
+        assert 0 <= p.attrs["head_work"] <= p.attrs["head_dense"]
+    assert polls[1].attrs["head_dense"] == 0          # nothing ran
+    assert polls[0].attrs["head_dense"] >= polls[2].attrs["head_dense"] > 0
+    fetches = [s for s in got if s.name == "stream.fetch"]
+    assert len(fetches) == 2                  # the full and the changed frame
+    assert all(s.attrs["bytes"] == vd._splan.decode_cap * 4 for s in fetches)
+    decodes = {s.id for s in got if s.name == "stream.decode"}
+    groups = [s for s in got if s.name == "nms.group"]
+    assert len(decodes) == 2 and all(g.parent in decodes for g in groups)

@@ -2,10 +2,12 @@
 their oracle twins and the host planners; `StreamConfig.device_state`
 streams are bit-identical to the host-planned path (and to per-frame
 ``detect``) at threshold 0 across every synthetic scenario, through the
-pipelined submit/retire API, the rung-retry loop, and the decode-overflow
-fallback; the donated state reuses its buffers with zero steady-state
-program builds; and serving sessions report identical stream stats
-either way."""
+pipelined submit/retire API, large changes, and the decode-overflow
+fallback; every frame, keyframes and full refreshes included, runs in one
+step program per stream, whose masked fused head (``use_pallas``, step 1)
+agrees with ``detect`` and the plain reference (``bench/reference.py``);
+the donated state reuses its buffers; and serving sessions report
+identical stream stats either way."""
 
 import numpy as np
 import pytest
@@ -98,9 +100,9 @@ def test_device_stream_bit_identical_to_host_and_detect(detector, kind):
 
 @pytest.mark.parametrize("kind", SCENARIOS)
 def test_pipelined_submit_retire_matches_sequential(detector, kind):
-    # all-full streaks exercise the provisional ahead-dispatch (bitmap
-    # stale, verdict sound); mixed scenarios exercise its true-up when a
-    # successor's verdict commits after a full refresh
+    # a successor's step is dispatched before its predecessor retires,
+    # across keyframes and full refreshes too: the donated state chains
+    # from step to step, so the answers are the sequential ones
     frames = frames_of(kind, n=12, seed=5)
     seq = VideoDetector(detector, DEV_CFG)
     pipe = VideoDetector(detector, DEV_CFG)
@@ -116,24 +118,26 @@ def test_pipelined_submit_retire_matches_sequential(detector, kind):
         assert np.array_equal(rw, rg) and sw == sg
 
 
-def test_retry_grows_rung_and_stays_identical(detector):
-    # static opening (tiny sticky rung) then a pan burst: the first burst
-    # frame overflows the compiled rung, retries at a larger one, and
-    # still commits the exact host result
+def test_pan_burst_commits_in_the_one_step_program(detector):
+    # static opening then a pan burst that recomputes (nearly) every
+    # window: the step has no capacity to outgrow, so every frame commits
+    # the exact host result in the stream's one program
     cfg_h = HOST_CFG._replace(keyframe_interval=0, full_refresh_frac=0.95,
                               max_changed_frac=0.95)
     cfg_d = cfg_h._replace(device_state=True)
     frames = (frames_of("static_cctv", n=3, seed=7)
               + frames_of("camera_pan", n=3, seed=7))
-    vh, vd = VideoDetector(detector, cfg_h), VideoDetector(detector, cfg_d)
-    rung0 = None
+    eng = StreamEngine(detector, cfg_d.max_changed_frac)
+    vh = VideoDetector(detector, cfg_h)
+    vd = VideoDetector(detector, cfg_d, engine=eng)
+    modes = []
     for f in frames:
         rh, sh = vh.process(f)
         rd, sd = vd.process(f)
-        if rung0 is None:
-            rung0 = vd._dev_rung
+        modes.append(sd.mode)
         assert np.array_equal(rh, rd) and sh == sd
-    assert vd._dev_rung > rung0          # the sticky rung actually grew
+        assert eng.program_builds == 1
+    assert modes[0] == "full" and modes[1] != "full"
 
 
 def test_decode_overflow_falls_back_to_full(detector):
@@ -166,9 +170,9 @@ def test_donated_state_reuses_buffers_and_programs(detector):
             ptrs.append(vd._dev_state.ref.unsafe_buffer_pointer())
         builds.append(eng.program_builds)
     assert modes[0] == "full" and set(modes[1:]) == {"incremental"}
-    # programs compiled by frame 2 (opening rung + one retry growth at
-    # most), then reused for every steady-state frame
-    assert builds[-1] == builds[2]
+    # one step program, built for the opening frame (a keyframe, run on
+    # the device like every other frame) and reused for every frame
+    assert builds == [1] * len(frames)
     # donation: the reference-frame buffer is recycled in place
     assert len(set(ptrs[2:])) == 1
     # steady state fetches scalars + slots, never the ref/bitmap arrays
@@ -193,6 +197,72 @@ def test_device_stream_api_guards(detector):
     r2, s2 = vd.process(frame)
     assert s2.mode == "full"
     assert np.array_equal(r2, detector.detect(frame))
+
+
+# ------------------------------------------------- masked fused head
+def parked_camera(n: int, h: int = 96, w: int = 128, seed: int = 13):
+    """A seeded parked camera: a still uint8 scene (with planted faces)
+    in which a dark 20 px square moves 12 px every other frame, then one
+    frame whose whole scene brightens (a full refresh by changed tiles)."""
+    from bench.scenes import render_scene
+
+    scene = render_scene(np.random.default_rng(seed), h, w, 2)
+    frames = []
+    for t in range(n - 1):
+        f = scene.copy()
+        x = 8 + 12 * (t // 2)
+        f[h - 24:h - 4, x:x + 20] = 30
+        frames.append(f)
+    frames.append(np.clip(frames[-1].astype(np.int32) + 9, 0, 255
+                          ).astype(np.uint8))
+    return frames
+
+
+def test_parked_camera_masked_head_matches_detect_and_reference(
+        monkeypatch):
+    """Still frames, a moving object, a keyframe and a forced full
+    refresh through the masked fused head: the survivors equal the plain
+    reference's and the rects ``detect``'s on every frame, and the whole
+    sequence runs one step program, which never reaches the packed tail."""
+    from bench import reference
+    from repro.kernels import packed_tail
+
+    def no_tail(*a, **k):
+        raise AssertionError("the device step must not use the packed tail")
+
+    monkeypatch.setattr(packed_tail, "stage_sums", no_tail)
+    det = Detector(CASC, EngineConfig(step=1, scale_factor=1.3,
+                                      min_neighbors=2, use_pallas=True,
+                                      pad_multiple=32))
+    cfg = StreamConfig(tile=16, threshold=0.0, keyframe_interval=5,
+                       full_refresh_frac=0.8, device_state=True)
+    eng = StreamEngine(det)
+    vd = VideoDetector(det, cfg, engine=eng)
+    arrays = {k: np.asarray(getattr(CASC, k)) for k in (
+        "rect_xywh", "rect_w", "wc_threshold", "left_val", "right_val",
+        "stage_offsets", "stage_threshold")}
+    rcfg = {"pad_multiple": 32, "scale_factor": 1.3}
+    modes, found = [], 0
+    for f in parked_camera(9):
+        tok = vd.submit(f.astype(np.float32))
+        vd.poll(tok)
+        n_surv = tok.flags[4]
+        slots = np.asarray(tok.out.slots)[:n_surv]
+        rects, stats = vd.commit_token(tok)
+        modes.append(stats.mode)
+        geo = vd._geo
+        got = np.stack([geo.lvl_of_slot[slots], geo.y_of_slot[slots],
+                        geo.x_of_slot[slots]], axis=1)
+        want = reference.evaluate(f, arrays, rcfg).survivors
+        assert np.array_equal(got, want)
+        found += len(want)
+        assert np.array_equal(rects, det.detect(f.astype(np.float32)))
+        assert eng.program_builds == 1
+    # the object moves on even frames; frame 5 is the keyframe, and every
+    # tile of the last frame changed
+    assert modes == ["full", "cached", "incremental", "cached",
+                     "incremental", "full", "incremental", "cached", "full"]
+    assert found > 0
 
 
 # --------------------------------------------------------------- serving

@@ -79,12 +79,27 @@ def test_fused_head_compiles_for_v5e(v5e, n_stages):
     assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
+def test_masked_fused_head_compiles_for_v5e(v5e):
+    """The stream step's head: the whole cascade at level 0, each tile
+    starting from its windows of the changed-window map (a (ty, tx) int32
+    block operand beside the 1/sigma grid)."""
+    n_stages = CASC.n_stages
+    rel = tuple(int(b) for b in OFF[:n_stages + 1])
+    args = _weak_specs(v5e, 0, rel[-1]) + [
+        _spec(v5e, (n_stages,), jnp.float32), _spec(v5e, (H, W), jnp.float32),
+        _spec(v5e, (NY, NX), jnp.bool_)]
+    compiled = _compile(
+        lambda a, b, c, d, e, th, img, live: fused_head_kernel(
+            a, b, c, d, e, th, rel, img, interpret=False, live=live), args)
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
 def test_haar_stage_compiles_for_v5e(v5e):
     s = DENSE_PREFIX
     ty, tx = DEFAULT_TILE
     ny_pad, nx_pad = NY + (-NY) % ty, NX + (-NX) % tx
     args = _weak_specs(v5e, int(OFF[s]), int(OFF[s + 1])) + [
-        _spec(v5e, sat_pad_shape(ny_pad, nx_pad), jnp.float32),
+        _spec(v5e, sat_pad_shape(ny_pad, nx_pad), jnp.int32),
         _spec(v5e, (ny_pad, nx_pad), jnp.float32)]
     _compile(lambda a, b, c, d, e, ii, inv: haar_stage_sums_kernel(
         a, b, c, d, e, ii, inv, interpret=False), args)
@@ -96,7 +111,7 @@ def test_packed_window_refused_for_tpu_with_named_error(v5e):
     rel = tuple(int(b) - k0 for b in OFF[s0:s1 + 1])
     rows = 2 * DEFAULT_TILE[0]
     args = _weak_specs(v5e, k0, int(OFF[s1])) + [
-        _spec(v5e, (1, (H + 1) * (W + 1)), jnp.float32)] + [
+        _spec(v5e, (1, (H + 1) * (W + 1)), jnp.int32)] + [
         _spec(v5e, (rows, DEFAULT_TILE[1]), jnp.int32)] * 4 + [
         _spec(v5e, (rows, DEFAULT_TILE[1]), jnp.float32)]
     # the TPU path raises the named refusal instead of running anything
