@@ -97,9 +97,16 @@ class StreamState(NamedTuple):
     frames reuse the same buffers — the only per-frame host->device
     transfer is the new frame, and the only device->host transfer is the
     :class:`StreamStepOut` scalars plus the decoded survivor slot list.
+
+    ``slots`` is ``bitmap``'s ascending survivor-slot list, carried so
+    that a step whose committed bitmap equals the one it was given (a
+    cached frame, or evaluated windows that kept their verdicts) reuses it
+    instead of compacting all ``n_slots`` again.
     """
     ref: jax.Array        # (hp, wp) f32 reference pixels, zero-padded
     bitmap: jax.Array     # (n_slots,) bool cached survivor decisions
+    slots: jax.Array      # (decode_cap,) i32 ascending survivor slots of
+    #                       bitmap (fill value n_slots past its count)
     drift: jax.Array      # (ty, tx) f32 peak change score of tiles whose
     #                       cached decisions were *not* refreshed (pure
     #                       diagnostic: scoring is always vs the reference
@@ -111,7 +118,10 @@ class StreamState(NamedTuple):
 
 class StreamStepOut(NamedTuple):
     """Per-frame result of the device plan-and-eval step (device arrays;
-    the host fetches the scalars, then the slot list)."""
+    the host fetches the scalars, then the slot list).  ``slots`` is the
+    committed bitmap's list on every frame; ``slots_built`` says whether
+    this step compacted it anew (1, the bitmap changed) or carried the
+    state's list (0)."""
     mode: jax.Array           # () i32: 0 cached, 1 incremental, 2 full
     tiles_changed: jax.Array  # () i32 changed tiles after halo dilation
     n_rec: jax.Array          # () i32 windows to recompute
@@ -119,6 +129,7 @@ class StreamStepOut(NamedTuple):
     n_surv: jax.Array         # () i32 survivors in the committed bitmap
     slots: jax.Array          # (decode_cap,) i32 ascending survivor slots
     #                           (fill value n_slots past n_surv)
+    slots_built: jax.Array    # () i32 1 if the step rebuilt slots, else 0
     head_tiles: jax.Array     # (n_stages,) i32 head tiles that ran each
     #                           stage, summed over the levels evaluated
     dense_tiles: jax.Array    # () i32 head tiles of the levels evaluated
@@ -254,11 +265,14 @@ class StreamEngine:
                                    tile, halo, decode_cap=decode_cap)
 
     def init_state(self, splan, frame_idx: int) -> StreamState:
-        """A fresh device state: no reference, no cached decision, and
-        ``last_full`` -1, so the stream's next step runs a full refresh
-        (the stream's opening keyframe) on the device."""
+        """A fresh device state: no reference, no cached decision (the
+        all-false bitmap, whose slot list is all fill), and ``last_full``
+        -1, so the stream's next step runs a full refresh (the stream's
+        opening keyframe) on the device."""
         return StreamState(jnp.zeros((splan.hp, splan.wp), jnp.float32),
                            jnp.zeros(splan.n_slots, bool),
+                           jnp.full(splan.decode_cap, splan.n_slots,
+                                    jnp.int32),
                            jnp.zeros((splan.ty, splan.tx), jnp.float32),
                            jnp.asarray(np.int32(frame_idx)),
                            jnp.asarray(np.int32(-1)))
@@ -290,8 +304,10 @@ class StreamEngine:
         head is the masked fused kernel with its tile exit where the
         kernels run (``use_pallas``, step 1), else the jnp dense sums.
         Levels with nothing to evaluate are skipped (``lax.cond``).  The
-        state argument is donated: steady-state frames allocate nothing
-        new."""
+        survivor-slot list (``jnp.nonzero`` over all slots) is rebuilt only
+        when the committed bitmap differs from the state's; otherwise the
+        state's list is carried (``slots_built`` 0).  The state argument
+        is donated: steady-state frames allocate nothing new."""
         det = self.detector
         hp, wp, h, w = splan.hp, splan.wp, splan.h, splan.w
         tile, halo = splan.tile, splan.halo
@@ -406,13 +422,20 @@ class StreamEngine:
             new_ref = jnp.where(pix | full, frame, state.ref)
             new_drift = jnp.where(changed | full, 0.0,
                                   jnp.maximum(state.drift, scores))
-            slots = jnp.nonzero(new_bitmap, size=decode_cap,
-                                fill_value=n_slots)[0].astype(jnp.int32)
+            # the list is a function of the bitmap: compact all n_slots
+            # only when a verdict changed
+            changed_bm = jnp.any(new_bitmap != state.bitmap)
+            slots = jax.lax.cond(
+                changed_bm,
+                lambda: jnp.nonzero(new_bitmap, size=decode_cap,
+                                    fill_value=n_slots)[0].astype(jnp.int32),
+                lambda: state.slots)
             n_surv = new_bitmap.sum().astype(jnp.int32)
             out = StreamStepOut(mode, n_tiles, n_rec, levels_active, n_surv,
-                                slots, head_tiles, dense_tiles)
+                                slots, changed_bm.astype(jnp.int32),
+                                head_tiles, dense_tiles)
             new_state = StreamState(
-                new_ref, new_bitmap, new_drift, state.frame_idx + 1,
+                new_ref, new_bitmap, slots, new_drift, state.frame_idx + 1,
                 jnp.where(full, state.frame_idx, state.last_full))
             return new_state, out
 

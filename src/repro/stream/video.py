@@ -214,6 +214,7 @@ class VideoDetector:
         self._pending: deque[_DevToken] = deque()   # in-flight frames, FIFO
         self._last_mode = "full"          # last committed frame's mode
         self.xfer_bytes = 0               # host<->device traffic accounting
+        self.slot_builds = 0              # steps that rebuilt the slot list
         self.sid = next(_SIDS)            # this stream's id in its spans
 
     # ------------------------------------------------------------ plumbing
@@ -446,13 +447,14 @@ class VideoDetector:
         or ``'full'`` (a keyframe or full refresh, committed on the device
         like the others) — and finish it with :meth:`commit_token`.
         Waits for the device step, then copies its verdict scalars and
-        head-work counters."""
+        head-work counters.  ``tok.flags`` holds (mode, tiles changed,
+        windows recomputed, levels active, survivors, slot list rebuilt)."""
         if not self._pending or tok is not self._pending[0]:
             raise RuntimeError("device tokens must be polled/retired in "
                                "submit order")
         if self._splan is None:
             # frame smaller than the detection window: nothing to run
-            tok.flags = (2 if self._rects is None else 0,) + (0,) * 4
+            tok.flags = (2 if self._rects is None else 0,) + (0,) * 5
             return _MODES[tok.flags[0]]
         out = tok.out
         jax.block_until_ready(out.n_surv)
@@ -460,11 +462,14 @@ class VideoDetector:
             # repro: ignore[HOST_SYNC] contract sync: the step's scalar verdict is what poll exists to fetch
             flags = jax.device_get((out.mode, out.tiles_changed, out.n_rec,
                                     out.levels_active, out.n_surv,
-                                    out.head_tiles, out.dense_tiles))
-            tok.flags = tuple(int(v) for v in flags[:5])
+                                    out.slots_built, out.head_tiles,
+                                    out.dense_tiles))
+            tok.flags = tuple(int(v) for v in flags[:6])
             attrs.update(zip(("mode", "n_tiles", "n_rec", "levels_active"),
                              tok.flags[:4]))
-            attrs.update(self._head_counts(flags[5], int(flags[6])))
+            attrs["slots_built"] = tok.flags[5]
+            attrs.update(self._head_counts(flags[6], int(flags[7])))
+        self.slot_builds += tok.flags[5]
         self.xfer_bytes += (7 + self.detector.n_stages) * 4
         return _MODES[tok.flags[0]]
 

@@ -223,8 +223,9 @@ def test_pack_and_fetch_count_the_bytes_they_move(flushed):
 
 def test_a_device_stream_records_step_poll_fetch_and_decode():
     """Each frame of a device-resident stream dispatches under
-    ``stream.step`` (its session and frame), copies its verdict and the
-    head's counters under ``stream.poll``, and, when the step evaluated
+    ``stream.step`` (its session and frame), copies its verdict, whether
+    the step rebuilt the survivor-slot list and the head's counters under
+    ``stream.poll``, and, when the step evaluated
     windows, copies the survivor slots (``stream.fetch``, with their
     bytes) and decodes and groups them (``stream.decode`` around
     ``nms.group``)."""
@@ -253,6 +254,9 @@ def test_a_device_stream_records_step_poll_fetch_and_decode():
         assert len(p.attrs["head_windows"]) == len(n_weak)
         assert 0 <= p.attrs["head_work"] <= p.attrs["head_dense"]
     assert polls[1].attrs["head_dense"] == 0          # nothing ran
+    built = [p.attrs["slots_built"] for p in polls]
+    assert built[1] == 0 and set(built) <= {0, 1}     # cached: list kept
+    assert vd.slot_builds == sum(built)
     assert polls[0].attrs["head_dense"] >= polls[2].attrs["head_dense"] > 0
     fetches = [s for s in got if s.name == "stream.fetch"]
     assert len(fetches) == 2                  # the full and the changed frame

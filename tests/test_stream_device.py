@@ -6,6 +6,8 @@ pipelined submit/retire API, large changes, and the decode-overflow
 fallback; every frame, keyframes and full refreshes included, runs in one
 step program per stream, whose masked fused head (``use_pallas``, step 1)
 agrees with ``detect`` and the plain reference (``bench/reference.py``);
+the survivor-slot list is rebuilt exactly on the frames whose bitmap
+changed and carried otherwise, through a decode overflow and a reset;
 the donated state reuses its buffers; and serving sessions report
 identical stream stats either way."""
 
@@ -21,6 +23,7 @@ from repro.serve import DetectorService, PodSpec, ServiceConfig
 from repro.stream import (SCENARIOS, StreamConfig, StreamEngine,
                           VideoDetector, make_video, tile_change_scores,
                           dilate_tiles)
+from repro.stream.video import level_windows_from_raw
 
 CASC = paper_shaped_cascade(0, stage_sizes=[3, 4, 5, 6, 8])
 KW = dict(step=2, scale_factor=1.3, min_neighbors=2)
@@ -152,6 +155,94 @@ def test_decode_overflow_falls_back_to_full(detector):
         modes.append(sd.mode)
         assert np.array_equal(rh, rd)
     assert set(modes) == {"full"}
+
+
+# ------------------------------------------------- carried survivor slots
+def face_scene(seed: int = 1, h: int = HW, w: int = HW):
+    """A seeded background and the same background with a 24 px face."""
+    from bench.scenes import make_background, make_face
+
+    rng = np.random.default_rng(seed)
+    bg = np.rint(make_background(rng, h, w)).astype(np.float32)
+    with_face = bg.copy()
+    with_face[36:60, 36:60] = np.rint(make_face(rng, 24))
+    return bg, with_face
+
+
+def stream_tokens(vd, frames):
+    """Retire ``frames`` one by one; per frame the token and the result."""
+    out = []
+    for f in frames:
+        tok = vd.submit(f)
+        vd.poll(tok)
+        out.append((tok,) + vd.commit_token(tok))
+    return out
+
+
+def test_slot_list_is_rebuilt_only_when_the_bitmap_changes(detector):
+    # a parked camera: a face enters at frame k and leaves at frame m; the
+    # keyframes at 4 and 8 see an unchanged scene, and the last frame
+    # changes one corner of it by one grey level
+    bg, with_face = face_scene()
+    tickled = bg.copy()
+    tickled[:2, -2:] += 1.0
+    k, m = 5, 7
+    frames = [bg] * k + [with_face] * (m - k) + [bg] * 2 + [tickled]
+    eng = StreamEngine(detector)
+    vd = VideoDetector(detector, DEV_CFG._replace(full_refresh_frac=0.95),
+                       engine=eng)
+    runs = stream_tokens(vd, frames)
+    want, prev = [], np.zeros(0)
+    for f in frames:
+        cur = vd._slots_of(level_windows_from_raw(detector.detect_raw(f)))
+        want.append((cur, int(not np.array_equal(cur, prev))))
+        prev = cur
+    built = [tok.flags[5] for tok, _r, _s in runs]
+    assert [s.mode for _t, _r, s in runs] == [
+        "full", "cached", "cached", "cached", "full", "incremental",
+        "cached", "incremental", "full", "incremental"]
+    assert built == [b for _c, b in want]
+    assert built[0] == built[k] == built[m] == 1
+    assert built[1:k] == [0] * (k - 1)            # cached, then a keyframe
+    assert built[k + 1] == built[m + 1] == built[-1] == 0
+    assert vd.slot_builds == 3
+    for (tok, rects, _s), f, (cur, _b) in zip(runs, frames, want):
+        slots = np.asarray(tok.out.slots)
+        assert np.array_equal(slots[:len(cur)], cur)
+        assert (slots[len(cur):] == vd._splan.n_slots).all()
+        assert np.array_equal(rects, detector.detect(f))
+    assert eng.program_builds == 1
+
+
+def test_carried_slot_list_after_overflow_and_reset(detector):
+    # more survivors than the slot list holds: the carried (truncated)
+    # list stays the bitmap's, and every frame, the unchanged ones too,
+    # takes its rects from a host detect; after reset the stream starts
+    # from the all-fill list of the all-false bitmap
+    bg, _face = face_scene()
+    cap = 4
+    eng = StreamEngine(detector)
+    vd = VideoDetector(detector, DEV_CFG._replace(keyframe_interval=0),
+                       engine=eng, decode_cap=cap)
+    runs = stream_tokens(vd, [bg] * 3)
+    want = vd._slots_of(level_windows_from_raw(detector.detect_raw(bg)))
+    assert len(want) > cap
+    assert [tok.flags[5] for tok, _r, _s in runs] == [1, 0, 0]
+    for tok, rects, stats in runs:
+        assert stats.mode == "full"
+        assert tok.flags[4] == len(want)
+        assert np.array_equal(np.asarray(tok.out.slots), want[:cap])
+        assert np.array_equal(rects, detector.detect(bg))
+    fill = np.full(cap, vd._splan.n_slots, np.int32)
+    assert np.array_equal(np.asarray(eng.init_state(vd._splan, 0).slots),
+                          fill)
+    vd.reset()
+    blank = np.full_like(bg, 128.0)
+    (tok, rects, stats), = stream_tokens(vd, [blank])
+    assert stats.mode == "full" and tok.flags[4:6] == (0, 0)
+    assert np.array_equal(np.asarray(tok.out.slots), fill)
+    assert np.array_equal(rects, detector.detect(blank))
+    assert vd.slot_builds == 1 and eng.program_builds == 1
 
 
 # ------------------------------------------------------------- residency
