@@ -4,8 +4,7 @@ Two sections:
 
 1. **Real streams (correctness anchor)** — a few co-keyed threshold-0
    streams run through the actual service/fleet data path: results must be
-   bit-identical to per-frame ``detect``, and the changed-tile rounds of
-   co-keyed tenants must share one compaction.
+   bit-identical to per-frame ``detect``.
 
 2. **Fleet simulation (~1k streams)** — the *control plane* is real (the
    actual :class:`FleetScheduler`: admission against the calibrated

@@ -6,11 +6,13 @@ moving face, slow lighting drift under a positive threshold, and a camera
 pan (the adversarial bound — everything changes, streaming must degrade to
 roughly per-frame cost, not collapse).
 
+Each stream runs the device-resident step, double-buffered across frames.
 Reported per scenario: per-frame baseline vs streaming throughput, frame
 latency percentiles, the fraction of tiles/windows skipped, and — for
 threshold-0 scenarios — whether the streaming output was bit-identical to
 ``Detector.detect`` on every frame (it must be; the equivalence suite in
-``tests/test_stream.py`` enforces the same invariant)."""
+``tests/test_stream.py`` enforces the same invariant).  Timings are of
+whatever backend runs the benchmark."""
 
 from __future__ import annotations
 
@@ -34,31 +36,25 @@ REPEATS = 3    # timed passes per row; min-time is the low-noise estimator
 
 
 def _run_scenario(det, engine, kind, threshold, tile, keyframe, n_frames,
-                  hw, device=False):
+                  hw):
     from repro.stream import VideoDetector, StreamConfig, make_video
 
     video = make_video(kind, n_frames=n_frames, h=hw, w=hw, seed=3)
     frames = [f for f, _gt in video]
     cfg = StreamConfig(tile=tile, threshold=threshold,
-                       keyframe_interval=keyframe, device_state=device)
+                       keyframe_interval=keyframe)
 
-    # warm both paths (compile; the engine's jit cache is shared) over the
-    # whole sequence so every capacity-ladder rung the timed run will hit
-    # is already built — device rows warm through the pipelined loop so the
-    # ahead-dispatch programs compile too
+    # warm the detector and the stream step (compile; the engine's jit
+    # cache is shared) through the pipelined loop the timed run uses
     det.detect(frames[0])
     warm = VideoDetector(det, cfg, engine=engine)
-    if device:
-        prev = None
-        for f in frames:
-            tok = warm.submit(f)
-            if prev is not None:
-                warm.retire(prev)
-            prev = tok
-        warm.retire(prev)
-    else:
-        for f in frames:
-            warm.process(f)
+    prev = None
+    for f in frames:
+        tok = warm.submit(f)
+        if prev is not None:
+            warm.retire(prev)
+        prev = tok
+    warm.retire(prev)
 
     # each timed pass measures baseline and stream back to back on a fresh
     # VideoDetector; the per-path minimum over the repeats strips scheduler
@@ -75,37 +71,25 @@ def _run_scenario(det, engine, kind, threshold, tile, keyframe, n_frames,
         vd = VideoDetector(det, cfg, engine=engine)
         rep_plan, rep_commit, rep_stats, streamed = [], [], [], []
         t0 = time.perf_counter()
-        if device:
-            # depth-2 double-buffered loop: frame N+1's plan-and-eval step
-            # is dispatched before frame N's result is fetched
-            prev = None
-            for f in frames:
-                t1 = time.perf_counter()
-                tok = vd.submit(f)
-                rep_plan.append(time.perf_counter() - t1)
-                if prev is not None:
-                    t1 = time.perf_counter()
-                    rects, st = vd.retire(prev)
-                    rep_commit.append(time.perf_counter() - t1)
-                    streamed.append(rects)
-                    rep_stats.append(st)
-                prev = tok
+        # depth-2 double-buffered loop: frame N+1's plan-and-eval step is
+        # dispatched before frame N's result is fetched
+        prev = None
+        for f in frames:
             t1 = time.perf_counter()
-            rects, st = vd.retire(prev)
-            rep_commit.append(time.perf_counter() - t1)
-            streamed.append(rects)
-            rep_stats.append(st)
-        else:
-            for f in frames:
+            tok = vd.submit(f)
+            rep_plan.append(time.perf_counter() - t1)
+            if prev is not None:
                 t1 = time.perf_counter()
-                frame, plan = vd.plan_frame(f)
-                t2 = time.perf_counter()
-                rects, st = vd.commit_planned(frame, plan)
-                t3 = time.perf_counter()
-                rep_plan.append(t2 - t1)
-                rep_commit.append(t3 - t2)
+                rects, st = vd.retire(prev)
+                rep_commit.append(time.perf_counter() - t1)
                 streamed.append(rects)
                 rep_stats.append(st)
+            prev = tok
+        t1 = time.perf_counter()
+        rects, st = vd.retire(prev)
+        rep_commit.append(time.perf_counter() - t1)
+        streamed.append(rects)
+        rep_stats.append(st)
         rep_s = time.perf_counter() - t0
         exact = exact and all(np.array_equal(a, b)
                               for a, b in zip(baseline, streamed))
@@ -117,13 +101,12 @@ def _run_scenario(det, engine, kind, threshold, tile, keyframe, n_frames,
     rebuilds = engine.program_builds + det.program_builds - builds0
 
     lat_ms = (np.asarray(plan_t) + np.asarray(commit_t)) * 1e3
-    # fraction of pyramid-level SAT/head builds actually run per frame
-    # (after the first keyframe): the level-subset engine's skip metric
+    # fraction of pyramid levels whose head ran per frame (after the
+    # first keyframe): the step's level-skip metric
     lvl_sat = float(np.mean([s.levels_active / max(s.levels_total, 1)
                              for s in stats[1:]])) if len(stats) > 1 else 1.0
     return {
-        "scenario": kind + (" (device)" if device else ""),
-        "device": device,
+        "scenario": kind,
         "threshold": threshold,
         "frames": n_frames,
         "base_fps": n_frames / base_s,
@@ -131,8 +114,7 @@ def _run_scenario(det, engine, kind, threshold, tile, keyframe, n_frames,
         "speedup": base_s / stream_s,
         "p50_ms": float(np.percentile(lat_ms, 50)),
         "p95_ms": float(np.percentile(lat_ms, 95)),
-        # phase split: host rows time plan_frame vs commit_planned; device
-        # rows time submit (async dispatch) vs retire (sync + decode)
+        # phase split: submit (async dispatch) vs retire (sync + decode)
         "plan_ms": float(np.mean(plan_t) * 1e3),
         "commit_ms": float(np.mean(commit_t) * 1e3),
         # host<->device traffic per frame (accounted, not measured)
@@ -156,41 +138,13 @@ def run(n_frames: int = 24, hw: int = 160, fast: bool = False) -> list[dict]:
     casc, _ = pretrained_cascade()
     det = Detector(casc, EngineConfig(mode="wave", step=2,
                                       scale_factor=1.25, min_neighbors=2))
-    from repro.stream import make_video, StreamEngine, StreamConfig
+    from repro.stream import make_video, StreamEngine
     probe = make_video("static_cctv", n_frames=1, h=hw, w=hw, seed=3)[0][0]
-    # tune_tail races the packed-tail backends and persists the crossover
-    # ladder; the stream engine's rung-sized programs then pick gather vs
-    # packed-kernel per dispatch from it
-    det = det.calibrated(probe, tune_tail=True,
-                         tail_sizes=(128, 1024) if fast
-                         else (128, 512, 2048, 8192))
-    print(f"packed-tail rungs: {det.config.tail_rungs} "
-          f"(pallas from n>={det.cal_profile['tail']['crossover']})")
-    engine = StreamEngine(det, StreamConfig().max_changed_frac)
-    rows = []
-    for kind, threshold, tile, keyframe in SCENARIOS:
-        rows.append(_run_scenario(det, engine, kind, threshold, tile,
-                                  keyframe, n_frames, hw))
-    # the same scenarios with device-resident state: planning, change
-    # scoring and the incremental tail fused into one donated jitted step,
-    # double-buffered across frames
-    for kind, threshold, tile, keyframe in SCENARIOS:
-        rows.append(_run_scenario(det, engine, kind, threshold, tile,
-                                  keyframe, n_frames, hw, device=True))
-    for row in rows:
-        row["tail"] = "auto"
-    # the same stream forced through the packed-window kernel: exactness of
-    # the kernelized incremental path on a real scenario (speed is the
-    # ladder's business — this row shows the kernel is safe to pick)
-    det_k = det.__class__(det.cascade,
-                          det.config._replace(tail_backend="pallas"))
-    eng_k = StreamEngine(det_k, StreamConfig().max_changed_frac)
-    row = _run_scenario(det_k, eng_k, "static_cctv", 0.0, 16, 0,
-                        n_frames, hw)
-    row["scenario"] = "static_cctv (tail=pallas)"
-    row["tail"] = "pallas"
-    rows.append(row)
-    return rows
+    det = det.calibrated(probe)
+    engine = StreamEngine(det)
+    return [_run_scenario(det, engine, kind, threshold, tile, keyframe,
+                          n_frames, hw)
+            for kind, threshold, tile, keyframe in SCENARIOS]
 
 
 def main(fast: bool = False):
@@ -207,19 +161,16 @@ def main(fast: bool = False):
     inter = rows[1]
     assert inter["exact"] is True, "threshold-0 streaming must be bit-exact"
     assert inter["lvl_sat_frac"] < 0.5, (
-        f"mostly-idle stream should build SATs for < 50% of pyramid levels "
-        f"per frame, got {inter['lvl_sat_frac']:.2f}")
-    kern = rows[-1]
-    assert kern["tail"] == "pallas" and kern["exact"] is True, \
-        "packed-window-kernel streaming must be bit-exact"
+        f"mostly-idle stream should run the head on < 50% of pyramid "
+        f"levels per frame, got {inter['lvl_sat_frac']:.2f}")
     for r in rows:
-        if r.get("device") and r["threshold"] <= 0:
+        if r["threshold"] <= 0:
             assert r["exact"] is True, (
-                f"device-resident stream must stay bit-exact at "
-                f"threshold 0: {r['scenario']}")
-            assert r["rebuilds"] == 0, (
-                f"warmed device stream rebuilt {r['rebuilds']} "
-                f"program(s): {r['scenario']}")
+                f"stream must stay bit-exact at threshold 0: "
+                f"{r['scenario']}")
+        assert r["rebuilds"] == 0, (
+            f"warmed stream rebuilt {r['rebuilds']} program(s): "
+            f"{r['scenario']}")
     return rows
 
 

@@ -145,7 +145,7 @@ def stream(det, frames) -> None:
     """Device-resident stream over ``frames``; each equals ``detect``."""
     from repro.stream import StreamConfig, VideoDetector
 
-    vid = VideoDetector(det, StreamConfig(threshold=0.0, device_state=True))
+    vid = VideoDetector(det, StreamConfig(threshold=0.0))
     t0 = time.perf_counter()
     toks = [vid.submit(f) for f in frames]
     outs = [vid.retire(t) for t in toks]

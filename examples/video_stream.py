@@ -1,6 +1,6 @@
 """Streaming video detection demo: temporal tile-reuse over a CCTV-style
-synthetic stream, plus concurrent stream sessions through the serving
-front-end.
+synthetic stream, one device-resident step per frame, plus concurrent
+stream sessions through the serving front-end.
 
     PYTHONPATH=src python examples/video_stream.py
 """
@@ -30,7 +30,7 @@ def main() -> None:
         print(f"frame {st.frame_idx:2d} {st.mode:11s} "
               f"tiles {st.tiles_changed:3d}/{st.tiles_total}  "
               f"windows {st.windows_recomputed:5d}/{st.windows_total}  "
-              f"level SATs {st.levels_active}/{st.levels_total}  "
+              f"levels {st.levels_active}/{st.levels_total}  "
               f"faces {len(rects)}")
 
     print("\n== concurrent streams through DetectorService ==")

@@ -57,42 +57,28 @@ python -m benchmarks.run --fast --only bench_detector --artifacts .
 echo "=== smoke: bench_rit (content/RIT relation, fast) ==="
 python -m benchmarks.run --fast --only bench_rit
 
-echo "=== smoke: bench_video (tile-reuse + level skip + tail rungs, fast) ==="
+echo "=== smoke: bench_video (tile-reuse + level skip, fast) ==="
 python -m benchmarks.run --fast --only bench_video --artifacts .
 python - <<'EOF'
 # High-motion streams must track per-frame detect within 5%: for each
-# adversarial scenario the better of the host-planned and device-resident
-# rows must reach 0.95x (3% timing-noise tolerance on the ratio, in line
-# with the other benchmark gates).  Device-resident streaming must keep
-# threshold-0 bit-identity with zero warmed rebuilds, and the static
-# stream's FPS must strictly improve over the host-planned path.
+# adversarial scenario the stream must reach 0.95x (3% timing-noise
+# tolerance on the ratio, in line with the other benchmark gates).
+# Streaming must keep threshold-0 bit-identity with zero warmed rebuilds.
 import json
 
 rows = json.load(open("BENCH_video.json"))["rows"]
 by = {r["scenario"]: r for r in rows}
 for kind in ("moving_face", "camera_pan"):
-    host, dev = by[kind], by[kind + " (device)"]
-    best = max(host["speedup"], dev["speedup"])
-    assert best >= 0.95 * 0.97, \
-        f"{kind}: streaming fell to {best:.3f}x of per-frame detect " \
-        f"(host {host['speedup']:.3f}, device {dev['speedup']:.3f})"
-    for r in (host, dev):
-        assert r["exact"] is True, f"{r['scenario']} lost bit-identity"
-devrows = [r for r in rows if r.get("device")]
-assert devrows, "no device-resident rows in BENCH_video.json"
-for r in devrows:
+    r = by[kind]
+    assert r["speedup"] >= 0.95 * 0.97, \
+        f"{kind}: streaming fell to {r['speedup']:.3f}x of per-frame detect"
+for r in rows:
     if r["threshold"] <= 0:
         assert r["exact"] is True, f"{r['scenario']} lost bit-identity"
     assert r["rebuilds"] == 0, f"{r['scenario']} rebuilt programs warm"
-st_h, st_d = by["static_cctv"], by["static_cctv (device)"]
-assert st_d["stream_fps"] > st_h["stream_fps"], \
-    f"device-resident static stream no faster than host " \
-    f"({st_d['stream_fps']:.1f} vs {st_h['stream_fps']:.1f} fps)"
-assert st_d["host_xfer"] < st_h["host_xfer"] * 2, \
-    "device static stream moves unexpectedly much host<->device traffic"
-print(f"video stream OK: static {st_h['stream_fps']:.0f}->"
-      f"{st_d['stream_fps']:.0f} fps device-resident, high-motion "
-      + ", ".join(f"{k} {max(by[k]['speedup'], by[k + ' (device)']['speedup']):.2f}x"
+print(f"video stream OK: static {by['static_cctv']['stream_fps']:.0f} fps, "
+      f"high-motion "
+      + ", ".join(f"{k} {by[k]['speedup']:.2f}x"
                   for k in ("moving_face", "camera_pan")))
 EOF
 

@@ -1,11 +1,11 @@
 """Host-sync discipline for the streaming hot path.
 
-The device-resident stream contract (``StreamConfig.device_state``) is
-that a steady-state frame moves exactly three things across the
-host<->device boundary: the new frame in, the step's scalar verdict out,
-and the decoded survivor slot list out.  Everything else — reference
-pixels, survivor bitmaps, drift, frame counters — stays on device inside
-the donated :class:`repro.stream.StreamState`.
+The device-resident stream contract is that a steady-state frame moves
+exactly three things across the host<->device boundary: the new frame in,
+the step's scalar verdict out, and the decoded survivor slot list out.
+Everything else — reference pixels, survivor bitmaps, drift, frame
+counters — stays on device inside the donated
+:class:`repro.stream.StreamState`.
 
 ``HOST_SYNC`` keeps that contract visible in the diff: any host
 materialisation (``np.asarray``/``np.array``, ``jax.device_get``,

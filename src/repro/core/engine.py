@@ -122,8 +122,7 @@ class EngineConfig(NamedTuple):
     #                                ladder ((max_windows, backend), ...)
     #                                ascending, persisted by
     #                                Detector.calibrated(tune_tail=True) so
-    #                                batch, stream and serving inherit one
-    #                                decision
+    #                                batch and serving inherit one decision
     head_mode: str = "auto"        # dense-head execution: 'fused' one-dispatch
     #                                megakernel | 'split' three-dispatch path
     #                                forces one; 'auto' walks the calibrated
@@ -365,7 +364,7 @@ class Detector:
 
     def _padded_plan(self, h: int, w: int):
         hp, wp = self._bucket_hw(h, w)
-        return hp, wp, self.batch_plan(hp, wp).levels_all
+        return hp, wp, self.batch_plan(hp, wp).levels
 
     @staticmethod
     def _decode_rects(ys: np.ndarray, xs: np.ndarray,
@@ -646,7 +645,7 @@ class Detector:
         (hp, wp), = hws
         stack, valid_hw = self._pack_stack(imgs, hp, wp)
         out = []
-        for lp in self.batch_plan(hp, wp).levels_all:
+        for lp in self.batch_plan(hp, wp).levels:
             ys_idx = downscale_indices(hp, lp.height)
             xs_idx = downscale_indices(wp, lp.width)
             img_l = stack[:, ys_idx[:, None], xs_idx[None, :]]
@@ -780,9 +779,8 @@ class Detector:
         on the profiled image's *real* multi-level packed workload — the
         plan's pyramid levels, each weighted by its measured survivor
         density — and the winners persisted in ``EngineConfig.tail_rungs``,
-        so every consumer of the config — batched detection, the streaming
-        engine's rung-sized programs, and the serving layer — inherits the
-        measured kernel-vs-gather crossover.
+        so every consumer of the config — batched detection and the
+        serving layer — inherits the measured kernel-vs-gather crossover.
 
         With ``tune_head=True`` the dense *head* is autotuned on the same
         workload (``kernels.autotune``): fused megakernel vs split

@@ -347,9 +347,9 @@ def tile_change_mask(prev: jax.Array, cur: jax.Array, threshold=0.0, *,
                      tile: int, halo: int = 0, exact: bool = True,
                      use_kernel: bool = True
                      ) -> tuple[jax.Array, jax.Array]:
-    """(changed, scores) tile grids of ``cur`` vs ``prev`` — the device
-    port of the host ``tile_change_scores`` + ``dilate_tiles`` pair
-    (one fused pass: SAT scoring, exact/threshold test, halo dilation)."""
+    """(changed, scores) tile grids of ``cur`` vs ``prev`` in one fused
+    pass — scoring, exact/threshold test, halo dilation; oracle twin
+    :func:`repro.kernels.ref.tile_change_mask_ref`."""
     if not use_kernel:
         return ref.tile_change_mask_ref(prev, cur, threshold, tile=tile,
                                         halo=halo, exact=exact)
@@ -362,8 +362,8 @@ def changed_window_map(changed: jax.Array, ty0: jax.Array, ty1: jax.Array,
                        tx0: jax.Array, tx1: jax.Array, valid: jax.Array,
                        *, use_kernel: bool = True) -> jax.Array:
     """Flat per-level window recompute mask from a changed-tile grid and
-    the plan-compiled receptive-field tile-range brackets — the device
-    port of the host ``changed_window_mask`` (integer SAT, exact)."""
+    the plan-compiled receptive-field tile-range brackets; oracle twin
+    :func:`repro.kernels.ref.changed_window_map_ref` (integer SAT, exact)."""
     if not use_kernel:
         return ref.changed_window_map_ref(changed, ty0, ty1, tx0, tx1,
                                           valid)

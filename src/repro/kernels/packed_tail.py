@@ -1,11 +1,10 @@
 """The one packed-tail evaluator: compacted cascade stages, three backends.
 
 Every "tail" in the system — the batched engine's shared-compaction
-segments (``Detector._build_batch_fn``) and the streaming engine's
-incremental evaluation over changed windows (``StreamEngine._build_fn``) —
-runs the same computation: a run of cascade stages over a *packed* window
-list whose entries live on different images and pyramid levels, addressed
-through flat per-level SAT offsets.  This module is its single
+segments (``Detector._build_batch_fn``) — runs the same computation: a run
+of cascade stages over a *packed* window list whose entries live on
+different images and pyramid levels, addressed through flat per-level SAT
+offsets.  This module is its single
 implementation, with three interchangeable, bit-identical backends:
 
 ``gather``
@@ -27,8 +26,8 @@ implementation, with three interchangeable, bit-identical backends:
 The dense/packed/gather *crossover* is a measured property, not a guess:
 :func:`measure_rungs` times each backend at capacity-ladder sizes and
 records the winner per rung; ``Detector.calibrated(tune_tail=True)``
-persists that ladder in ``EngineConfig.tail_rungs`` so batched detection,
-streaming, and serving all inherit one decision.  (The *dense* end of the
+persists that ladder in ``EngineConfig.tail_rungs`` so batched detection
+and serving inherit one decision.  (The *dense* end of the
 spectrum — full-grid waves through the dense tile kernel — is chosen
 earlier, by the engine's segment plan; this module only arbitrates the
 packed/gather end.)
@@ -52,7 +51,7 @@ _AREA = float(WINDOW * WINDOW)
 BACKENDS = ("gather", "bulk", "pallas")
 
 # capacity-ladder sizes at which measure_rungs races the backends; chosen to
-# bracket the real ladders (BATCH_CAP_FLOOR=128 .. stream rung doublings)
+# bracket the real ladders (BATCH_CAP_FLOOR=128 .. calibrated batch lists)
 DEFAULT_RUNG_SIZES = (128, 512, 2048, 8192)
 
 # lanes per bulk-gather block: each (K, 3, lanes) corner temporary stays

@@ -1,7 +1,7 @@
 """Device-side temporal tile planning: change scoring + window mapping.
 
-jax ports of :mod:`repro.stream.tiles` (`tile_change_scores`,
-`dilate_tiles`, `changed_window_mask`) fused into two kernels so the
+Two kernels, with oracle twins in :mod:`repro.kernels.ref`
+(``tile_change_mask_ref``, ``changed_window_map_ref``), so the
 device-resident stream step (:meth:`repro.stream.StreamEngine.stream_step`)
 can compute a whole frame plan without a host round-trip:
 
@@ -18,13 +18,12 @@ TPU streams, where an XLA gather serializes (ROADMAP 1.3a).
 Geometry never originates here: the per-level receptive-field tile-range
 tables and window-limit masks are compiled once by
 :func:`repro.plan.compile_stream_plan` and passed in as arrays
-(PLAN_GEOMETRY).  Exactness mirrors the host contract: with
-``exact=True`` the changed test is a per-tile any-reduction of
-``delta != 0`` — IEEE subtraction is exact at zero (``RN(x - y) == 0``
-iff ``x == y``), so the float32 device test equals the host's float64
-one bit-for-bit.  Positive-threshold *scores* are float32 sums here
-vs float64 on host, so near-threshold tiles may classify differently
-(documented divergence; threshold 0 is the bit-identity contract).
+(PLAN_GEOMETRY).  With ``exact=True`` the changed test is a per-tile
+any-reduction of ``delta != 0`` — IEEE subtraction is exact at zero
+(``RN(x - y) == 0`` iff ``x == y``), so a tiny change is never absorbed
+by a large one.  Positive-threshold *scores* are float32 sums, so
+near-threshold tiles may classify differently from an exact-arithmetic
+score (threshold 0 is the bit-identity contract).
 """
 
 from __future__ import annotations
@@ -46,7 +45,7 @@ def tile_change_mask_kernel(prev: jax.Array, cur: jax.Array,
     any-pixel-differs when ``exact``, else ``scores > threshold``);
     ``scores`` is the mean squared pixel change per tile.  Shapes are
     static from ``cur``; partial edge tiles divide by their true area,
-    like the host path.
+    like the oracle twin.
     """
     h, w = cur.shape
     ty, tx = -(-h // tile), -(-w // tile)
@@ -63,7 +62,7 @@ def tile_change_mask_kernel(prev: jax.Array, cur: jax.Array,
         changed = nz.reshape(ty, tile, tx, tile).any(axis=(1, 3))
     else:
         changed = scores > threshold
-    for _ in range(halo):          # 4-neighbour ring, like the host dilate
+    for _ in range(halo):          # 4-neighbour ring, like the oracle twin
         changed = (changed
                    | jnp.pad(changed[:-1, :], ((1, 0), (0, 0)))
                    | jnp.pad(changed[1:, :], ((0, 1), (0, 0)))

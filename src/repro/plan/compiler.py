@@ -1,18 +1,18 @@
 """The cascade plan compiler: one place that derives execution facts.
 
 ``compile_plan`` / ``compile_level_plan`` turn (EngineConfig, cascade
-stage count, bucket shape, batch, optional active-level subset, optional
-capacity rung) into the typed IR of :mod:`repro.plan.ir`.  Everything the
-engines used to re-derive independently lives here, once:
+stage count, bucket shape, batch) into the typed IR of
+:mod:`repro.plan.ir`, and ``compile_stream_plan`` the device stream
+step's geometry.  Everything the engines used to re-derive independently
+lives here, once:
 
 - pyramid levels and per-level window grids / limits
   (:func:`compile_plan`, :func:`window_limits`);
 - the dense-prefix / compacted-tail segmentation of the cascade
   (:func:`segment_spans`);
-- compaction capacity ladders — per-level (:func:`level_capacities`),
-  shared across a batch (:func:`shared_capacities`), and the streaming
-  power-of-two rungs (:func:`stream_capacity_rung`, :func:`stream_budget`);
-- the per-segment / per-rung packed-tail backend decision from the
+- compaction capacity ladders — per-level (:func:`level_capacities`)
+  and shared across a batch (:func:`shared_capacities`);
+- the per-segment packed-tail backend decision from the
   measured ``EngineConfig.tail_rungs`` crossover ladder
   (:func:`select_backend`);
 - the per-level dense-head execution mode — fused megakernel vs split
@@ -41,26 +41,18 @@ from repro.kernels.packed_tail import BACKENDS
 from .ir import (CascadePlan, LevelPlan, LevelWavePlan, SegmentPlan,
                  SlotLayout, StreamStatePlan)
 
-__all__ = ["CAP_FLOOR", "BATCH_CAP_FLOOR", "STREAM_CAP_BASE",
-           "STREAM_DECODE_CAP",
+__all__ = ["CAP_FLOOR", "BATCH_CAP_FLOOR", "STREAM_DECODE_CAP",
            "segment_spans", "n_compactions", "level_capacities",
            "shared_capacities", "select_backend", "select_head_mode",
            "validate_config",
            "window_limits", "compile_level_plan", "compile_plan",
-           "compile_stream_plan",
-           "stream_capacity_rung", "stream_budget", "segment_work_units",
-           "plan_cache_info"]
+           "compile_stream_plan", "segment_work_units", "plan_cache_info"]
 
 # static-shape floor of every compaction capacity: keeps `nonzero(size=...)`
 # shapes sane for tiny levels, and is exactly the per-(image, level) lane
 # waste that the batched engine's shared compaction amortizes.
 CAP_FLOOR = 256
 BATCH_CAP_FLOOR = 128
-
-# smallest rung of the streaming packed-list capacity ladder: the host
-# knows the exact changed-window count before dispatch, so stream programs
-# compile a few power-of-two capacities and pick the smallest that fits.
-STREAM_CAP_BASE = 512
 
 # static length of the decoded-survivor slot list a device-resident stream
 # step ships back per frame (the only steady-state device->host transfer
@@ -131,23 +123,6 @@ def shared_capacities(n_slots: int, batch: int, n_comp: int,
         cap = min(cap, caps[-1] if caps else total)
         caps.append(cap)
     return tuple(caps)
-
-
-def stream_capacity_rung(n_sub_slots: int, batch: int, n_changed: int) -> int:
-    """Smallest power-of-two ladder rung holding ``n_changed`` packed
-    windows, capped at the active subset's own slot count."""
-    total = max(n_sub_slots * batch, 1)
-    cap = STREAM_CAP_BASE
-    while cap < n_changed:
-        cap *= 2
-    return min(cap, total)
-
-
-def stream_budget(n_slots: int, batch: int, max_changed_frac: float) -> int:
-    """Most changed windows an incremental flush may evaluate; beyond it a
-    full refresh is cheaper anyway (the caller's fallback)."""
-    total = max(n_slots * batch, 1)
-    return min(max(int(math.ceil(total * max_changed_frac)), 1), total)
 
 
 # ------------------------------------------------------------- work model
@@ -287,24 +262,23 @@ def _pyramid_levels(hp: int, wp: int, scale_factor: float,
                     step: int) -> tuple[LevelPlan, ...]:
     """The bucket's full pyramid as LevelPlans — shared by every plan
     variant over the same bucket geometry."""
-    levels_all, off = [], 0
+    levels, off = [], 0
     for li, lv in enumerate(pyramid_plan(hp, wp, scale_factor)):
         ny = (lv.height - WINDOW) // step + 1
         nx = (lv.width - WINDOW) // step + 1
-        levels_all.append(LevelPlan(li, lv.height, lv.width, lv.scale,
-                                    ny, nx, off))
+        levels.append(LevelPlan(li, lv.height, lv.width, lv.scale, ny, nx,
+                                off))
         off += ny * nx
-    return tuple(levels_all)
+    return tuple(levels)
 
 
 @lru_cache(maxsize=512)
-def _slot_layout(hp: int, wp: int, scale_factor: float, step: int,
-                 active: tuple[int, ...]) -> SlotLayout:
-    """One SlotLayout per (bucket geometry, active subset): every plan
-    variant over it — any batch size, any capacity rung — shares the same
-    index arrays instead of rebuilding and separately retaining them."""
-    return SlotLayout(_pyramid_levels(hp, wp, scale_factor, step), active,
-                      step)
+def _slot_layout(hp: int, wp: int, scale_factor: float,
+                 step: int) -> SlotLayout:
+    """One SlotLayout per bucket geometry: every plan over it, of any
+    batch size, shares the same index arrays instead of rebuilding and
+    separately retaining them."""
+    return SlotLayout(_pyramid_levels(hp, wp, scale_factor, step), step)
 
 
 @lru_cache(maxsize=4096)
@@ -333,51 +307,33 @@ def compile_level_plan(config, n_stages: int, h: int, w: int
 
 
 @lru_cache(maxsize=4096)
-def compile_plan(config, n_stages: int, hp: int, wp: int, batch: int = 1,
-                 levels: tuple[int, ...] | None = None,
-                 capacity: int | None = None) -> CascadePlan:
-    """Compile the full plan for one (bucket, batch, subset, rung).
-
-    ``levels=None`` activates every pyramid level of the bucket.
-    ``capacity=None`` plans the batched engine's dense-prefix + shared
-    compacted tail (capacities from :func:`shared_capacities`, one tail
-    backend per segment capacity); a given ``capacity`` instead plans the
-    streaming shape — one packed segment over *all* stages at that rung,
-    with the rung's backend.
-    """
+def compile_plan(config, n_stages: int, hp: int, wp: int,
+                 batch: int = 1) -> CascadePlan:
+    """Compile the full plan for one (bucket, batch): every pyramid level,
+    the batched engine's dense-prefix + shared compacted tail (capacities
+    from :func:`shared_capacities`, one tail backend per segment
+    capacity)."""
     step = config.step
-    levels_all = _pyramid_levels(hp, wp, config.scale_factor, step)
-    off = sum(lp.n_windows for lp in levels_all)
-    active = (tuple(range(len(levels_all))) if levels is None
-              else tuple(levels))
-    layout = _slot_layout(hp, wp, config.scale_factor, step, active)
-
-    if capacity is None:
-        spans = segment_spans(n_stages, config)
-        caps = shared_capacities(off, batch, n_compactions(spans), config)
-        segments, ki = [], 0
-        for (s0, s1, dense) in spans:
-            if dense:
-                segments.append(SegmentPlan(s0, s1, True))
-            else:
-                c = caps[min(ki, len(caps) - 1)]
-                segments.append(SegmentPlan(s0, s1, False, c,
-                                            select_backend(config, c)))
-                ki += 1
-        segments = tuple(segments)
-    else:
-        caps = (capacity,)
-        segments = (SegmentPlan(0, n_stages, False, capacity,
-                                select_backend(config, capacity)),)
-
-    dense_prefix_n = sum(seg.s1 - seg.s0 for seg in segments if seg.dense)
-    head_modes = tuple(
-        select_head_mode(config, levels_all[li].n_windows)
-        if dense_prefix_n else "split"
-        for li in active)
-    key = ("cascade", hp, wp, batch, levels, capacity, n_stages, config)
-    return CascadePlan(key, hp, wp, batch, step, levels_all, active,
-                       segments, caps, layout, head_modes,
+    levels = _pyramid_levels(hp, wp, config.scale_factor, step)
+    layout = _slot_layout(hp, wp, config.scale_factor, step)
+    spans = segment_spans(n_stages, config)
+    caps = shared_capacities(layout.n_slots, batch, n_compactions(spans),
+                             config)
+    segments, ki = [], 0
+    for (s0, s1, dense) in spans:
+        if dense:
+            segments.append(SegmentPlan(s0, s1, True))
+        else:
+            c = caps[min(ki, len(caps) - 1)]
+            segments.append(SegmentPlan(s0, s1, False, c,
+                                        select_backend(config, c)))
+            ki += 1
+    dense_prefix = sum(s1 - s0 for (s0, s1, dense) in spans if dense)
+    head_modes = tuple(select_head_mode(config, lp.n_windows)
+                       if dense_prefix else "split" for lp in levels)
+    key = ("cascade", hp, wp, batch, n_stages, config)
+    return CascadePlan(key, hp, wp, batch, step, levels, tuple(segments),
+                       caps, layout, head_modes,
                        _resolve_tile(getattr(config, "head_tile", ())),
                        _resolve_tile(getattr(config, "lane_block", ())))
 
@@ -391,18 +347,19 @@ def compile_stream_plan(config, n_stages: int, hp: int, wp: int, h: int,
 
     Precomputes everything the on-device frame planner gathers through:
     the tile grid over the true (h, w) frame, each level's closed
-    tile-range brackets (``tile_range`` of the host
-    :func:`repro.stream.tiles.changed_window_mask`, vectorized over window
-    origins), the flat window-limit mask over the bucket's full slot
-    layout, and the live-window count (the host ``VideoDetector``'s
-    ``_n_live``).  ``decode_cap`` sizes the static decoded-survivor list
-    (default :data:`STREAM_DECODE_CAP`, clipped to the slot count).
+    tile-range brackets of every window origin's receptive field (the
+    ``ty0, ty1, tx0, tx1`` operands of
+    :func:`repro.kernels.ref.changed_window_map_ref`), the flat
+    window-limit mask over the bucket's slot layout, and the live-window
+    count the full-refresh fraction is measured against.  ``decode_cap``
+    sizes the static decoded-survivor list (default
+    :data:`STREAM_DECODE_CAP`, clipped to the slot count).
     """
     step = config.step
-    levels_all = _pyramid_levels(hp, wp, config.scale_factor, step)
+    levels = _pyramid_levels(hp, wp, config.scale_factor, step)
     ty, tx = -(-h // tile), -(-w // tile)
     ranges, valid_parts, n_live = [], [], 0
-    for lp in levels_all:
+    for lp in levels:
         oy = np.arange(lp.ny, dtype=np.int64) * step
         ox = np.arange(lp.nx, dtype=np.int64) * step
         ty0 = np.clip(((oy * hp) // lp.height) // tile, 0, ty - 1)
@@ -419,7 +376,7 @@ def compile_stream_plan(config, n_stages: int, hp: int, wp: int, h: int,
         n_y = min(int(y_lim) // step + 1, lp.ny) if y_lim >= 0 else 0
         n_x = min(int(x_lim) // step + 1, lp.nx) if x_lim >= 0 else 0
         n_live += n_y * n_x
-    n_slots = sum(lp.n_windows for lp in levels_all)
+    n_slots = sum(lp.n_windows for lp in levels)
     limit_mask = (np.concatenate(valid_parts) if valid_parts
                   else np.zeros(0, bool))
     cap = decode_cap if decode_cap is not None else STREAM_DECODE_CAP

@@ -1,70 +1,36 @@
-"""Host-side geometry views over a bucket's :class:`CascadePlan`.
+"""Host-side geometry view over a bucket's :class:`CascadePlan`.
 
-:class:`StreamGeometry` is the streaming/serving layers' handle on one
-shape bucket: the pyramid plan, per-level window grids, flat slot layout,
-window limits for a true (unpadded) frame shape, and cached
-:class:`~repro.plan.ir.SlotLayout` views over active level subsets.  It
-derives everything from ``compile_plan`` — it computes no geometry of its
-own — and exists so host code (tile→window mapping, bitmap merging,
-serving chunk planning) can read the plan without touching jitted
-executors.
+:class:`StreamGeometry` is the stream layer's handle on one shape bucket:
+the pyramid plan, per-level window grids and the flat slot layout the
+stream's survivor slots decode through.  It derives everything from
+``compile_plan`` — it computes no geometry of its own — and exists so host
+code can read the plan without touching jitted executors.
 """
 
 from __future__ import annotations
 
-import numpy as np
-
 from repro.core.pyramid import PyramidLevel
 
-from .compiler import compile_plan, window_limits
-from .ir import SlotLayout
+from .compiler import compile_plan
 
-__all__ = ["StreamGeometry", "LevelSubset"]
-
-# the subset slot/SAT layout *is* the generic plan layout; the old
-# stream-side LevelSubset class folded into it
-LevelSubset = SlotLayout
+__all__ = ["StreamGeometry"]
 
 
 class StreamGeometry:
-    """Static per-bucket geometry shared by host planning and jitted code:
-    pyramid plan, per-level window grids, flat slot layout, SAT layout —
-    all read off the bucket's compiled :class:`CascadePlan`."""
+    """Static per-bucket geometry: pyramid plan, per-level window grids and
+    flat slot layout, all read off the bucket's compiled
+    :class:`CascadePlan`."""
 
     def __init__(self, detector, hp: int, wp: int):
-        cfg = detector.config
-        base = compile_plan(cfg, detector.n_stages, hp, wp)
-        self.base_plan = base
+        base = compile_plan(detector.config, detector.n_stages, hp, wp)
         self.hp, self.wp = hp, wp
-        self.step = cfg.step
-        self._config = cfg
-        self._n_stages = detector.n_stages
+        self.step = detector.config.step
         self.plan = [PyramidLevel(lp.height, lp.width, lp.scale)
-                     for lp in base.levels_all]
-        self.level_windows = [(lp.ny, lp.nx) for lp in base.levels_all]
+                     for lp in base.levels]
+        self.level_windows = [(lp.ny, lp.nx) for lp in base.levels]
         self.slot_offsets = [0] + [lp.slot_offset + lp.n_windows
-                                   for lp in base.levels_all]
+                                   for lp in base.levels]
         self.n_slots = base.n_slots
-        self.sat_sizes = [lp.sat_size for lp in base.levels_all]
-        layout = base.layout
-        self.lvl_of_slot = layout.lvl_of_slot
-        self.y_of_slot = layout.y_of_slot
-        self.x_of_slot = layout.x_of_slot
-        self.sat_base_of_lvl = layout.sat_base_of_lvl
-        self.sat_stride_of_lvl = layout.sat_stride_of_lvl
-
-    def limits(self, h: int, w: int) -> list[tuple[int, int]]:
-        """Per-level inclusive (y_lim, x_lim) for a true (h, w) frame."""
-        return [window_limits(h, w, lp.height, lp.width, self.hp, self.wp)
-                for lp in self.base_plan.levels_all]
-
-    def split_levels(self, flat: np.ndarray) -> list[np.ndarray]:
-        """Flat (n_slots,) per-window array -> one array per level."""
-        return [flat[self.slot_offsets[li]:self.slot_offsets[li + 1]]
-                for li in range(len(self.plan))]
-
-    def subset(self, levels: tuple[int, ...]) -> SlotLayout:
-        """Flat layout over an active level subset (sorted ids); cached by
-        the plan compiler, so repeated calls return the same object."""
-        return compile_plan(self._config, self._n_stages, self.hp, self.wp,
-                            levels=tuple(levels)).layout
+        self.lvl_of_slot = base.layout.lvl_of_slot
+        self.y_of_slot = base.layout.y_of_slot
+        self.x_of_slot = base.layout.x_of_slot

@@ -9,20 +9,19 @@ out of the worker loop).  These types are that model for our engines:
 - :class:`SegmentPlan` — one run of cascade stages and, for compacted tail
   segments, the survivor capacity entering the run plus the packed-tail
   backend chosen for that capacity;
-- :class:`SlotLayout` — the flat slot / SAT layout over an (optionally
-  subset) tuple of levels: the index tables every packed program gathers
-  through, plus the subset→full slot mapping host code merges bitmaps with;
-- :class:`CascadePlan` — the whole compiled plan for one (bucket, batch,
-  level subset, capacity rung): levels + segments + layout, with a
-  hashable ``key`` that *is* the jit-cache identity of the program built
-  from it;
+- :class:`SlotLayout` — the flat slot / SAT layout over a bucket's
+  pyramid levels: the index tables every packed program gathers through;
+- :class:`CascadePlan` — the whole compiled plan for one (bucket, batch):
+  levels + segments + layout, with a hashable ``key`` that *is* the
+  jit-cache identity of the program built from it;
 - :class:`LevelWavePlan` — the single-image per-level wave program's plan
-  (dense window grid, per-compaction capacity ladder).
+  (dense window grid, per-compaction capacity ladder);
+- :class:`StreamStatePlan` — the device stream step's geometry.
 
 Everything here is derived data; :mod:`repro.plan.compiler` is the only
 producer.  Executors (``Detector._build_level_fn``,
-``Detector._build_batch_fn``, ``StreamEngine._build_fn``) consume these
-objects and derive nothing themselves.
+``Detector._build_batch_fn``, ``StreamEngine._build_stream_fn``) consume
+these objects and derive nothing themselves.
 """
 
 from __future__ import annotations
@@ -43,7 +42,7 @@ class LevelPlan(NamedTuple):
     scale: float        # original_size / level_size
     ny: int             # window-grid rows ((h - WINDOW) // step + 1)
     nx: int             # window-grid cols
-    slot_offset: int    # first flat slot of this level in the *full* layout
+    slot_offset: int    # first flat slot of this level in the layout
 
     @property
     def n_windows(self) -> int:
@@ -75,31 +74,16 @@ class SegmentPlan(NamedTuple):
 
 
 class SlotLayout:
-    """Flat slot / SAT layout over an active subset of pyramid levels.
+    """Flat slot / SAT layout over a bucket's pyramid levels: per slot its
+    level and window origin, per level the base and row stride of its
+    table in the flat SAT concatenation."""
 
-    ``slot_indices`` maps each layout slot back to the full-layout flat
-    slot id (the identity mapping when every level is active), so cached
-    per-level bitmaps merge on host.  ``sat_base_of_lvl`` is addressed by
-    *original* level id; inactive levels keep base 0 — no layout slot
-    refers to them, so the value never feeds a gather.
-    """
-
-    def __init__(self, levels_all: tuple[LevelPlan, ...],
-                 active: tuple[int, ...], step: int):
-        self.active = active
-        parts = [np.arange(levels_all[li].slot_offset,
-                           levels_all[li].slot_offset
-                           + levels_all[li].n_windows, dtype=np.int64)
-                 for li in active]
-        self.slot_indices = (np.concatenate(parts) if parts
-                             else np.zeros(0, np.int64))
-        self.n_slots = int(self.slot_indices.shape[0])
+    def __init__(self, levels: tuple[LevelPlan, ...], step: int):
         lvl_parts, y_parts, x_parts = [], [], []
-        for li in active:
-            lp = levels_all[li]
+        for lp in levels:
             gy = np.arange(lp.ny, dtype=np.int32) * step
             gx = np.arange(lp.nx, dtype=np.int32) * step
-            lvl_parts.append(np.full(lp.n_windows, li, np.int32))
+            lvl_parts.append(np.full(lp.n_windows, lp.index, np.int32))
             y_parts.append(np.repeat(gy, lp.nx))
             x_parts.append(np.tile(gx, lp.ny))
         self.lvl_of_slot = (np.concatenate(lvl_parts) if lvl_parts
@@ -108,14 +92,12 @@ class SlotLayout:
                           else np.zeros(0, np.int32))
         self.x_of_slot = (np.concatenate(x_parts) if x_parts
                           else np.zeros(0, np.int32))
-        sizes = [levels_all[li].sat_size for li in active]
-        bases = (np.concatenate([[0], np.cumsum(sizes)[:-1]]).astype(np.int32)
-                 if active else np.zeros(0, np.int32))
-        self.sat_base_of_lvl = np.zeros(max(len(levels_all), 1), np.int32)
-        for li, b in zip(active, bases):
-            self.sat_base_of_lvl[li] = b
+        self.n_slots = int(self.lvl_of_slot.shape[0])
+        bases = np.cumsum([0] + [lp.sat_size for lp in levels])[:-1]
+        self.sat_base_of_lvl = np.zeros(max(len(levels), 1), np.int32)
+        self.sat_base_of_lvl[:len(levels)] = bases
         self.sat_stride_of_lvl = np.asarray(
-            [lp.sat_stride for lp in levels_all], np.int32)
+            [lp.sat_stride for lp in levels], np.int32)
 
 
 class CascadePlan:
@@ -123,18 +105,17 @@ class CascadePlan:
 
     ``key`` is the hashable identity of the plan (and therefore of the
     program built from it) — two calls that compile the same key must
-    reuse the same program.  ``levels`` are the *active* levels (the full
-    pyramid unless a subset was requested); ``segments`` carry the
-    per-segment capacities and tail backends; ``layout`` is the flat
-    slot / SAT layout over the active levels.
+    reuse the same program.  ``levels`` are the bucket's pyramid levels;
+    ``segments`` carry the per-segment capacities and tail backends;
+    ``layout`` is the flat slot / SAT layout over the levels.
     """
 
-    __slots__ = ("key", "hp", "wp", "batch", "step", "levels_all", "active",
-                 "levels", "segments", "capacities", "layout", "head_modes",
-                 "head_tile", "lane_block")
+    __slots__ = ("key", "hp", "wp", "batch", "step", "levels", "segments",
+                 "capacities", "layout", "head_modes", "head_tile",
+                 "lane_block")
 
     def __init__(self, key: tuple, hp: int, wp: int, batch: int, step: int,
-                 levels_all: tuple[LevelPlan, ...], active: tuple[int, ...],
+                 levels: tuple[LevelPlan, ...],
                  segments: tuple[SegmentPlan, ...],
                  capacities: tuple[int, ...], layout: SlotLayout,
                  head_modes: tuple[str, ...] = (),
@@ -144,9 +125,7 @@ class CascadePlan:
         self.hp, self.wp = hp, wp
         self.batch = batch
         self.step = step
-        self.levels_all = levels_all
-        self.active = active
-        self.levels = tuple(levels_all[li] for li in active)
+        self.levels = levels
         self.segments = segments
         self.capacities = capacities
         self.layout = layout
@@ -161,14 +140,13 @@ class CascadePlan:
 
     @property
     def n_slots(self) -> int:
-        """Flat slots of the *active* layout (== full count when all
-        levels are active)."""
+        """Flat slots of the layout: the pyramid's window count."""
         return self.layout.n_slots
 
     @property
     def n_windows_total(self) -> int:
-        """Window count of the full pyramid (all levels, active or not)."""
-        return sum(lp.n_windows for lp in self.levels_all)
+        """Window count of the pyramid (== :attr:`n_slots`)."""
+        return self.n_slots
 
     @property
     def work_units(self) -> int:
@@ -204,7 +182,7 @@ class CascadePlan:
 
     def __repr__(self):
         return (f"CascadePlan(hp={self.hp}, wp={self.wp}, batch={self.batch},"
-                f" levels={len(self.levels)}/{len(self.levels_all)},"
+                f" levels={len(self.levels)},"
                 f" n_slots={self.n_slots}, segments={self.segments})")
 
 
@@ -240,14 +218,14 @@ class StreamStatePlan:
     .StreamEngine.stream_step`) needs beyond a :class:`CascadePlan`:
     the tile grid covering the true (h, w) frame inside its (hp, wp)
     bucket, the per-level closed tile-range brackets of each window
-    origin's receptive field (the host ``changed_window_mask``'s
-    ``tile_range`` tables, precomputed), the flat window-limit mask, the
-    live-window count the full-refresh fraction is measured against, and
-    the static capacity of the decoded-survivor slot list shipped back
-    to host each frame.  ``key`` is the plan's hashable identity — with
-    the evaluation rung and exactness flag it keys the compiled step
-    program.  :func:`repro.plan.compile_stream_plan` is the only
-    producer.
+    origin's receptive field (the range operands of
+    :func:`repro.kernels.ref.changed_window_map_ref`, precomputed), the
+    flat window-limit mask, the live-window count the full-refresh
+    fraction is measured against, and the static capacity of the
+    decoded-survivor slot list shipped back to host each frame.  ``key``
+    is the plan's hashable identity — with the exactness flag and the
+    refresh limits it keys the compiled step program.
+    :func:`repro.plan.compile_stream_plan` is the only producer.
     """
 
     __slots__ = ("key", "hp", "wp", "h", "w", "tile", "halo", "ty", "tx",

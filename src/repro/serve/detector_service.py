@@ -40,20 +40,15 @@ Stream sessions (video workload)
 --------------------------------
 ``open_stream()`` adds stateful video sessions alongside one-shot requests:
 each session owns a :class:`repro.stream.VideoDetector` (temporal tile-reuse
-cache), and ``submit_frame`` enqueues frames into the same queue.  A flush
-processes streams in per-session-ordered *rounds* sharded across pods like
-any other work; within a round the changed-tile work items of concurrent
-sessions that share a *plan key* (their shape bucket, hence their compiled
-:class:`repro.plan.CascadePlan` family) are funneled through the shared
-packed incremental engine — one compaction for every co-keyed stream's
-changed windows — and sessions that need a full refresh (first frame,
-keyframe, over-budget change) are batched through
-``Detector.detect_batch_raw``.  Sessions with device-resident state
-(``StreamConfig.device_state``) dispatch one device step per frame, which
-runs keyframes and refreshes too.  This is the content-dependent,
-variable-size task stream the asymmetric-scheduling literature targets:
-mostly-static streams produce tiny work items, busy streams produce big
-ones, and the rate-weighted split keeps the pods balanced either way.
+state on the device), and ``submit_frame`` enqueues frames into the same
+queue.  A flush processes streams in per-session-ordered *rounds* sharded
+across pods like any other work; within a round every session's frame is
+dispatched to its device step first (cached, incremental and full frames
+alike, keyframes included), then each is retired.  This is the
+content-dependent, variable-size task stream the asymmetric-scheduling
+literature targets: mostly-static streams produce tiny work items, busy
+streams produce big ones, and the rate-weighted split keeps the pods
+balanced either way.
 """
 
 from __future__ import annotations
@@ -65,7 +60,6 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
-import repro.plan as planlib
 from repro import obs
 from repro.core.engine import CapacityOverflow
 from repro.scheduling.dvfs import (GovernorDecision, binding_slo,
@@ -75,8 +69,7 @@ from repro.scheduling.energy import (EnergyAccount, parked_point,
                                      pod_operating_points)
 from repro.scheduling.hetero import (HeteroPodPlan, rate_weighted_split,
                                      replan_on_straggle, update_rates_ema)
-from repro.stream import (StreamConfig, StreamEngine, VideoDetector,
-                          level_windows_from_raw)
+from repro.stream import StreamConfig, StreamEngine, VideoDetector
 from .stats import (SCHEMA_VERSION, DecisionStats, EnergyPodStats,
                     EnergyStats, PodStats, ServiceStats, StreamStats,
                     TailStats)
@@ -241,10 +234,9 @@ class StreamSession:
 
     @property
     def plan_key(self) -> tuple[int, int] | None:
-        """The session's co-batching key: its shape bucket, i.e. the prefix
-        of every compiled ``CascadePlan.key`` its frames execute.  Sessions
-        sharing it share one compaction per round (None until the first
-        frame binds the bucket)."""
+        """The session's shape bucket, i.e. the prefix of every compiled
+        ``CascadePlan.key`` its frames execute (None until the first frame
+        binds the bucket); the fleet groups sessions by it."""
         return self.video.bucket_hw
 
     def submit_frame(self, frame) -> Request:
@@ -370,12 +362,11 @@ class DetectorService:
     # ------------------------------------------------------------- streams
     @property
     def stream_engine(self) -> StreamEngine:
-        """Shared packed incremental engine: every session's changed-tile
-        work items go through its one compaction per flush."""
+        """Shared stream engine: sessions on the same stream plan share
+        its one step program."""
         with self._lock:
             if self._stream_engine is None:
-                self._stream_engine = StreamEngine(
-                    self.detector, self.stream_config.max_changed_frac)
+                self._stream_engine = StreamEngine(self.detector)
             return self._stream_engine
 
     def open_stream(self, config: StreamConfig | None = None,
@@ -384,11 +375,9 @@ class DetectorService:
         — warmup swaps in a calibrated detector, and sessions bind the
         detector (and shared stream engine) at open time.
 
-        ``config`` tunes the session's tile/threshold/keyframe policy; the
-        incremental *budget* (``max_changed_frac``) is a property of the
-        shared engine and always comes from the service-level
-        ``stream_config`` (a per-session value here is ignored).  ``tier``
-        sets the session's SLO class (every frame inherits it)."""
+        ``config`` tunes the session's tile/threshold/keyframe policy
+        (default: the service-level ``stream_config``).  ``tier`` sets the
+        session's SLO class (every frame inherits it)."""
         self._check_tier(tier)
         with self._lock:
             sid = self._next_stream_id
@@ -751,104 +740,20 @@ class DetectorService:
 
     # ---------------------------------------------------------- stream run
     def _run_stream_shard(self, shard: list[Request]) -> None:
-        """Process one round of frames (<= 1 per session).
-
-        Host-planned sessions: plans every session's frame, then batches
-        the work *across* sessions: incremental frames of sessions sharing
-        a plan key go through one shared-compaction call on the packed
-        engine (grouped by the key, chopped to ``batch_sizes``), and frames
-        needing a full refresh go through ``detect_batch_raw`` together.
-        Device-resident sessions run every frame, keyframes and full
-        refreshes included, in their own device step.  Any failure or
-        overflow degrades per frame, never the whole round.
-        """
-        incr: list[tuple[Request, np.ndarray, object]] = []
-        full: list[tuple[Request, np.ndarray]] = []
+        """Process one round of frames (<= 1 per session): submit every
+        session's frame to its device step, then retire each.  jax
+        dispatch is async, so every session's step is queued on the device
+        before the host waits on the first.  A failure fails its own
+        frame, never the whole round."""
         dev: list[tuple[Request, object]] = []
         for fr in shard:
-            video = fr.session.video
-            if video.config.device_state:
-                # submit first: jax dispatch is async, so every device
-                # session's plan-and-eval step runs while the host plans
-                # and packs the host-resident sessions below
-                try:
-                    dev.append((fr, video.submit(fr.image)))
-                except Exception as e:         # noqa: BLE001
-                    self._complete(fr, e)
-                continue
             try:
-                frame, plan = video.plan_frame(fr.image)
+                dev.append((fr, fr.session.video.submit(fr.image)))
             except Exception as e:             # noqa: BLE001
                 self._complete(fr, e)
-                continue
-            if plan.mode == "cached":
-                rects, stats = video.commit_cached(frame, plan)
-                self._complete(fr, rects, stats)
-            elif plan.mode == "full":
-                full.append((fr, frame))
-            else:
-                incr.append((fr, frame, plan))
-
-        # ---- changed-tile work items: all sessions sharing a plan key
-        # funnel through ONE compaction per chunk (cross-tenant batching)
-        buckets: dict[tuple[int, int], list] = {}
-        for item in incr:
-            buckets.setdefault(item[0].session.plan_key, []).append(item)
-        for (hp, wp), items in buckets.items():
-            for chunk in self._chunks(items):
-                frames = [frame for (_fr, frame, _plan) in chunk]
-                masks = [plan.masks for (_fr, _frame, plan) in chunk]
-                # union of the sessions' active level sets: the chunk shares
-                # one level-subset program, and fully-cached levels across
-                # every stream in the chunk build no SAT at all
-                active = tuple(sorted({
-                    li for (_fr, _frame, plan) in chunk
-                    for li in (plan.active_levels or ())}))
-                try:
-                    bitmaps, _rec, overflow = self.stream_engine.incremental(
-                        frames, masks, hp, wp, active=active)
-                except Exception as e:         # noqa: BLE001
-                    for fr, _frame, _plan in chunk:
-                        self._complete(fr, e)
-                    continue
-                if overflow:   # shared capacity blown: full-refresh chunk
-                    full.extend((fr, frame) for (fr, frame, _plan) in chunk)
-                    continue
-                for (fr, frame, plan), bm in zip(chunk, bitmaps):
-                    rects, stats = fr.session.video.commit_incremental(
-                        frame, plan, bm)
-                    self._complete(fr, rects, stats)
-
-        # ---- device-resident sessions, dispatched up-front: collect each
-        # step's verdict and finish the frame off the committed state
         for fr, tok in dev:
             try:
                 rects, stats = fr.session.video.retire(tok)
-                self._complete(fr, rects, stats)
-            except Exception as e:             # noqa: BLE001
-                self._complete(fr, e)
-
-        # ---- keyframes / refreshes, batched through the raw batch path
-        buckets = {}
-        for item in full:
-            buckets.setdefault(item[0].session.plan_key, []).append(item)
-        for _hw, items in buckets.items():
-            for chunk in self._chunks(items):
-                self._run_full_chunk(chunk)
-
-    def _run_full_chunk(self, chunk: list[tuple]) -> None:
-        levels = None
-        if len(chunk) > 1:
-            try:
-                levels = self.detector.detect_batch_raw(
-                    [frame for _fr, frame in chunk])
-            except CapacityOverflow:
-                levels = None                  # isolate per frame below
-        for i, (fr, frame) in enumerate(chunk):
-            try:
-                wins = (level_windows_from_raw(levels, i)
-                        if levels is not None else None)
-                rects, stats = fr.session.video.commit_full(frame, wins)
                 self._complete(fr, rects, stats)
             except Exception as e:             # noqa: BLE001
                 self._complete(fr, e)

@@ -28,13 +28,13 @@ Three mechanisms:
   restores levels with hysteresis (``restore_margin``) so the fleet does
   not flap around the threshold.
 
-- **Tier-ordered flushing + plan-key co-batching** — ``flush()`` runs one
+- **Tier-ordered flushing + plan-key groups** — ``flush()`` runs one
   service flush per SLO tier, realtime first, so each tier's flush plans
   against *its* deadline (the governor's binding SLO) instead of every
-  frame inheriting the strictest tenant's.  Within a flush, sessions
-  sharing a plan key (shape bucket) already funnel through one shared
-  compaction in the service; the fleet surfaces the live key-group count
-  (``plan_groups``) as the co-batching observability hook.
+  frame inheriting the strictest tenant's.  The fleet surfaces the live
+  count of plan keys (shape buckets) as ``plan_groups``: sessions sharing
+  one run the same stream step program, the grouping that co-batching
+  streams on the device would use.
 """
 
 from __future__ import annotations

@@ -1,34 +1,22 @@
-"""Incremental cascade evaluation over changed windows: two executors.
+"""The device-resident stream step: incremental cascade evaluation over
+changed windows.
 
-**Device-resident streams** (:meth:`StreamEngine.stream_step`, the
-``StreamConfig.device_state`` path): one jitted step per (stream plan,
-exactness) scores the frame's tiles against the reference, maps changed
-tiles to changed windows per pyramid level, decides cached / incremental /
-full, and evaluates every window to recompute — the changed windows, or
-every live window on a keyframe or a full refresh — through one head call
-per level whose initial alive mask is that level's map: the masked fused
-head (:func:`repro.kernels.ops.fused_head` with ``live``), whose tiles
-without a live window stop after one mask check.  There is no packed tail
-on this path, no compacted window list, so no capacity and no rung: the
-program's shape does not depend on how much changed, and nothing on the
-device path gathers SAT corners.  Levels with nothing to evaluate are
-skipped by ``lax.cond``.  Survivors merge into the donated state's bitmap
-(``(bitmap & ~recomputed) | survivors``).
+:meth:`StreamEngine.stream_step` builds one jitted step per (stream plan,
+exactness, refresh limits).  It scores the frame's tiles against the
+reference, maps changed tiles to changed windows per pyramid level,
+decides cached / incremental / full, and evaluates every window to
+recompute — the changed windows, or every live window on a keyframe or a
+full refresh — through one head call per level whose initial alive mask
+is that level's map: the masked fused head
+(:func:`repro.kernels.ops.fused_head` with ``live``), whose tiles without
+a live window stop after one mask check.  There is no packed tail, no
+compacted window list, so no capacity and no rung: the program's shape
+does not depend on how much changed, and nothing gathers SAT corners.
+Levels with nothing to evaluate are skipped by ``lax.cond``.  Survivors
+merge into the donated state's bitmap (``(bitmap & ~recomputed) |
+survivors``).
 
-**Host-planned streams** (:meth:`StreamEngine.incremental`): this is
-``Detector._build_batch_fn``'s shared-compaction tail with the dense-wave
-head cut off — the initial alive set is "every window whose tile content
-changed" (computed on host by :mod:`repro.stream.tiles`).  Changed windows
-from every frame in the stack and every pyramid level are compacted into
-one shared window list and run through *all* cascade stages by the shared
-packed-tail evaluator (:mod:`repro.kernels.packed_tail`), bit-identical
-per window to the baseline engine's tail.  One jitted program per
-:class:`repro.plan.CascadePlan` (bucket shape, batch size, capacity rung —
-the smallest power of two holding the flush's changed count — and active
-level subset: levels whose windows are all cached build no SAT).
-Concurrent streams' changed-tile work items share the single compaction.
-
-Both read int32 summed-area tables (:mod:`repro.core.integral`), so a
+It reads int32 summed-area tables (:mod:`repro.core.integral`), so a
 window's decision depends on the pixels under it alone and a cached
 decision is exact at threshold 0.
 """
@@ -46,47 +34,12 @@ from repro.core.engine import Detector
 from repro.core.features import run_sums_grid
 from repro.core.integral import integral_images, window_inv_sigma_grid
 from repro.core.pyramid import downscale_indices
-from repro.kernels import packed_tail
 from repro.kernels.autotune import DEFAULT_TILE
 from repro.kernels.tile_change import (tile_change_mask_kernel,
                                        changed_window_map_kernel)
-from repro.plan import (STREAM_CAP_BASE, LevelSubset,  # noqa: F401
-                        StreamGeometry, compile_plan, compile_stream_plan,
-                        stream_budget, stream_capacity_rung)
+from repro.plan import StreamGeometry, compile_plan, compile_stream_plan
 
-__all__ = ["StreamGeometry", "StreamEngine", "LevelSubset", "StreamState",
-           "StreamStepOut"]
-
-_AREA = float(WINDOW * WINDOW)
-
-
-def _packed_inv_sigma(pair_flat: jax.Array, img: jax.Array, base: jax.Array,
-                      stride: jax.Array, ys: jax.Array, xs: jax.Array
-                      ) -> jax.Array:
-    """1/sigma for packed windows living on different images and levels.
-
-    ``pair_flat`` is (B, 2, sum_l (h_l+1)*(w_l+1)) — the stacked
-    (ii2, iic) pair of every level, flattened and concatenated.  Same
-    corner order and variance identity as
-    :func:`repro.core.integral.window_inv_sigma`, bit-for-bit, only the
-    lookup goes through the packed (img, base + y*stride + x) indexing —
-    dense per-grid normalization would be wasted work when only a small
-    changed subset of windows is evaluated.
-    """
-
-    def rect(tab, y0, x0):
-        y1, x1 = y0 + WINDOW, x0 + WINDOW
-        return (pair_flat[img, tab, base + y1 * stride + x1]
-                - pair_flat[img, tab, base + y0 * stride + x1]
-                - pair_flat[img, tab, base + y1 * stride + x0]
-                + pair_flat[img, tab, base + y0 * stride + x0]
-                ).astype(jnp.float32)        # int32 corners, exact
-
-    s2 = rect(0, ys, xs)
-    s1 = rect(1, ys, xs)
-    var = s2 / _AREA - (s1 / _AREA) ** 2
-    sigma = jnp.sqrt(jnp.maximum(var, 1.0))
-    return 1.0 / sigma
+__all__ = ["StreamGeometry", "StreamEngine", "StreamState", "StreamStepOut"]
 
 
 class StreamState(NamedTuple):
@@ -136,124 +89,24 @@ class StreamStepOut(NamedTuple):
 
 
 class StreamEngine:
-    """Jitted incremental evaluators over a :class:`Detector`'s cascade."""
+    """The jitted device stream steps over a :class:`Detector`'s cascade,
+    one per (stream plan, exactness, refresh limits)."""
 
-    def __init__(self, detector: Detector, max_changed_frac: float = 0.5):
+    def __init__(self, detector: Detector):
         self.detector = detector
-        self.max_changed_frac = max_changed_frac
         self._geos: dict[tuple[int, int], StreamGeometry] = {}
         self._fns: dict[tuple, object] = {}
-        # head-work accounting: how many per-level SAT builds the subset
-        # programs actually ran vs the all-level layout's total (tests and
-        # benchmarks assert fully-cached levels build no SAT from these)
-        self.sat_level_builds = 0
-        self.sat_level_total = 0
-        self.dispatches = 0
         self.program_builds = 0          # executor builds (plan-cache probe)
         self.head_tile = tuple(DEFAULT_TILE)   # the device step's head tile
         # weak classifiers per stage: the head-work counters' unit
         # repro: ignore[HOST_SYNC] host constant of the cascade, read once at construction
         self.n_weak = np.diff(np.asarray(detector.cascade.stage_offsets))
 
-    @property
-    def sat_level_frac(self) -> float:
-        """Fraction of pyramid levels whose SAT was built, over all
-        incremental dispatches (1.0 = the old all-level behaviour)."""
-        return self.sat_level_builds / max(self.sat_level_total, 1)
-
     def geometry(self, hp: int, wp: int) -> StreamGeometry:
         key = (hp, wp)
         if key not in self._geos:
             self._geos[key] = StreamGeometry(self.detector, hp, wp)
         return self._geos[key]
-
-    def cap_budget(self, geo: StreamGeometry, batch: int) -> int:
-        """Most changed windows a flush may evaluate incrementally; beyond
-        it a full refresh is cheaper anyway (the caller's fallback)."""
-        return stream_budget(geo.n_slots, batch, self.max_changed_frac)
-
-    def _cap_for(self, n_sub_slots: int, batch: int, n_changed: int) -> int:
-        """Smallest ladder rung holding ``n_changed`` packed windows, capped
-        at the active subset's own slot count (the plan layer's ladder)."""
-        return stream_capacity_rung(n_sub_slots, batch, n_changed)
-
-    # ------------------------------------------------------------- build
-    def _build_fn(self, plan):
-        """Thin executor over a stream-shaped :class:`repro.plan
-        .CascadePlan`: SATs are built (and the flat slot layout laid out)
-        over only the plan's active levels — fully cached levels cost
-        nothing, not even their SAT pass.  The whole incremental tail is
-        the plan's single all-stage segment; its capacity is the rung and
-        its backend is the plan's decision off the crossover ladder."""
-        det = self.detector
-        hp, wp = plan.hp, plan.wp
-        batch = plan.batch
-        seg = plan.segments[0]
-        cap, backend = seg.capacity, seg.backend
-        n_slots = plan.n_slots
-        cascade_static = det.cascade
-        self.program_builds += 1
-        layout = plan.layout
-        lvl_of_slot = jnp.asarray(layout.lvl_of_slot)
-        y_of_slot = jnp.asarray(layout.y_of_slot)
-        x_of_slot = jnp.asarray(layout.x_of_slot)
-        sat_base_of_lvl = jnp.asarray(layout.sat_base_of_lvl)
-        sat_stride_of_lvl = jnp.asarray(layout.sat_stride_of_lvl)
-
-        def frame_fn(cascade: Cascade, stack: jax.Array,
-                     mask_flat: jax.Array):
-            # stack: (B, hp, wp) f32 frames; mask_flat: (B, n_slots) bool of
-            # windows to recompute (already limit-masked on host), laid out
-            # over the active subset's slots only.
-            sat_parts, pair_parts = [], []
-            for lp in plan.levels:
-                ys_idx = downscale_indices(hp, lp.height)
-                xs_idx = downscale_indices(wp, lp.width)
-                img_l = stack[:, ys_idx[:, None], xs_idx[None, :]]
-                ii_l, pair_l = jax.vmap(integral_images)(img_l)
-                sat_parts.append(ii_l.reshape(batch, -1))
-                pair_parts.append(pair_l.reshape(batch, 2, -1))
-
-            alive_flat = mask_flat.reshape(-1)
-            ii_flat = jnp.concatenate(sat_parts, axis=1)
-            pair_flat = jnp.concatenate(pair_parts, axis=2)
-            recomputed = mask_flat.sum(axis=1).astype(jnp.int32)  # (B,)
-            overflow = alive_flat.sum() > cap
-            idx = jnp.nonzero(alive_flat, size=cap, fill_value=-1)[0]
-            sel = jnp.maximum(idx, 0)
-            valid = idx >= 0
-            b_sel = sel // n_slots
-            slot = sel % n_slots
-            lvl_sel = jnp.take(lvl_of_slot, slot)
-            y_sel = jnp.take(y_of_slot, slot)
-            x_sel = jnp.take(x_of_slot, slot)
-            base_sel = jnp.take(sat_base_of_lvl, lvl_sel)
-            stride_sel = jnp.take(sat_stride_of_lvl, lvl_sel)
-            inv_sel = _packed_inv_sigma(pair_flat, b_sel, base_sel,
-                                        stride_sel, y_sel, x_sel)
-            ss_run = packed_tail.stage_sums(
-                cascade, cascade_static, seg.s0, seg.s1, ii_flat, b_sel,
-                base_sel, stride_sel, y_sel, x_sel, inv_sel,
-                backend=backend, tile=plan.lane_block)
-            for j, s in enumerate(range(seg.s0, seg.s1)):
-                valid = valid & (ss_run[j] >= cascade.stage_threshold[s])
-            # scatter survivors back onto the full (B, n_slots) grid; dead
-            # and padding lanes target index B*n_slots which is dropped
-            target = jnp.where(valid, sel, batch * n_slots)
-            survivors = jnp.zeros(batch * n_slots, bool).at[target].set(
-                True, mode="drop")
-            return survivors.reshape(batch, n_slots), recomputed, overflow
-
-        return jax.jit(frame_fn)
-
-    def _fn(self, hp: int, wp: int, batch: int, cap: int,
-            levels: tuple[int, ...]):
-        det = self.detector
-        plan = compile_plan(det.config, det.n_stages, hp, wp, batch=batch,
-                            levels=levels, capacity=cap)
-        if plan.key not in self._fns:
-            self._fns[plan.key] = self._build_fn(plan)
-        return self._fns[plan.key]
 
     # ----------------------------------------------- device-resident state
     def stream_plan(self, hp: int, wp: int, h: int, w: int, tile: int,
@@ -440,65 +293,3 @@ class StreamEngine:
             return new_state, out
 
         return jax.jit(step, donate_argnums=(1,))
-
-    # -------------------------------------------------------------- run
-    def incremental(self, frames: list[np.ndarray],
-                    masks_per_frame: list[list[np.ndarray]],
-                    hp: int, wp: int,
-                    active: tuple[int, ...] | None = None
-                    ) -> tuple[list[np.ndarray], np.ndarray, bool]:
-        """Evaluate changed windows of a same-bucket stack of frames.
-
-        ``masks_per_frame[i]`` is one flat bool mask per pyramid level for
-        frame ``i``.  The dispatch compiles (and runs) a *level-subset*
-        program keyed on the plan for the set of levels with any changed
-        window across the stack; ``active`` optionally widens that set
-        (e.g. the serving layer passes the union of its sessions'
-        ``FramePlan.active_levels`` so one chunk shares one program).
-        Returns ``(survivor bitmaps per frame (flat n_slots),
-        recomputed-window counts, overflow)`` — on overflow (more changed
-        windows than ``cap_budget``) nothing is dispatched and the caller
-        must fall back to a full refresh.
-        """
-        geo = self.geometry(hp, wp)
-        batch = len(frames)
-        n_levels = len(geo.plan)
-        mask_flat = np.stack([np.concatenate(masks_per_frame[i])
-                              for i in range(batch)])
-        counts = mask_flat.sum(axis=1).astype(np.int32)
-        n_changed = int(counts.sum())
-        if n_changed > self.cap_budget(geo, batch):
-            return [], counts, True
-        # active level subset = union over the stack of levels with any
-        # changed window (plus the caller's widening hint)
-        changed_lv = {li for li in range(n_levels)
-                      if mask_flat[:, geo.slot_offsets[li]:
-                                   geo.slot_offsets[li + 1]].any()}
-        if active is not None:
-            changed_lv |= set(active)
-        levels = tuple(sorted(changed_lv))
-        self.dispatches += 1
-        self.sat_level_builds += len(levels)
-        self.sat_level_total += n_levels
-        if not levels:          # nothing changed anywhere: no program at all
-            return ([np.zeros(geo.n_slots, bool) for _ in range(batch)],
-                    counts, False)
-        sub = geo.subset(levels)
-        mask_sub = mask_flat[:, sub.slot_indices]
-        cap = self._cap_for(sub.n_slots, batch, n_changed)
-        stack = np.zeros((batch, hp, wp), np.float32)
-        for i, f in enumerate(frames):
-            h, w = f.shape
-            stack[i, :h, :w] = f
-        out, recomputed, overflow = self._fn(hp, wp, batch, cap, levels)(
-            self.detector.cascade, jnp.asarray(stack),
-            jnp.asarray(mask_sub))
-        # repro: ignore[HOST_SYNC] host-path contract: the host-resident caches merge survivor bitmaps here (the device-resident path avoids this sync)
-        sub_bitmaps = np.asarray(out)
-        bitmaps = []
-        for i in range(batch):  # scatter subset survivors into full layout
-            full = np.zeros(geo.n_slots, bool)
-            full[sub.slot_indices] = sub_bitmaps[i]
-            bitmaps.append(full)
-        # repro: ignore[HOST_SYNC] host-path contract: recompute counts and the overflow flag gate the caller's full-refresh fallback
-        return (bitmaps, np.asarray(recomputed), bool(np.asarray(overflow)))

@@ -1,19 +1,24 @@
 """Streaming video detection with temporal tile-reuse.
 
 :class:`VideoDetector` wraps a calibrated :class:`repro.core.Detector` for
-one video stream.  Per frame it:
+one video stream whose temporal state lives on the device.  Per frame, one
+jitted step (:meth:`repro.stream.StreamEngine.stream_step`):
 
 1. scores each tile of the frame against the stream's *reference frame*
    (the pixels the cached decisions were computed on — not simply the
    previous frame, so sub-threshold drift never compounds silently);
 2. maps changed tiles (plus a dilated halo) to the exact set of detection
-   windows whose receptive field they overlap, per pyramid level; the
-   levels with any changed window form the frame's *active level subset*
-   (``FramePlan.active_levels``);
-3. re-evaluates only those windows through the packed incremental engine
-   (:class:`repro.stream.StreamEngine`), which compiles a level-subset
-   program: fully-cached levels build no SAT at all.  Survivors merge
-   into the cached per-level bitmaps; everything else is reused.
+   windows whose receptive field they overlap, per pyramid level;
+3. decides cached / incremental / full and evaluates every window to
+   recompute — the changed windows, or every live window on a keyframe or
+   a full refresh — merging the survivors into the cached bitmap.
+
+The host uploads the frame, fetches the step's scalar verdict and, on
+frames that evaluated windows, the decoded survivor slots, then groups
+rects.  ``submit`` dispatches a frame's step and ``retire`` finishes it
+(``poll`` + ``commit_token``); ``process`` composes them.  jax dispatch is
+async and the donated state chains from step to step, so a caller may
+submit frame N+1 before retiring frame N.
 
 Exactness: with ``threshold <= 0`` a tile is "changed" iff any pixel
 differs, so the cache always reflects the current frame's pixels exactly
@@ -21,17 +26,9 @@ and the output is **bit-identical** to running ``Detector.detect`` on
 every frame (same windows, same order, same grouping).  With a positive
 threshold, cached decisions may lag the true frame by at most the
 per-tile score threshold; a periodic keyframe (``keyframe_interval``)
-re-detects the whole frame and bounds the staleness window.
-
-Fallbacks keep the fast path honest: if the changed-window fraction
-exceeds ``full_refresh_frac``, or the packed list overflows its static
-capacity, the frame is re-detected in full (same result, no drift).
-
-The plan/commit split (``plan_frame`` / ``commit_*``) exists so the
-serving layer can batch work *across* streams: many sessions' changed
-windows share one packed compaction, and many sessions' keyframes share
-one ``detect_batch`` flush.  ``process`` composes the two for the
-single-stream case.
+re-detects the whole frame and bounds the staleness window.  A frame
+whose changed-window fraction exceeds ``full_refresh_frac`` is re-detected
+in full in the same step.
 """
 
 from __future__ import annotations
@@ -47,39 +44,29 @@ from repro import obs
 from repro.core.engine import Detector
 from repro.core import nms
 from .engine import StreamEngine, StreamGeometry
-from .tiles import (tile_grid_shape, tile_change_scores, dilate_tiles,
-                    changed_window_mask)
 
-__all__ = ["StreamConfig", "FrameStats", "FramePlan", "VideoDetector",
+__all__ = ["StreamConfig", "FrameStats", "VideoDetector",
            "level_windows_from_raw"]
 
 _MODES = ("cached", "incremental", "full")
 _SIDS = itertools.count()
 
 
-def level_windows_from_raw(levels, index: int | None = None
-                           ) -> list[tuple[np.ndarray, np.ndarray]]:
-    """Surviving (ys, xs) per pyramid level from a raw detector pass.
-
-    ``levels`` is ``Detector.detect_raw`` output (``index=None``) or the
-    batched ``detect_batch_raw`` output (``index`` = image position); the
-    single decode/overflow policy for every keyframe path, single-stream
-    and service-batched alike."""
+def level_windows_from_raw(levels) -> list[tuple[np.ndarray, np.ndarray]]:
+    """Surviving (ys, xs) per pyramid level from ``Detector.detect_raw``
+    output: the host detect a frame falls back to when its survivors
+    overflow the step's slot list."""
     wins = []
     for res, _scale in levels:
-        # repro: ignore[HOST_SYNC] keyframe decode: raw survivor arrays are this path's output
-        over = np.asarray(res.overflow)
-        if bool(over if index is None else over[index]):
+        # repro: ignore[HOST_SYNC] fallback decode: raw survivor arrays are this path's output
+        if bool(np.asarray(res.overflow)):
             raise RuntimeError(
-                "wave-engine capacity overflow on stream keyframe; raise "
-                "capacity_fracs (see Detector.calibrated)")
-        # repro: ignore[HOST_SYNC] keyframe decode: raw survivor arrays are this path's output
-        ys = np.asarray(res.ys if index is None else res.ys[index])
-        # repro: ignore[HOST_SYNC] keyframe decode: raw survivor arrays are this path's output
-        xs = np.asarray(res.xs if index is None else res.xs[index])
-        # repro: ignore[HOST_SYNC] keyframe decode: raw survivor arrays are this path's output
-        val = np.asarray(res.valid if index is None else res.valid[index])
-        wins.append((ys[val], xs[val]))
+                "wave-engine capacity overflow on a stream frame's host "
+                "detect; raise capacity_fracs (see Detector.calibrated)")
+        # repro: ignore[HOST_SYNC] fallback decode: raw survivor arrays are this path's output
+        val = np.asarray(res.valid)
+        # repro: ignore[HOST_SYNC] fallback decode: raw survivor arrays are this path's output
+        wins.append((np.asarray(res.ys)[val], np.asarray(res.xs)[val]))
     return wins
 
 
@@ -88,7 +75,6 @@ class StreamConfig(NamedTuple):
     threshold: float = 0.0         # mean-sq change per pixel; <=0 = exact
     halo: int = 1                  # dilation rings around changed tiles
     keyframe_interval: int = 64    # full re-detect cadence; 0 = never
-    max_changed_frac: float = 0.5  # incremental budget as a window fraction
     full_refresh_frac: float = 0.5  # changed-window frac forcing full detect
     # ---- graceful-degradation knobs (fleet serving under overload).
     # degraded(level) stretches the keyframe cadence and raises the change
@@ -100,18 +86,9 @@ class StreamConfig(NamedTuple):
     #                                      keyframe stretch only, keeps
     #                                      threshold-0 streams bit-exact)
     max_degrade_level: int = 3
-    # ---- device-resident state.  True moves the reference frame, survivor
-    # bitmap and frame counters onto the device as a donated pytree: per
-    # frame, change scoring, window mapping, the cached/incremental/full
-    # decision and the evaluation of every window to recompute — changed
-    # windows, or the whole frame on a keyframe or full refresh — run in
-    # one jitted step, one program per stream shape and exactness.  The
-    # host uploads the new frame and fetches a handful of scalars plus the
-    # survivor slot list.  Frames go through submit/retire (process
-    # composes them); at threshold<=0 the output stays bit-identical to
-    # the host-planned path and to per-frame Detector.detect.
-    # max_changed_frac does not apply here: the step has no capacity.
-    device_state: bool = False
+    # streams are always device-resident; the field remains only because
+    # the benchmark's drivers pass device_state=True, and False is refused
+    device_state: bool = True
 
     def degraded(self, level: int) -> "StreamConfig":
         """The stretched config at degradation ``level`` (0 = this config).
@@ -132,6 +109,12 @@ class StreamConfig(NamedTuple):
         return self._replace(keyframe_interval=kf, threshold=thr)
 
 
+def _check_device_state(config: StreamConfig) -> None:
+    if not config.device_state:
+        raise ValueError("StreamConfig.device_state=False is refused: "
+                         "streams are always device-resident")
+
+
 class FrameStats(NamedTuple):
     frame_idx: int
     mode: str                      # 'full' | 'incremental' | 'cached'
@@ -140,7 +123,7 @@ class FrameStats(NamedTuple):
     windows_total: int             # live (limit-valid) windows, all levels
     windows_recomputed: int
     levels_total: int = 0          # pyramid levels in the bucket's plan
-    levels_active: int = 0         # levels whose SAT/head ran this frame
+    levels_active: int = 0         # levels whose head ran this frame
 
     @property
     def tile_skip_frac(self) -> float:
@@ -152,21 +135,9 @@ class FrameStats(NamedTuple):
 
     @property
     def level_skip_frac(self) -> float:
-        """Fraction of pyramid levels whose dense-wave/SAT head was skipped
-        (fully cached) this frame."""
+        """Fraction of pyramid levels whose head was skipped (fully
+        cached) this frame."""
         return 1.0 - self.levels_active / max(self.levels_total, 1)
-
-
-class FramePlan(NamedTuple):
-    mode: str                      # 'full' | 'incremental' | 'cached'
-    masks: list | None             # per-level flat recompute masks
-    changed_tiles: np.ndarray | None   # dilated tile mask
-    tiles_changed: int
-    windows_to_recompute: int
-    active_levels: tuple[int, ...] | None = None   # levels with changed
-    #                                windows ('incremental' plans only; the
-    #                                incremental engine builds SATs for
-    #                                exactly this subset)
 
 
 class _DevToken:
@@ -186,33 +157,27 @@ class _DevToken:
 
 
 class VideoDetector:
-    """One stream's temporal state over a shared :class:`Detector`."""
+    """One stream's device-resident temporal state over a shared
+    :class:`Detector`."""
 
     def __init__(self, detector: Detector, config: StreamConfig = StreamConfig(),
                  engine: StreamEngine | None = None, *,
                  decode_cap: int | None = None):
+        _check_device_state(config)
         self.detector = detector
         self.config = config
-        self.engine = engine or StreamEngine(detector,
-                                             config.max_changed_frac)
+        self.engine = engine or StreamEngine(detector)
         self._shape: tuple[int, int] | None = None
         self._geo: StreamGeometry | None = None
-        self._limits: list[tuple[int, int]] = []
         self._n_live = 0
-        self._tile_grid: tuple[int, int] = (0, 0)
         self._tiles_total = 0
         self._scales: np.ndarray | None = None
-        self._ref: np.ndarray | None = None         # reference pixels
-        self._bitmap: np.ndarray | None = None      # flat survivor cache
         self._rects: np.ndarray | None = None       # cached grouped output
         self._frame_idx = 0
-        self._last_full = -1
-        # ---- device-resident state (config.device_state)
         self._decode_cap = decode_cap     # override for the slot-list size
         self._splan = None                # StreamStatePlan, built at open
         self._dev_state = None            # donated StreamState pytree
         self._pending: deque[_DevToken] = deque()   # in-flight frames, FIFO
-        self._last_mode = "full"          # last committed frame's mode
         self.xfer_bytes = 0               # host<->device traffic accounting
         self.slot_builds = 0              # steps that rebuilt the slot list
         self.sid = next(_SIDS)            # this stream's id in its spans
@@ -228,27 +193,21 @@ class VideoDetector:
 
     def _init_stream(self, frame: np.ndarray) -> None:
         h, w = frame.shape
+        tile = self.config.tile
         self._shape = (h, w)
         hp, wp = self.detector._bucket_hw(h, w)
         self._geo = self.engine.geometry(hp, wp)
-        self._limits = self._geo.limits(h, w)
-        self._n_live = 0
-        for (ny, nx), (y_lim, x_lim) in zip(self._geo.level_windows,
-                                            self._limits):
-            n_y = min(int(y_lim) // self._geo.step + 1, ny) if y_lim >= 0 else 0
-            n_x = min(int(x_lim) // self._geo.step + 1, nx) if x_lim >= 0 else 0
-            self._n_live += n_y * n_x
-        # per-frame constants, computed once at open (not per _finish call)
-        ty, tx = tile_grid_shape(h, w, self.config.tile)
-        self._tile_grid = (ty, tx)
-        self._tiles_total = ty * tx
         # repro: ignore[HOST_SYNC] host constant from plan metadata, no device round-trip
         self._scales = np.asarray([lv.scale for lv in self._geo.plan]) \
             if self._geo.plan else np.zeros(0)
-        if self.config.device_state and self._geo.n_slots > 0:
+        if self._geo.n_slots > 0:
             self._splan = self.engine.stream_plan(
-                hp, wp, h, w, self.config.tile, self.config.halo,
+                hp, wp, h, w, tile, self.config.halo,
                 decode_cap=self._decode_cap)
+            self._n_live = self._splan.n_live
+            self._tiles_total = self._splan.ty * self._splan.tx
+        else:                      # frame smaller than the detection window
+            self._tiles_total = -(-h // tile) * -(-w // tile)
 
     def _check_frame(self, frame) -> np.ndarray:
         # repro: ignore[HOST_SYNC] frame intake: callers hand in host pixels
@@ -263,53 +222,8 @@ class VideoDetector:
                              f"{frame.shape}; open a new stream instead")
         return frame
 
-    # ------------------------------------------------------------ planning
-    def plan_frame(self, frame) -> tuple[np.ndarray, FramePlan]:
-        """Decide how to process ``frame``; returns (frame_f32, plan)."""
-        frame = self._check_frame(frame)
-        cfg = self.config
-        geo = self._geo
-        if self._splan is not None:
-            raise RuntimeError(
-                "device-resident stream: planning happens on device — use "
-                "submit/poll/commit_token (or process) instead of "
-                "plan_frame")
-        if self._ref is None:
-            return frame, FramePlan("full", None, None, 0, 0)
-        if geo.n_slots == 0:       # frame smaller than the detection window
-            return frame, FramePlan("cached", None, None, 0, 0)
-        due = (cfg.keyframe_interval > 0 and
-               self._frame_idx - self._last_full >= cfg.keyframe_interval)
-        if due:
-            return frame, FramePlan("full", None, None, 0, 0)
-        exact = cfg.threshold <= 0
-        scores, changed_any = tile_change_scores(self._ref, frame, cfg.tile,
-                                                 exact=exact)
-        changed = changed_any if exact else (scores > cfg.threshold)
-        changed = dilate_tiles(changed, cfg.halo)
-        n_changed = int(changed.sum())
-        if n_changed == 0:
-            return frame, FramePlan("cached", None, changed, 0, 0)
-        # tile fraction under-estimates the window fraction (receptive
-        # fields cover multiple tiles), so this is a safe early exit that
-        # skips per-level mask building when a refresh is certain anyway
-        if n_changed > cfg.full_refresh_frac * changed.size:
-            return frame, FramePlan("full", None, changed, n_changed, 0)
-        masks = [changed_window_mask(changed, cfg.tile, geo.hp, geo.wp,
-                                     lv, geo.step, y_lim, x_lim)
-                 for lv, (y_lim, x_lim) in zip(geo.plan, self._limits)]
-        n_rec = int(sum(int(m.sum()) for m in masks))
-        if n_rec > cfg.full_refresh_frac * max(self._n_live, 1):
-            return frame, FramePlan("full", None, changed, n_changed, n_rec)
-        active = tuple(li for li, m in enumerate(masks) if m.any())
-        return frame, FramePlan("incremental", masks, changed,
-                                n_changed, n_rec, active)
-
-    # ------------------------------------------------------------- commits
     def _decode_slots(self, idxs: np.ndarray) -> np.ndarray:
-        """Grouped rects from a list of surviving flat slot indices.
-
-        The single decode path for host bitmaps and device slot lists; the
+        """Grouped rects from a list of surviving flat slot indices; the
         returned array is marked read-only so cached frames can hand the
         same object back without a per-frame copy."""
         geo = self._geo
@@ -326,22 +240,6 @@ class VideoDetector:
         rects.setflags(write=False)
         return rects
 
-    def _decode(self) -> np.ndarray:
-        return self._decode_slots(np.nonzero(self._bitmap)[0])
-
-    def _finish(self, frame: np.ndarray, mode: str, tiles_changed: int,
-                recomputed: int, levels_active: int
-                ) -> tuple[np.ndarray, FrameStats]:
-        self._rects = self._decode() if mode != "cached" else self._rects
-        stats = FrameStats(self._frame_idx, mode, self._tiles_total,
-                           tiles_changed, self._n_live, recomputed,
-                           len(self._geo.plan), levels_active)
-        self._frame_idx += 1
-        self._last_mode = mode
-        # read-only (see _decode_slots): cached frames return the same
-        # array, copy-free — callers must not mutate it
-        return self._rects, stats
-
     def _slots_of(self, level_windows: list[tuple[np.ndarray, np.ndarray]]
                   ) -> np.ndarray:
         """Flat survivor slots of a raw detector pass's (ys, xs) per
@@ -352,71 +250,21 @@ class VideoDetector:
             if len(ys) == 0:
                 continue
             ny, nx = geo.level_windows[li]
-            # keyframe decode: the raw survivor coords are this path's input
-            ys = np.asarray(ys)  # repro: ignore[HOST_SYNC] keyframe decode input
-            xs = np.asarray(xs)  # repro: ignore[HOST_SYNC] keyframe decode input
+            # fallback decode: the raw survivor coords are this path's input
+            ys = np.asarray(ys)  # repro: ignore[HOST_SYNC] fallback decode input
+            xs = np.asarray(xs)  # repro: ignore[HOST_SYNC] fallback decode input
             parts.append(geo.slot_offsets[li] + (ys // geo.step) * nx
                          + xs // geo.step)
         return (np.sort(np.concatenate(parts)) if parts
                 else np.zeros(0, np.int64))
 
-    def commit_full(self, frame: np.ndarray,
-                    level_windows: list[tuple[np.ndarray, np.ndarray]] | None
-                    = None) -> tuple[np.ndarray, FrameStats]:
-        """Full re-detect of a host-planned stream: refresh every cached
-        decision from ``frame``.
-
-        ``level_windows`` (surviving (ys, xs) per pyramid level, as produced
-        by the detector's raw paths) lets the serving layer batch many
-        streams' keyframes through ``detect_batch_raw`` and feed each
-        session its slice; when omitted the detector runs directly.
-        """
-        geo = self._geo
-        if level_windows is None:
-            level_windows = level_windows_from_raw(
-                self.detector.detect_raw(frame))
-        # full-detect traffic: frame up, surviving window coords back down
-        self.xfer_bytes += frame.nbytes + sum(
-            ys.nbytes + xs.nbytes for ys, xs in level_windows)
-        bitmap = np.zeros(geo.n_slots, bool)
-        bitmap[self._slots_of(level_windows)] = True
-        self._bitmap = bitmap
-        self._ref = frame.copy()
-        self._last_full = self._frame_idx
-        return self._finish(frame, "full", self._tiles_total, self._n_live,
-                            len(geo.plan))
-
-    def commit_incremental(self, frame: np.ndarray, plan: FramePlan,
-                           survivors_flat: np.ndarray
-                           ) -> tuple[np.ndarray, FrameStats]:
-        """Merge recomputed survivors into the cache; update the reference
-        pixels under every recomputed tile."""
-        mask_flat = np.concatenate(plan.masks)
-        self._bitmap = (self._bitmap & ~mask_flat) | survivors_flat
-        h, w = self._shape
-        tile = self.config.tile
-        pix = np.repeat(np.repeat(plan.changed_tiles, tile, axis=0),
-                        tile, axis=1)[:h, :w]
-        self._ref = np.where(pix, frame, self._ref)
-        return self._finish(frame, "incremental", plan.tiles_changed,
-                            plan.windows_to_recompute,
-                            len(plan.active_levels or ()))
-
-    def commit_cached(self, frame: np.ndarray,
-                      plan: FramePlan) -> tuple[np.ndarray, FrameStats]:
-        return self._finish(frame, "cached", plan.tiles_changed, 0, 0)
-
-    # ------------------------------------------- device-resident fast path
+    # ------------------------------------------------------- frame lifecycle
     def submit(self, frame) -> _DevToken:
         """Queue ``frame`` on the device-resident stream, dispatch its
         plan-and-eval step and return its token.  jax dispatch is async
         and every step commits (the donated state chains from one frame's
         step to the next), so frame N+1's step runs while the host decodes
         frame N.  Tokens must be retired in submit order."""
-        if not self.config.device_state:
-            raise RuntimeError(
-                "submit/retire need StreamConfig.device_state=True; use "
-                "process/plan_frame on host-planned streams")
         frame = self._check_frame(frame)
         tok = _DevToken(frame)
         if self._splan is not None:
@@ -489,8 +337,7 @@ class VideoDetector:
 
     def commit_token(self, tok: _DevToken) -> tuple[np.ndarray, FrameStats]:
         """Finish a polled token: fetch the decoded survivor slots (frames
-        the step evaluated), group rects, and mirror the host path's
-        stats and engine counters."""
+        the step evaluated), group rects, and report the frame's stats."""
         mode = _MODES[tok.flags[0]]
         n_tiles, n_rec, lvls, n_surv = tok.flags[1:5]
         self._pending.popleft()
@@ -512,10 +359,6 @@ class VideoDetector:
             mode = "full"
             n_tiles, n_rec, lvls = self._tiles_total, self._n_live, n_levels
         elif mode != "cached":
-            if mode == "incremental":
-                self.engine.dispatches += 1
-                self.engine.sat_level_builds += lvls
-                self.engine.sat_level_total += n_levels
             with obs.span("stream.fetch") as attrs:
                 # repro: ignore[HOST_SYNC] contract sync: decoded survivor slots are the frame's output
                 slots = np.asarray(jax.device_get(tok.out.slots))[:n_surv]
@@ -525,7 +368,8 @@ class VideoDetector:
         stats = FrameStats(self._frame_idx, mode, self._tiles_total,
                            n_tiles, self._n_live, n_rec, n_levels, lvls)
         self._frame_idx += 1
-        self._last_mode = mode
+        # read-only (see _decode_slots): cached frames return the same
+        # array, copy-free — callers must not mutate it
         return self._rects, stats
 
     def retire(self, tok: _DevToken) -> tuple[np.ndarray, FrameStats]:
@@ -537,7 +381,7 @@ class VideoDetector:
         """Swap the stream's threshold/keyframe policy mid-stream without
         dropping temporal state — the serving layer's degradation path
         (``config.degraded(level)``).  ``tile`` and ``halo`` must not
-        change: the cached bitmaps stay valid under any threshold/cadence,
+        change: the cached bitmap stays valid under any threshold/cadence,
         but the change-detection granularity is part of the stream's
         conservative-mapping contract and is fixed at open time."""
         if (config.tile, config.halo) != (self.config.tile, self.config.halo):
@@ -545,10 +389,7 @@ class VideoDetector:
                 f"tile/halo are fixed per stream: "
                 f"{(self.config.tile, self.config.halo)} -> "
                 f"{(config.tile, config.halo)}; open a new stream instead")
-        if config.device_state != self.config.device_state:
-            raise ValueError(
-                "device_state is fixed per stream (the temporal state "
-                "lives on one side); open a new stream instead")
+        _check_device_state(config)
         self.config = config
 
     # -------------------------------------------------------------- public
@@ -559,35 +400,10 @@ class VideoDetector:
         ``Detector.detect`` would format them (the array is read-only and
         shared across cached frames — copy before mutating).
         """
-        if self.config.device_state:
-            return self.retire(self.submit(frame))
-        frame, plan = self.plan_frame(frame)
-        return self.commit_planned(frame, plan)
-
-    def commit_planned(self, frame: np.ndarray, plan: FramePlan
-                       ) -> tuple[np.ndarray, FrameStats]:
-        """Execute a host-planned frame: the commit half of ``process``
-        (benchmarks time the plan/commit phases through this split)."""
-        if plan.mode == "cached":
-            return self.commit_cached(frame, plan)
-        if plan.mode == "full":
-            return self.commit_full(frame)
-        geo = self._geo
-        bitmaps, _rec, overflow = self.engine.incremental(
-            [frame], [plan.masks], geo.hp, geo.wp,
-            active=plan.active_levels)
-        # frame stack up; recompute masks up, survivor bitmap back down
-        self.xfer_bytes += geo.hp * geo.wp * 4 + 2 * geo.n_slots
-        if overflow:   # too many changed windows for the packed capacity
-            return self.commit_full(frame)
-        return self.commit_incremental(frame, plan, bitmaps[0])
+        return self.retire(self.submit(frame))
 
     def reset(self) -> None:
         """Drop all temporal state (next frame is a keyframe)."""
-        self._ref = None
-        self._bitmap = None
         self._rects = None
-        self._last_full = -1
         self._dev_state = None
         self._pending.clear()
-        self._last_mode = "full"

@@ -8,8 +8,10 @@ plan→compile→execute contract.
 - the plan's segments, capacity ladders, and slot/SAT layout must equal
   the legacy builders' inline derivations (the formulas the engines used
   to recompute independently);
-- the per-segment / per-rung tail backend is the plan's decision off the
+- the per-segment tail backend is the plan's decision off the
   ``tail_rungs`` crossover ladder, and executors consume it as compiled;
+- the device stream step's plan (``compile_stream_plan``) is cached on its
+  identity and its tile ranges bracket every window's receptive field;
 - plan-built executors stay bit-identical across strategies and the
   threshold-0 streaming path (the cross-checks the equivalence suites in
   ``test_engine_batch`` / ``test_stream`` enforce corpus-wide).
@@ -41,17 +43,6 @@ def test_compile_plan_is_cached():
     assert planlib.compile_level_plan(CFG, N_STAGES, 64, 64) is lp
 
 
-def test_plan_key_distinguishes_subset_and_capacity():
-    full = planlib.compile_plan(CFG, N_STAGES, 64, 64)
-    sub = planlib.compile_plan(CFG, N_STAGES, 64, 64, levels=(0, 2))
-    rung = planlib.compile_plan(CFG, N_STAGES, 64, 64, levels=(0, 2),
-                                capacity=512)
-    assert len({full.key, sub.key, rung.key}) == 3
-    assert sub.layout.n_slots < full.layout.n_slots
-    assert rung.segments == (planlib.SegmentPlan(
-        0, N_STAGES, False, 512, planlib.select_backend(CFG, 512)),)
-
-
 def test_detect_paths_never_rebuild_programs():
     """Repeated detect / detect_batch (both strategies) on the same bucket:
     zero program rebuilds after the first call."""
@@ -72,19 +63,78 @@ def test_detect_paths_never_rebuild_programs():
 
 def test_stream_never_rebuilds_programs():
     det = Detector(CASC, CFG)
-    engine = StreamEngine(det, 0.5)
+    engine = StreamEngine(det)
     video = make_video("moving_face", n_frames=4, h=64, w=64, seed=2)
     vd = VideoDetector(det, StreamConfig(tile=16, threshold=0.0,
                                          keyframe_interval=0), engine=engine)
     for f, _gt in video:
         vd.process(f)
     builds = (det.program_builds, engine.program_builds)
+    assert engine.program_builds == 1
     vd2 = VideoDetector(det, StreamConfig(tile=16, threshold=0.0,
                                           keyframe_interval=0),
                         engine=engine)
-    for f, _gt in video:
-        vd2.process(f)
+    got = [vd2.process(f)[0] for f, _gt in video]
     assert (det.program_builds, engine.program_builds) == builds
+    for rects, (f, _gt) in zip(got, video):
+        assert np.array_equal(rects, det.detect(f))
+
+
+def test_stream_plan_keys_and_cache():
+    """The stream plan is cached on its identity, and its key tells apart
+    every field that shapes the step program."""
+    args = (CFG, N_STAGES, 96, 96, 90, 80, 16, 1)
+    base = planlib.compile_stream_plan(*args)
+    assert planlib.compile_stream_plan(*args) is base
+    variants = [planlib.compile_stream_plan(CFG, N_STAGES, 96, 96, 96, 96,
+                                            16, 1),
+                planlib.compile_stream_plan(CFG, N_STAGES, 96, 96, 90, 80,
+                                            12, 1),
+                planlib.compile_stream_plan(CFG, N_STAGES, 96, 96, 90, 80,
+                                            16, 0),
+                planlib.compile_stream_plan(CFG, N_STAGES, 96, 96, 90, 80,
+                                            16, 1, decode_cap=64)]
+    keys = {base.key} | {v.key for v in variants}
+    assert len(keys) == 5
+    assert variants[-1].decode_cap == 64
+    assert base.decode_cap == min(planlib.STREAM_DECODE_CAP, base.n_slots)
+    assert (base.ty, base.tx) == (-(-90 // 16), -(-80 // 16))
+
+
+@pytest.mark.parametrize("halo", [0, 1])
+def test_stream_plan_tile_ranges_match_receptive_fields(halo):
+    """Each window's (ty0, ty1, tx0, tx1) bracket is the first and last
+    tile its receptive field samples (brute force over the
+    nearest-neighbour map), clipped to the true frame's tile grid; the
+    limit mask keeps exactly the windows that sample no padding."""
+    from repro.core.pyramid import downscale_indices
+    hp = wp = 96
+    h, w, tile = 90, 80, 16
+    sp = planlib.compile_stream_plan(CFG, N_STAGES, hp, wp, h, w, tile,
+                                     halo)
+    plan = planlib.compile_plan(CFG, N_STAGES, hp, wp)
+    assert len(sp.level_tile_ranges) == len(plan.levels)
+    n_live = 0
+    for lp, (ty0, ty1, tx0, tx1) in zip(plan.levels, sp.level_tile_ranges):
+        ys_map = downscale_indices(hp, lp.height)
+        xs_map = downscale_indices(wp, lp.width)
+        for iy in range(lp.ny):
+            rows = ys_map[iy * CFG.step:iy * CFG.step + WINDOW]
+            assert ty0[iy] == min(rows.min() // tile, sp.ty - 1)
+            assert ty1[iy] == min(rows.max() // tile, sp.ty - 1)
+        for ix in range(lp.nx):
+            cols = xs_map[ix * CFG.step:ix * CFG.step + WINDOW]
+            assert tx0[ix] == min(cols.min() // tile, sp.tx - 1)
+            assert tx1[ix] == min(cols.max() // tile, sp.tx - 1)
+        rows_ok = np.array([ys_map[iy * CFG.step + WINDOW - 1] < h
+                            for iy in range(lp.ny)])
+        cols_ok = np.array([xs_map[ix * CFG.step + WINDOW - 1] < w
+                            for ix in range(lp.nx)])
+        want = (rows_ok[:, None] & cols_ok[None, :]).reshape(-1)
+        got = sp.limit_mask[lp.slot_offset:lp.slot_offset + lp.n_windows]
+        assert np.array_equal(got, want)
+        n_live += int(want.sum())
+    assert sp.n_live == n_live and sp.n_slots == plan.n_slots
 
 
 # ----------------------------------------------------- derivation identity
@@ -141,9 +191,9 @@ def test_plan_levels_match_pyramid():
     from repro.core.pyramid import pyramid_plan
     plan = planlib.compile_plan(CFG, N_STAGES, 96, 80)
     pyr = pyramid_plan(96, 80, CFG.scale_factor)
-    assert len(plan.levels_all) == len(pyr)
+    assert len(plan.levels) == len(pyr)
     off = 0
-    for lp, lv in zip(plan.levels_all, pyr):
+    for lp, lv in zip(plan.levels, pyr):
         assert (lp.height, lp.width, lp.scale) == tuple(lv)
         assert lp.ny == (lv.height - WINDOW) // CFG.step + 1
         assert lp.nx == (lv.width - WINDOW) // CFG.step + 1
@@ -156,7 +206,7 @@ def test_slot_layout_matches_bruteforce():
     plan = planlib.compile_plan(CFG, N_STAGES, 96, 96)
     lo = plan.layout
     lvl, ys, xs, bases = [], [], [], [0]
-    for lp in plan.levels_all:
+    for lp in plan.levels:
         gy = np.arange(lp.ny) * CFG.step
         gx = np.arange(lp.nx) * CFG.step
         lvl.append(np.full(lp.ny * lp.nx, lp.index))
@@ -168,27 +218,16 @@ def test_slot_layout_matches_bruteforce():
     assert np.array_equal(lo.x_of_slot, np.concatenate(xs))
     assert np.array_equal(lo.sat_base_of_lvl, bases[:-1])
     assert np.array_equal(lo.sat_stride_of_lvl,
-                          [lp.width + 1 for lp in plan.levels_all])
-    assert np.array_equal(lo.slot_indices, np.arange(plan.n_slots))
+                          [lp.width + 1 for lp in plan.levels])
 
 
-def test_subset_layout_maps_back_to_full():
-    full = planlib.compile_plan(CFG, N_STAGES, 96, 96)
-    active = (0, 2)
-    sub = planlib.compile_plan(CFG, N_STAGES, 96, 96, levels=active).layout
-    assert sub.n_slots == sum(full.levels_all[li].n_windows
-                              for li in active)
-    # subset slots map back to exactly the active levels' full slots
-    assert np.array_equal(full.layout.lvl_of_slot[sub.slot_indices],
-                          sub.lvl_of_slot)
-    assert np.array_equal(full.layout.y_of_slot[sub.slot_indices],
-                          sub.y_of_slot)
-    # the subset SAT layout is compacted over active levels only
-    sizes = [full.levels_all[li].sat_size for li in active]
-    assert sub.sat_base_of_lvl[active[0]] == 0
-    assert sub.sat_base_of_lvl[active[1]] == sizes[0]
-    # inactive levels keep base 0 (never gathered through)
-    assert sub.sat_base_of_lvl[1] == 0
+def test_slot_layout_of_a_bucket_without_windows():
+    # a bucket smaller than the detection window has no level and no slot
+    plan = planlib.compile_plan(CFG, N_STAGES, 16, 16)
+    lo = plan.layout
+    assert plan.levels == () and lo.n_slots == plan.n_slots == 0
+    assert lo.lvl_of_slot.shape == lo.y_of_slot.shape == (0,)
+    assert np.array_equal(lo.sat_base_of_lvl, [0])
 
 
 # ------------------------------------------------------- backend decisions
@@ -203,13 +242,6 @@ def test_tail_backends_compiled_into_plan():
     for seg in plan.tail_segments:
         assert seg.backend == planlib.select_backend(cfg, seg.capacity)
         assert seg.backend in ("gather", "bulk", "pallas")
-    # stream rung plans: one all-stage segment at the rung's backend
-    for cap, want in ((64, "gather"), (512, "bulk"), (5000, "pallas")):
-        sp = planlib.compile_plan(cfg, N_STAGES, 96, 96, levels=(0,),
-                                  capacity=cap)
-        (seg,) = sp.segments
-        assert (seg.s0, seg.s1, seg.dense) == (0, N_STAGES, False)
-        assert seg.backend == want
 
 
 def test_packed_tail_select_backend_delegates_to_plan():
@@ -253,17 +285,6 @@ def test_head_modes_compiled_into_plan():
             == {"split"}
 
 
-def test_head_mode_needs_dense_prefix():
-    # a tail-only rung plan (dense=False everywhere) has no dense head to
-    # fuse: compiled mode is split regardless of the forced config
-    cfg = PALL._replace(head_mode="fused", tail_backend="auto",
-                        tail_rungs=LADDER)
-    sp = planlib.compile_plan(cfg, N_STAGES, 96, 96, levels=(0,),
-                              capacity=512)
-    assert not any(seg.dense for seg in sp.segments)
-    assert set(sp.head_modes) == {"split"}
-
-
 def test_tuned_shapes_key_plans_and_rebuild_once():
     """Two calibration profiles differing only in tuned shapes must compile
     to distinct plans (distinct ``plan.key``s), carry the tuned shapes, and
@@ -299,19 +320,20 @@ def test_tuned_shapes_key_plans_and_rebuild_once():
 
 # ------------------------------------------------- executor equivalence
 def test_forced_rung_backends_bit_identical_end_to_end():
-    """The same stream evaluated under ladders that force different
-    backends at the active rung must produce identical detections (the
+    """The same frames batched under ladders that force different
+    backends at every tail rung must produce identical detections (the
     plan layer only changes *how* the tail runs, never what it computes)."""
     video = make_video("moving_face", n_frames=3, h=64, w=64, seed=4)
+    frames = [f for f, _gt in video]
     ref = None
     for bk in ("gather", "bulk", "pallas"):
         ladder = ((10 ** 9, bk),)
         det = Detector(CASC, CFG._replace(tail_backend="auto",
                                           tail_rungs=ladder))
-        vd = VideoDetector(det, StreamConfig(tile=16, threshold=0.0,
-                                             keyframe_interval=0),
-                           engine=StreamEngine(det, 0.5))
-        got = [vd.process(f)[0] for f, _gt in video]
+        plan = det.batch_plan(64, 64, len(frames))
+        assert plan.tail_segments
+        assert {s.backend for s in plan.tail_segments} == {bk}
+        got = list(det.detect_batch(frames, strategy="packed"))
         if ref is None:
             ref = got
         else:
@@ -332,7 +354,7 @@ def test_forced_head_modes_bit_identical_end_to_end():
         det = Detector(CASC, PALL._replace(head_mode=hm))
         vd = VideoDetector(det, StreamConfig(tile=16, threshold=0.0,
                                              keyframe_interval=0),
-                           engine=StreamEngine(det, 0.5))
+                           engine=StreamEngine(det))
         res = det.batch_result(imgs)
         got = ([det.detect(imgs[0])]
                + list(det.detect_batch(imgs, strategy="packed"))

@@ -1,15 +1,16 @@
 """Device-resident stream state: the on-device tile-change kernels race
-their oracle twins and the host planners; `StreamConfig.device_state`
-streams are bit-identical to the host-planned path (and to per-frame
-``detect``) at threshold 0 across every synthetic scenario, through the
+their oracle twins and a brute-force numpy test; streams stay
+bit-identical to per-frame ``detect`` at threshold 0 through the
 pipelined submit/retire API, large changes, and the decode-overflow
 fallback; every frame, keyframes and full refreshes included, runs in one
 step program per stream, whose masked fused head (``use_pallas``, step 1)
 agrees with ``detect`` and the plain reference (``bench/reference.py``);
 the survivor-slot list is rebuilt exactly on the frames whose bitmap
 changed and carried otherwise, through a decode overflow and a reset;
-the donated state reuses its buffers; and serving sessions report
-identical stream stats either way."""
+the donated state reuses its buffers and moves only the frame, the
+verdict and the slot list; and serving sessions report the stats and
+rects a single-stream ``VideoDetector`` does, across shape buckets and
+decode overflows."""
 
 import numpy as np
 import pytest
@@ -21,15 +22,12 @@ from repro.kernels.ops import (tile_change_mask, changed_window_map)
 from repro.kernels.ref import (tile_change_mask_ref, changed_window_map_ref)
 from repro.serve import DetectorService, PodSpec, ServiceConfig
 from repro.stream import (SCENARIOS, StreamConfig, StreamEngine,
-                          VideoDetector, make_video, tile_change_scores,
-                          dilate_tiles)
-from repro.stream.video import level_windows_from_raw
+                          VideoDetector, level_windows_from_raw, make_video)
 
 CASC = paper_shaped_cascade(0, stage_sizes=[3, 4, 5, 6, 8])
 KW = dict(step=2, scale_factor=1.3, min_neighbors=2)
 HW = 96
-HOST_CFG = StreamConfig(tile=12, threshold=0.0, keyframe_interval=4)
-DEV_CFG = HOST_CFG._replace(device_state=True)
+DEV_CFG = StreamConfig(tile=12, threshold=0.0, keyframe_interval=4)
 
 
 @pytest.fixture(scope="module")
@@ -42,7 +40,24 @@ def frames_of(kind, n=10, seed=3, h=HW, w=HW):
                                        seed=seed)]
 
 
-# ------------------------------------------------- kernels vs oracles/host
+def brute_changed(prev, cur, tile, halo):
+    """Any pixel differs per tile, then ``halo`` 4-neighbour rings."""
+    ty, tx = -(-cur.shape[0] // tile), -(-cur.shape[1] // tile)
+    m = np.array([[(prev[i * tile:(i + 1) * tile, j * tile:(j + 1) * tile]
+                    != cur[i * tile:(i + 1) * tile,
+                           j * tile:(j + 1) * tile]).any()
+                   for j in range(tx)] for i in range(ty)])
+    for _ in range(halo):
+        g = m.copy()
+        g[1:] |= m[:-1]
+        g[:-1] |= m[1:]
+        g[:, 1:] |= m[:, :-1]
+        g[:, :-1] |= m[:, 1:]
+        m = g
+    return m
+
+
+# ------------------------------------------------ kernels vs oracles
 @pytest.mark.parametrize("exact", [True, False])
 @pytest.mark.parametrize("halo", [0, 1])
 def test_tile_change_mask_matches_ref_and_host(exact, halo):
@@ -59,11 +74,10 @@ def test_tile_change_mask_matches_ref_and_host(exact, halo):
     assert np.array_equal(np.asarray(changed), np.asarray(changed_r))
     np.testing.assert_allclose(np.asarray(scores), np.asarray(scores_r),
                                rtol=1e-5, atol=1e-7)
-    # exact mode matches the host planner's bit-for-bit change test
+    # exact mode is the brute-force any-pixel-differs test, dilated
     if exact:
-        _s, host_any = tile_change_scores(prev, cur, 12, exact=True)
-        host = dilate_tiles(host_any, halo)
-        assert np.array_equal(np.asarray(changed), host)
+        assert np.array_equal(np.asarray(changed),
+                              brute_changed(prev, cur, 12, halo))
 
 
 def test_changed_window_map_matches_ref():
@@ -89,19 +103,6 @@ def test_changed_window_map_matches_ref():
 
 # ------------------------------------------------------- bit-identity
 @pytest.mark.parametrize("kind", SCENARIOS)
-def test_device_stream_bit_identical_to_host_and_detect(detector, kind):
-    vh = VideoDetector(detector, HOST_CFG)
-    vd = VideoDetector(detector, DEV_CFG)
-    for f in frames_of(kind):
-        rh, sh = vh.process(f)
-        rd, sd = vd.process(f)
-        assert np.array_equal(rh, rd)
-        assert sh == sd                  # mode, counters, level accounting
-        assert np.array_equal(rd, detector.detect(f))
-    assert vd.xfer_bytes > 0             # the accounting actually ran
-
-
-@pytest.mark.parametrize("kind", SCENARIOS)
 def test_pipelined_submit_retire_matches_sequential(detector, kind):
     # a successor's step is dispatched before its predecessor retires,
     # across keyframes and full refreshes too: the donated state chains
@@ -124,21 +125,17 @@ def test_pipelined_submit_retire_matches_sequential(detector, kind):
 def test_pan_burst_commits_in_the_one_step_program(detector):
     # static opening then a pan burst that recomputes (nearly) every
     # window: the step has no capacity to outgrow, so every frame commits
-    # the exact host result in the stream's one program
-    cfg_h = HOST_CFG._replace(keyframe_interval=0, full_refresh_frac=0.95,
-                              max_changed_frac=0.95)
-    cfg_d = cfg_h._replace(device_state=True)
+    # the exact result in the stream's one program
+    cfg = DEV_CFG._replace(keyframe_interval=0, full_refresh_frac=0.95)
     frames = (frames_of("static_cctv", n=3, seed=7)
               + frames_of("camera_pan", n=3, seed=7))
-    eng = StreamEngine(detector, cfg_d.max_changed_frac)
-    vh = VideoDetector(detector, cfg_h)
-    vd = VideoDetector(detector, cfg_d, engine=eng)
+    eng = StreamEngine(detector)
+    vd = VideoDetector(detector, cfg, engine=eng)
     modes = []
     for f in frames:
-        rh, sh = vh.process(f)
         rd, sd = vd.process(f)
         modes.append(sd.mode)
-        assert np.array_equal(rh, rd) and sh == sd
+        assert np.array_equal(rd, detector.detect(f))
         assert eng.program_builds == 1
     assert modes[0] == "full" and modes[1] != "full"
 
@@ -146,14 +143,13 @@ def test_pan_burst_commits_in_the_one_step_program(detector):
 def test_decode_overflow_falls_back_to_full(detector):
     # decode_cap smaller than the survivor count: rects stay identical,
     # the frame is just accounted as a full refresh
-    vh = VideoDetector(detector, HOST_CFG)
     vd = VideoDetector(detector, DEV_CFG, decode_cap=4)
     modes = []
     for f in frames_of("moving_face", n=8, seed=9):
-        rh, _sh = vh.process(f)
         rd, sd = vd.process(f)
         modes.append(sd.mode)
-        assert np.array_equal(rh, rd)
+        assert np.array_equal(rd, detector.detect(f))
+        assert sd.windows_recomputed == sd.windows_total
     assert set(modes) == {"full"}
 
 
@@ -249,13 +245,15 @@ def test_carried_slot_list_after_overflow_and_reset(detector):
 def test_donated_state_reuses_buffers_and_programs(detector):
     # a stream that settles into steady incremental frames: the donated
     # state must recycle its buffers in place with no new program builds
-    eng = StreamEngine(detector, DEV_CFG.max_changed_frac)
+    eng = StreamEngine(detector)
     vd = VideoDetector(detector, DEV_CFG._replace(keyframe_interval=0),
                        engine=eng)
     frames = frames_of("static_cctv", n=12, seed=11)
-    ptrs, builds, modes = [], [], []
+    ptrs, builds, modes, xfer = [], [], [], []
     for f in frames:
+        before = vd.xfer_bytes
         _r, s = vd.process(f)
+        xfer.append(vd.xfer_bytes - before)
         modes.append(s.mode)
         if vd._dev_state is not None:
             ptrs.append(vd._dev_state.ref.unsafe_buffer_pointer())
@@ -266,24 +264,31 @@ def test_donated_state_reuses_buffers_and_programs(detector):
     assert builds == [1] * len(frames)
     # donation: the reference-frame buffer is recycled in place
     assert len(set(ptrs[2:])) == 1
-    # steady state fetches scalars + slots, never the ref/bitmap arrays
-    assert vd._ref is None and vd._bitmap is None
+    # steady state moves the frame up, the verdict and the slot list
+    # down, never the reference or the bitmap
+    splan = vd._splan
+    step_bytes = (splan.hp * splan.wp * 4 + (7 + detector.n_stages) * 4
+                  + splan.decode_cap * 4)
+    assert xfer[1:] == [step_bytes] * (len(frames) - 1)
 
 
 def test_device_stream_api_guards(detector):
+    with pytest.raises(ValueError, match="device_state"):
+        VideoDetector(detector, DEV_CFG._replace(device_state=False))
     vd = VideoDetector(detector, DEV_CFG)
     frame = frames_of("static_cctv", n=1)[0]
     vd.process(frame)
-    with pytest.raises(RuntimeError, match="device-resident"):
-        vd.plan_frame(frame)
     with pytest.raises(ValueError, match="device_state"):
         vd.reconfigure(DEV_CFG._replace(device_state=False))
+    assert vd.config == DEV_CFG          # the refused config never lands
     rects, _ = vd.process(frame)
     with pytest.raises(ValueError):      # cached returns are read-only
         rects[...] = 0
-    vh = VideoDetector(detector, HOST_CFG)
-    with pytest.raises(RuntimeError, match="device_state"):
-        vh.submit(frame)
+    tok_a, tok_b = vd.submit(frame), vd.submit(frame)
+    with pytest.raises(RuntimeError, match="submit order"):
+        vd.retire(tok_b)
+    vd.retire(tok_a)
+    vd.retire(tok_b)
     vd.reset()                           # next frame re-opens cleanly
     r2, s2 = vd.process(frame)
     assert s2.mode == "full"
@@ -357,27 +362,71 @@ def test_parked_camera_masked_head_matches_detect_and_reference(
 
 
 # --------------------------------------------------------------- serving
+def serve_round_robin(svc, sessions, videos):
+    """Submit frame t of every session, for every t, then flush once."""
+    reqs = []
+    for t in range(len(videos[0])):
+        for sess, vid in zip(sessions, videos):
+            reqs.append(sess.submit_frame(vid[t]))
+    svc.flush()
+    return [(r.result(), r.stats) for r in reqs]
+
+
 def test_service_sessions_identical_stats_either_way(detector):
+    # sessions served round by round report the rects and stats of the
+    # same streams run alone, and the rects of per-frame detect
     videos = [frames_of(k, n=6, seed=s)
               for s, k in enumerate(("static_cctv", "moving_face",
                                      "camera_pan"))]
-    outs, stream_stats = [], []
-    for dev in (False, True):
-        svc = DetectorService(detector, ServiceConfig(
-            pods=(PodSpec("big", 1.0), PodSpec("little", 0.4)),
-            stream_config=HOST_CFG._replace(device_state=dev)))
-        sessions = [svc.open_stream() for _ in videos]
-        reqs = []
-        for t in range(6):
-            for sess, vid in zip(sessions, videos):
-                reqs.append(sess.submit_frame(vid[t]))
-        svc.flush()
-        outs.append([(r.result(), r.stats) for r in reqs])
-        stream_stats.append(svc.stats().stream.as_dict())
-    for (rh, sh), (rd, sd) in zip(*outs):
-        assert np.array_equal(rh, rd)
-        assert sh == sd
-    assert stream_stats[0] == stream_stats[1]
+    svc = DetectorService(detector, ServiceConfig(
+        pods=(PodSpec("big", 1.0), PodSpec("little", 0.4)),
+        stream_config=DEV_CFG))
+    got = serve_round_robin(svc, [svc.open_stream() for _ in videos],
+                            videos)
+    alone = [VideoDetector(detector, DEV_CFG) for _ in videos]
+    want = [alone[i].process(videos[i][t])
+            for t in range(6) for i in range(len(videos))]
+    flat = [videos[i][t] for t in range(6) for i in range(len(videos))]
+    modes = {"full": 0, "incremental": 0, "cached": 0}
+    for (rg, sg), (rw, sw), f in zip(got, want, flat):
+        assert np.array_equal(rg, rw) and sg == sw
+        assert np.array_equal(rg, detector.detect(f))
+        modes[sg.mode] += 1
+    stream = svc.stats().stream
+    assert stream.frames_done == len(flat)
+    assert stream.frame_modes == modes
+
+
+def test_service_round_two_frame_shapes(detector):
+    # two plan keys in one round: each session runs its own bucket's step
+    # program, and every frame matches detect
+    videos = [frames_of("static_cctv", n=4, seed=1),
+              frames_of("moving_face", n=4, seed=2, h=64, w=80)]
+    svc = DetectorService(detector, ServiceConfig(stream_config=DEV_CFG))
+    sessions = [svc.open_stream() for _ in videos]
+    got = serve_round_robin(svc, sessions, videos)
+    flat = [videos[i][t] for t in range(4) for i in range(2)]
+    for (rects, _st), f in zip(got, flat):
+        assert np.array_equal(rects, detector.detect(f))
+    assert len({s.plan_key for s in sessions}) == 2
+    assert svc.stream_engine.program_builds == 2
+
+
+def test_service_decode_overflow_counts_full(detector):
+    # a session whose survivors overflow its slot list: every frame takes
+    # its rects from a host detect and counts as a full frame
+    bg, with_face = face_scene()
+    frames = [bg, bg, with_face, with_face]
+    svc = DetectorService(detector, ServiceConfig(stream_config=DEV_CFG))
+    sess = svc.open_stream()
+    sess.video = VideoDetector(detector, DEV_CFG,
+                               engine=svc.stream_engine, decode_cap=4)
+    got = serve_round_robin(svc, [sess], [frames])
+    for (rects, st), f in zip(got, frames):
+        assert st.mode == "full"
+        assert st.windows_recomputed == st.windows_total
+        assert np.array_equal(rects, detector.detect(f))
+    assert svc.stats().stream.frame_modes["full"] == len(frames)
 
 
 def test_jax_default_backend_is_importable():
